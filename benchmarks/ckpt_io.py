@@ -124,7 +124,10 @@ def _child(mb: int, reps: int) -> dict:
 
 
 def _run_child(mb: int, reps: int) -> dict:
+    """The forced-host-device child: a CPU emulation by design, held to the
+    CPU so it never contends for an accelerator the parent holds."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={DEVICES}"
                         ).strip()
@@ -138,7 +141,7 @@ def _run_child(mb: int, reps: int) -> dict:
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     if r.returncode != 0:
         raise RuntimeError(f"ckpt_io child failed:\n{r.stdout}\n{r.stderr}")
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    return {**json.loads(r.stdout.strip().splitlines()[-1]), "device": "cpu"}
 
 
 def rows(*, smoke: bool = False) -> List[str]:

@@ -148,12 +148,12 @@ def main():
         # recompute the collective term with ring-wire weights (all-reduce
         # moves 2x) from the stored breakdown, so old and new records render
         # consistently
-        from repro.launch.roofline import ICI_BW, wire_bytes
-        t_coll = wire_bytes(f.get("coll_breakdown", {})) / ICI_BW
+        from repro.launch.roofline import V5E, wire_bytes
+        t_coll = wire_bytes(f.get("coll_breakdown", {})) / V5E.ici_bw
         terms = {"compute": f["t_compute_s"], "memory": f["t_memory_s"],
                  "collective": t_coll}
         bound = max(terms, key=terms.get)
-        mfu = f["model_flops"] / (max(terms.values()) * r["chips"] * 197e12) \
+        mfu = f["model_flops"] / (max(terms.values()) * r["chips"] * V5E.flops) \
             if max(terms.values()) > 0 else float("nan")
         print(f"| {arch} | {shape} "
               f"| {f['t_compute_s']*1e3:.1f}ms | {f['t_memory_s']*1e3:.1f}ms "

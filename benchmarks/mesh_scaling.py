@@ -379,8 +379,10 @@ def _skew_child(n_devices: int) -> dict:
 
 def _run_child(n: int, flag: str, *extra: str) -> dict:
     """One forced-device-count subprocess point (``--child`` or
-    ``--skew-child``)."""
+    ``--skew-child``).  A CPU emulation by design: the child is held to
+    the CPU so it never contends for an accelerator the parent holds."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n}").strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -393,7 +395,7 @@ def _run_child(n: int, flag: str, *extra: str) -> dict:
     if r.returncode != 0:
         raise RuntimeError(
             f"mesh_scaling child ({flag} n={n}) failed:\n{r.stdout}\n{r.stderr}")
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    return {**json.loads(r.stdout.strip().splitlines()[-1]), "device": "cpu"}
 
 
 def rows(*, smoke: bool = False) -> List[str]:

@@ -36,18 +36,18 @@ def rows(path: str = RESULTS, hillclimb: str = HILLCLIMB) -> List[str]:
                 r = json.loads(line)
                 if r.get("status") == "ok":
                     best[(r["arch"] + "+opt", r["shape"])] = r
-    from repro.launch.roofline import ICI_BW, PEAK_FLOPS, wire_bytes
+    from repro.launch.roofline import V5E, wire_bytes
 
     for (arch, shape), r in sorted(best.items()):
         roof = r["roofline"]
         # recompute the collective term with ring-wire weights (all-reduce
         # moves 2x) so old records render consistently with make_tables
-        t_coll = wire_bytes(roof.get("coll_breakdown", {})) / ICI_BW
+        t_coll = wire_bytes(roof.get("coll_breakdown", {})) / V5E.ici_bw
         terms = {"compute": roof["t_compute_s"], "memory": roof["t_memory_s"],
                  "collective": t_coll}
         bound = max(terms, key=terms.get)
         t_max = max(terms.values())
-        mfu = roof["model_flops"] / (t_max * r["chips"] * PEAK_FLOPS) \
+        mfu = roof["model_flops"] / (t_max * r["chips"] * V5E.flops) \
             if t_max > 0 else float("nan")
         out.append(
             f"roofline_{arch}_{shape},{t_max * 1e6:.0f},"

@@ -10,6 +10,8 @@ import sys
 
 
 def main() -> None:
+    from repro.core import enable_compile_cache
+    enable_compile_cache()
     which = set(sys.argv[1:]) or {"table1", "fig2", "overhead", "roofline",
                                   "lm", "lm_decode", "stream", "mesh",
                                   "serve", "fanin", "pallas", "ckpt"}

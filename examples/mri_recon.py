@@ -52,42 +52,15 @@ import time
 import numpy as np
 
 from repro.configs.mri_recon import CONFIG
-from repro.core import (CLapp, Data, DeviceTraits, DeviceType, KData,
-                        Pipeline, PlatformTraits, ProfileParameters,
-                        SyncSource, XData)
+from repro.core import (CLapp, Data, DeviceTraits, KData, Pipeline,
+                        PlatformTraits, ProfileParameters, SyncSource, XData,
+                        enable_compile_cache)
+from repro.data.phantom import oracle_recon, synthetic_kdata
 from repro.processes import (FFT, ComplexElementProd, SimpleMRIRecon,
                              XImageSum)
 from repro.processes.coil_combine import CombineParams
 from repro.processes.complex_elementprod import ComplexElementProdParams
 from repro.processes.fft import FFTParams
-
-
-def synthetic_kdata(frames: int, coils: int, h: int, w: int, seed: int = 0):
-    """Phantom: moving ellipse + smooth coil sensitivities -> K-space."""
-    rng = np.random.default_rng(seed)
-    yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
-    smaps = np.stack([
-        np.exp(-(((yy - h * (0.2 + 0.6 * c / max(1, coils - 1))) / h) ** 2
-                 + ((xx - w * 0.5) / w) ** 2) * 3.0)
-        * np.exp(1j * 2 * np.pi * c / coils)
-        for c in range(coils)
-    ]).astype(np.complex64)
-    frames_img = []
-    for f in range(frames):
-        cx = w * (0.4 + 0.2 * np.sin(2 * np.pi * f / frames))
-        img = ((xx - cx) ** 2 / (0.1 * w) ** 2
-               + (yy - h * 0.5) ** 2 / (0.2 * h) ** 2 < 1.0).astype(np.float32)
-        img += 0.1 * rng.standard_normal((h, w)).astype(np.float32)
-        frames_img.append(img.astype(np.complex64))
-    imgs = np.stack(frames_img)                       # (F, H, W)
-    coil_imgs = imgs[:, None] * smaps[None]           # (F, C, H, W)
-    kdata = np.fft.fft2(coil_imgs, norm="ortho").astype(np.complex64)
-    return kdata, smaps, imgs
-
-
-def oracle_recon(kdata: np.ndarray, smaps: np.ndarray) -> np.ndarray:
-    x = np.fft.ifft2(kdata, norm="ortho")
-    return (np.conj(smaps)[None] * x).sum(axis=1)
 
 
 def _argval(flag: str, default: int) -> int:
@@ -300,10 +273,10 @@ def main() -> None:
     batch = _argval("--batch", 4)
     cfg = CONFIG
 
+    enable_compile_cache()
     app = CLapp()
-    # select the CPU device explicitly, as in listing 5
-    traits = DeviceTraits(type=DeviceType.CPU)
-    app.init(PlatformTraits(), traits)
+    # any device: the accelerator where there is one (listing 5's traits)
+    app.init(PlatformTraits(), DeviceTraits())
     app.loadKernels(["complex_elementprod", "coil_combine"])
 
     kdata, smaps, _ = synthetic_kdata(cfg.frames, cfg.coils, cfg.height, cfg.width)

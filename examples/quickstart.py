@@ -13,7 +13,8 @@ import sys
 import numpy as np
 
 from repro.core import (CLapp, DeviceTraits, Pipeline, PlatformTraits,
-                        ProfileParameters, SyncSource, XData)
+                        ProfileParameters, SyncSource, XData,
+                        enable_compile_cache)
 from repro.processes import Negate
 from repro.processes.negate import NegateParams
 
@@ -21,6 +22,7 @@ from repro.processes.negate import NegateParams
 def main() -> None:
     in_path = sys.argv[1] if len(sys.argv) > 1 else None
     out_path = sys.argv[2] if len(sys.argv) > 2 else "output.png"
+    enable_compile_cache()
 
     # Step 0: get a new OpenCLIPER-style app
     app = CLapp()

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke
+from repro.core import enable_compile_cache
 from repro.models import build_model
 from repro.serve import CallableReplica, FrontDoor, LMServer, SamplingConfig
 
@@ -118,6 +119,7 @@ def serve_front_door() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     serve_transformer()
     serve_whisper()
     serve_front_door()

@@ -13,6 +13,7 @@ import tempfile
 
 import jax
 
+from repro.core import enable_compile_cache
 from repro.data.pipeline import StreamConfig, TokenStream
 from repro.models import build_model
 from repro.models.common import ArchConfig
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = lm_tiny() if args.tiny else lm_100m()
     model = build_model(cfg)
     n_params = sum(

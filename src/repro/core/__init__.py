@@ -13,6 +13,8 @@ from .app import (
     INVALID_HANDLE,
     NoMatchingDeviceError,
     PlatformTraits,
+    compile_cache_dir,
+    enable_compile_cache,
 )
 from .arena import (
     ALIGN,
@@ -56,7 +58,8 @@ __all__ = [
     "Port", "PortError", "Process", "ProcessChain", "ProfileParameters",
     "PureLaunchable", "SplitBatch", "StreamQueue", "SyncSource", "XData",
     "aot_compile",
-    "batched_spec", "compile_cache_stats", "device_view", "kernel",
+    "batched_spec", "compile_cache_dir", "compile_cache_stats",
+    "device_view", "enable_compile_cache", "kernel",
     "pack_device", "pack_host", "pack_tree_host", "plan_layout",
     "split_batched_blob", "stack_host_blobs", "stream_launch",
     "unpack_device", "unpack_host", "unpack_tree_host",

@@ -29,17 +29,44 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import os
+from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
 
+from .arena import blob_spec
 from .data import Data
 from .registry import KernelRegistry
 from .sync import Coherence, SyncSource
 
 DataHandle = int
 INVALID_HANDLE: DataHandle = -1
+
+#: The checkout root (``src/repro/core/app.py`` -> three levels up).
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set, otherwise the fixed
+    ``<checkout>/.jax_cache`` (a fixed path, since the path is part of the
+    cache key: a directory that moves never hits)."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(CHECKOUT / ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache before a program's first
+    compile and return its directory.  Entry points (``chip_smoke.py``, the
+    examples, ``benchmarks/run.py``) call this; importing the library never
+    does.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself
+    and nothing is changed here."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class DeviceType(enum.Enum):
@@ -286,7 +313,8 @@ class CLapp:
             blob = data.pack_host()
             coherence = Coherence.IN_SYNC
         else:
-            blob = np.zeros(data.layout.total_bytes, dtype=np.uint8)
+            spec = blob_spec(data.layout)
+            blob = np.zeros(spec.shape, spec.dtype)
             coherence = Coherence.DEVICE_FRESH
         data.device_blob = jax.device_put(
             blob, sharding if sharding is not None else self.default_sharding)

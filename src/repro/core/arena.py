@@ -16,18 +16,29 @@ dtypes is packed into one contiguous byte blob with a predictable,
 The offset table is the analogue of OpenCLIPER's on-device position/size
 table that its OpenCL kernels read; here host code slices views out of the
 blob (zero-copy on host; lazily sliced+bitcast on device).
+
+Offsets and sizes are in bytes, but a blob is held as 32-bit words
+(``uint32``) on host and device alike.  On a TPU a byte array is a poor
+carrier: turning ``u8[4n]`` into ``f32[n]`` goes through a ``u8[n, 4]``
+array whose minor dimension of 4 is padded to the 128-lane tile, 32x the
+data.  From words, every 4-byte view is a free bitcast and a narrower one
+(bf16, int8) splits each word in place.  For the same reason a complex
+entry is stored planar, all real parts and then all imaginary parts:
+numpy's interleaved pairs would need an ``(n, 2)`` shuffle on the device.
+Every other entry is stored in numpy's memory order.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 ALIGN = 128  # bytes; TPU lane width (128 x f32) and a safe DMA alignment
+WORD = np.dtype(np.uint32)  # the blob's element type
 
 
 def _round_up(n: int, align: int = ALIGN) -> int:
@@ -55,6 +66,11 @@ class ArenaLayout:
 
     entries: Tuple[ArenaEntry, ...]
     total_bytes: int
+
+    @property
+    def total_words(self) -> int:
+        """Length of the blob (``total_bytes`` is a multiple of ``ALIGN``)."""
+        return self.total_bytes // WORD.itemsize
 
     def __post_init__(self):
         names = [e.name for e in self.entries]
@@ -127,12 +143,13 @@ def _as_numpy(x) -> np.ndarray:
 
 
 def pack_host(arrays: Mapping[str, Any], layout: ArenaLayout | None = None) -> Tuple[np.ndarray, ArenaLayout]:
-    """Pack named host arrays into one contiguous uint8 blob."""
+    """Pack named host arrays into one contiguous word blob."""
     if layout is None:
         layout = plan_layout(
             (name, _as_numpy(a).shape, _as_numpy(a).dtype) for name, a in arrays.items()
         )
-    blob = np.zeros(layout.total_bytes, dtype=np.uint8)
+    words = np.zeros(layout.total_words, dtype=WORD)
+    blob = words.view(np.uint8)
     for e in layout.entries:
         a = _as_numpy(arrays[e.name])
         if tuple(a.shape) != e.shape:
@@ -140,17 +157,30 @@ def pack_host(arrays: Mapping[str, Any], layout: ArenaLayout | None = None) -> T
         want = np.dtype(jnp.dtype(e.dtype))
         if a.dtype != want:
             a = a.astype(want)
+        if np.iscomplexobj(a):
+            a = np.concatenate([a.real.reshape(-1), a.imag.reshape(-1)])
         raw = np.ascontiguousarray(a).view(np.uint8).reshape(-1)
         blob[e.offset : e.offset + e.nbytes] = raw
-    return blob, layout
+    return words, layout
 
 
 def unpack_host(blob: np.ndarray, layout: ArenaLayout) -> Dict[str, np.ndarray]:
-    """Zero-copy views of each entry out of a host blob."""
+    """Zero-copy views of each entry out of a host blob (words, or the
+    same bytes as read back from a file).  Complex entries, stored planar,
+    come back as assembled copies."""
+    blob = blob.view(np.uint8)
     out: Dict[str, np.ndarray] = {}
     for e in layout.entries:
         raw = blob[e.offset : e.offset + e.nbytes]
-        out[e.name] = raw.view(np.dtype(jnp.dtype(e.dtype))).reshape(e.shape)
+        dt = e.np_dtype
+        if dt.kind == "c":
+            parts = raw.view(np.finfo(dt).dtype)
+            arr = np.empty(e.shape, dt)
+            arr.real = parts[:arr.size].reshape(e.shape)
+            arr.imag = parts[arr.size:].reshape(e.shape)
+            out[e.name] = arr
+        else:
+            out[e.name] = raw.view(dt).reshape(e.shape)
     return out
 
 
@@ -158,31 +188,52 @@ def unpack_host(blob: np.ndarray, layout: ArenaLayout) -> Dict[str, np.ndarray]:
 # Device-side unpack (lazy slice + bitcast inside jit; no host round trip)
 # ---------------------------------------------------------------------------
 
+def _from_words(words: jax.Array, dt, n: int) -> jax.Array:
+    """The first ``n`` items of dtype ``dt`` held in ``words`` (1-D)."""
+    item = np.dtype(dt).itemsize
+    if item == WORD.itemsize:
+        return jax.lax.bitcast_convert_type(words, dt)
+    if item > WORD.itemsize:
+        return jax.lax.bitcast_convert_type(
+            words.reshape(-1, item // WORD.itemsize), dt)
+    # narrower: each word splits in place into (words, 4 // item) items
+    return jax.lax.bitcast_convert_type(words, dt).reshape(-1)[:n]
+
+
+def _to_words(a: jax.Array) -> jax.Array:
+    """Inverse of :func:`_from_words`: a 1-D array as words (zero-padded
+    to a whole word)."""
+    item = a.dtype.itemsize
+    if item >= WORD.itemsize:
+        return jax.lax.bitcast_convert_type(a, WORD).reshape(-1)
+    per = WORD.itemsize // item
+    a = jnp.pad(a, (0, -a.shape[0] % per))
+    return jax.lax.bitcast_convert_type(a.reshape(-1, per), WORD)
+
+
 def device_view(blob: jax.Array, entry: ArenaEntry) -> jax.Array:
-    """Slice one logical array out of a device-resident uint8 arena blob.
+    """Slice one logical array out of a device-resident word blob.
 
     Works under ``jit``; the compiler folds the slice+bitcast into the
     consumer so chained Processes read the arena in place (zero copy).
-    ``bitcast_convert_type`` rejects bool/complex, so those are routed
-    through uint8 / interleaved float pairs (matching numpy memory layout).
+    ``bitcast_convert_type`` rejects bool/complex, so those are read as
+    uint8 / the real and the imaginary plane.
     """
     dt = jnp.dtype(entry.dtype)
-    raw = jax.lax.dynamic_slice_in_dim(blob, entry.offset, entry.nbytes, axis=0)
-
-    def _bitcast(r, target):
-        item = np.dtype(target).itemsize
-        if item > 1:
-            r = r.reshape((-1, item))
-        return jax.lax.bitcast_convert_type(r, target)
-
+    n = int(np.prod(entry.shape, dtype=np.int64))
+    start = entry.offset // WORD.itemsize
+    # a static slice: offsets past 2**31 (arenas over 8 GiB) stay exact,
+    # where a dynamic slice's int32 index would wrap
+    words = jax.lax.slice_in_dim(
+        blob, start, start + -(-entry.nbytes // WORD.itemsize))
     if dt == jnp.bool_:
-        arr = _bitcast(raw, jnp.uint8) != 0
+        arr = _from_words(words, jnp.uint8, n) != 0
     elif jnp.issubdtype(dt, jnp.complexfloating):
         real_dt = jnp.float32 if dt == jnp.complex64 else jnp.float64
-        pairs = _bitcast(raw, real_dt).reshape((-1, 2))
-        arr = jax.lax.complex(pairs[:, 0], pairs[:, 1]).astype(dt)
+        parts = _from_words(words, real_dt, 2 * n)
+        arr = jax.lax.complex(parts[:n], parts[n:]).astype(dt)
     else:
-        arr = _bitcast(raw, dt)
+        arr = _from_words(words, dt, n)
     return arr.reshape(entry.shape)
 
 
@@ -191,68 +242,90 @@ def unpack_device(blob: jax.Array, layout: ArenaLayout) -> Dict[str, jax.Array]:
 
 
 def pack_device(arrays: Mapping[str, jax.Array], layout: ArenaLayout) -> jax.Array:
-    """Pack device arrays into a uint8 blob (jit-compatible)."""
-    blob = jnp.zeros((layout.total_bytes,), dtype=jnp.uint8)
+    """Pack device arrays into a word blob (jit-compatible).  Built by
+    concatenation, so no offset becomes an int32 index."""
+    parts = []
+    end = 0
     for e in layout.entries:
         dt = jnp.dtype(e.dtype)
         a = arrays[e.name].astype(dt).reshape(-1)
         if dt == jnp.bool_:
             a = a.astype(jnp.uint8)
         elif jnp.issubdtype(dt, jnp.complexfloating):
-            real_dt = jnp.float32 if dt == jnp.complex64 else jnp.float64
-            a = jnp.stack([jnp.real(a), jnp.imag(a)], axis=-1).astype(real_dt).reshape(-1)
-        raw = jax.lax.bitcast_convert_type(a, jnp.uint8)
-        raw = raw.reshape(-1)
-        blob = jax.lax.dynamic_update_slice_in_dim(blob, raw, e.offset, axis=0)
-    return blob
+            a = jnp.concatenate([jnp.real(a), jnp.imag(a)])
+        start = e.offset // WORD.itemsize
+        raw = _to_words(a)
+        parts += [jnp.zeros((start - end,), WORD), raw]
+        end = start + raw.shape[0]
+    parts.append(jnp.zeros((layout.total_words - end,), WORD))
+    # the blob is materialised as a stage boundary: a chain traced into one
+    # program then computes each stage as its own program does (same
+    # fusions, same rounding), not folded into the next stage's consumer
+    return jax.lax.optimization_barrier(jnp.concatenate(parts))
 
 
 # ---------------------------------------------------------------------------
 # Batched layouts: k identical arenas stacked on a leading axis (streaming)
 # ---------------------------------------------------------------------------
 
+def blob_spec(layout: ArenaLayout) -> jax.ShapeDtypeStruct:
+    """AOT spec of one arena blob: ``(total_words,)`` words."""
+    return jax.ShapeDtypeStruct((layout.total_words,), WORD)
+
+
 def batched_spec(layout: ArenaLayout, batch: int) -> jax.ShapeDtypeStruct:
-    """AOT spec for ``batch`` stacked arena blobs: ``(batch, total_bytes)``
-    uint8.  The per-item layout is unchanged — a vmapped program sees each
+    """AOT spec for ``batch`` stacked arena blobs: ``(batch, total_words)``
+    words.  The per-item layout is unchanged — a vmapped program sees each
     row as one ordinary 1-D arena blob."""
-    return jax.ShapeDtypeStruct((int(batch), layout.total_bytes), np.uint8)
+    return jax.ShapeDtypeStruct((int(batch), layout.total_words), WORD)
 
 
 def split_batched_blob(stacked: jax.Array) -> List[jax.Array]:
-    """Per-item 1-D arena blobs out of a ``(k, total_bytes)`` stacked blob.
+    """Per-item 1-D arena blobs out of a ``(k, total_words)`` stacked blob.
 
     For a batch-sharded stacked blob (``NamedSharding`` with the leading
     axis on the mesh's ``data`` axis) rows are sliced out of the LOCAL
     ``addressable_shards``, so each item's output blob stays resident on
     the device that computed it — no cross-device gather, no implicit
-    transfer back to device 0.  A single-device (or replicated) stacked
-    blob is one shard covering every row, which reduces to plain row
-    indexing.
+    transfer back to device 0.  A row held by several devices (replicated
+    over the ``model`` axis of a 2D mesh: the model group that computed
+    it) stays on every one of them, as one array replicated over that
+    group.  A single-device stacked blob is one shard covering every row,
+    which reduces to plain row indexing.
     """
     k = int(stacked.shape[0])
-    items: List[Optional[jax.Array]] = [None] * k
+    copies: List[Dict[Any, jax.Array]] = [{} for _ in range(k)]
     for shard in stacked.addressable_shards:
         row0 = shard.index[0].start or 0
         for r in range(shard.data.shape[0]):
-            if items[row0 + r] is None:     # replicated: first copy wins
-                items[row0 + r] = shard.data[r]
-    missing = [i for i, b in enumerate(items) if b is None]
+            copies[row0 + r].setdefault(shard.device, shard.data[r])
+    missing = [i for i, c in enumerate(copies) if not c]
     if missing:
         raise ValueError(
             f"stacked blob rows {missing} have no addressable shard "
             "(multi-process sharding is not supported by split_batched_blob)")
+    items: List[jax.Array] = []
+    for row in copies:
+        if len(row) == 1:
+            items.extend(row.values())
+            continue
+        from repro.launch.mesh import group_sharding  # lazy: no import cycle
+        devices = list(row)
+        items.append(jax.make_array_from_single_device_arrays(
+            (stacked.shape[1],), group_sharding(devices),
+            [row[d] for d in devices]))
     return items
 
 
 def stack_host_blobs(blobs: Sequence[np.ndarray], layout: ArenaLayout) -> np.ndarray:
-    """Stack per-item host blobs into one contiguous ``(k, total_bytes)``
+    """Stack per-item host blobs into one contiguous ``(k, total_words)``
     array — the single-call batched transfer (one ``device_put`` moves k
     Data sets; fewer, larger DMAs, as the paper prescribes per set)."""
     for b in blobs:
-        if b.shape != (layout.total_bytes,) or b.dtype != np.uint8:
+        if b.shape != (layout.total_words,) or b.dtype != WORD:
             raise ValueError(
                 f"blob shape {b.shape}/{b.dtype} does not match layout "
-                f"({layout.total_bytes},)/uint8")
+                f"({layout.total_words},)/{WORD}")
     return np.stack(blobs, axis=0)
 
 

@@ -90,7 +90,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .app import CLapp, DataHandle, INVALID_HANDLE
-from .arena import ArenaLayout, pack_device, unpack_device
+from .arena import ArenaLayout, blob_spec, pack_device, unpack_device
 from .sync import Coherence
 
 
@@ -707,7 +707,7 @@ class Process:
             d = app.getData(h)
             if d.layout is None:
                 d.plan()
-            specs.append(jax.ShapeDtypeStruct((d.layout.total_bytes,), np.uint8))
+            specs.append(blob_spec(d.layout))
         return specs
 
     def init(self) -> None:
@@ -716,8 +716,7 @@ class Process:
         for name in self.kernel_names:
             app.kernels.load(name)  # module names; idempotent
         la = self.launchable()
-        specs = [jax.ShapeDtypeStruct((lay.total_bytes,), np.uint8)
-                 for lay in la.in_layouts]
+        specs = [blob_spec(lay) for lay in la.in_layouts]
         specs += self._aux_specs(la)
         self._compiled = aot_compile(
             la.fn,
@@ -989,8 +988,7 @@ class ProcessChain(Process):
         la = self.launchable()
         self.in_handles = dict(zip(la.in_names, la.in_handles))
         self.out_handle = self.stages[-1].out_handle
-        specs = [jax.ShapeDtypeStruct((lay.total_bytes,), np.uint8)
-                 for lay in la.in_layouts]
+        specs = [blob_spec(lay) for lay in la.in_layouts]
         specs += self._aux_specs(la)
         self._compiled = aot_compile(
             la.fn, specs, tag=la.tag,

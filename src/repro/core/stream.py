@@ -62,7 +62,7 @@ With ``sharded=True`` the executor is *mesh-aware*: it uses the
 built over its selected devices (paper §III-A.1a: device selection is the
 ONLY device-count-dependent call the user makes).  The contract:
 
-* **Placement** — each stacked ``(batch, total_bytes)`` arena blob is
+* **Placement** — each stacked ``(batch, total_words)`` arena blob is
   ``device_put`` with ``NamedSharding(mesh, P("data"))``: rows (items)
   are scattered round-robin across every device on the ``data`` axis in
   ONE call.  Aux blobs are replicated (``P()``) over the same mesh.
@@ -152,7 +152,8 @@ from typing import (Any, Iterable, Iterator, List, Mapping, Optional,
 import jax
 import numpy as np
 
-from .arena import batched_spec, split_batched_blob, stack_host_blobs
+from .arena import (batched_spec, blob_spec, split_batched_blob,
+                    stack_host_blobs)
 from .data import Data
 from .process import (PureLaunchable, ProfileParameters, aot_compile,
                       _layout_fingerprint)
@@ -310,7 +311,7 @@ class _SplitStack:
 class SplitBatch:
     """Per-device parts of one proportionally-split stacked batch.
 
-    ``parts[j]`` is a ``(counts[j], total_bytes)`` blob resident on
+    ``parts[j]`` is a ``(counts[j], total_words)`` blob resident on
     ``devices[j]`` (zero-count devices are omitted); concatenating the
     parts in order restores the items in stream order.  Quacks enough
     like a stacked ``jax.Array`` for the queue bookkeeping: ``shape``,
@@ -406,8 +407,12 @@ class BatchedProcess:
             app.kernels.load(name)
         la = p.launchable()
         n_in = la.n_inputs
+        # sharded: the batch dim of any shard_map inside the program
+        # (``shard_by_logical``) is split over ``data`` too, so each device
+        # computes only its own rows
         batched = jax.vmap(
-            la.fn, in_axes=(0,) * n_in + (None,) * len(la.aux_handles))
+            la.fn, in_axes=(0,) * n_in + (None,) * len(la.aux_handles),
+            spmd_axis_name="data" if self.sharded else None)
         specs = [batched_spec(lay, self.batch) for lay in la.in_layouts]
         specs += p._aux_specs(la)
         in_shardings = out_shardings = None
@@ -888,7 +893,7 @@ def _host_blob_of(data: Data) -> "np.ndarray | jax.Array":
 
 def _stack_blobs(blobs: Sequence["np.ndarray | jax.Array"],
                  layout) -> "np.ndarray | jax.Array":
-    """Stack one group's per-item blobs into a ``(rows, total_bytes)``
+    """Stack one group's per-item blobs into a ``(rows, total_words)``
     batch.  A group resident entirely on ONE device stacks there
     (``jnp.stack`` — the device-to-device edge: zero host2device traffic,
     and the downstream :class:`StreamQueue` placement becomes a
@@ -898,13 +903,13 @@ def _stack_blobs(blobs: Sequence["np.ndarray | jax.Array"],
     if all(isinstance(b, jax.Array) for b in blobs):
         devices = {d for b in blobs for d in b.devices()}
         if len(devices) == 1:
+            want = blob_spec(layout)
             for b in blobs:
-                if tuple(b.shape) != (layout.total_bytes,) or \
-                        b.dtype != np.uint8:
+                if tuple(b.shape) != want.shape or b.dtype != want.dtype:
                     raise ValueError(
                         f"device blob shape {tuple(b.shape)}/{b.dtype} does "
                         f"not match the arena layout "
-                        f"({layout.total_bytes},)/uint8")
+                        f"{want.shape}/{want.dtype}")
             import jax.numpy as jnp
             return jnp.stack(blobs)
     host = [np.asarray(b) if isinstance(b, jax.Array) else b for b in blobs]

@@ -25,18 +25,25 @@ SUBLANE = 8
 
 
 def interpret_mode() -> bool:
-    """Pallas must interpret on non-TPU backends; real lowering on TPU.
+    """Pallas interprets off the TPU and lowers for real on it.
 
     Auto-enabling interpret mode off-TPU is what lets ``use_pallas="auto"``
     resolve to the Pallas backend without hard-failing in a CPU container.
-    ``REPRO_PALLAS_INTERPRET=0/1`` overrides the autodetection either way
-    (``1`` forces interpret even on TPU — useful for debugging kernel
-    bodies; ``0`` forces real lowering — only valid on TPU).
+    ``REPRO_PALLAS_INTERPRET=0`` forces real lowering off the TPU, which is
+    how the compile tests lower kernels for a described chip.  ``=1`` is
+    refused on a TPU backend: a kernel there never runs interpreted.
     """
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    if env != "":
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    if env not in ("", "0", "1"):
+        raise ValueError(f"REPRO_PALLAS_INTERPRET={env!r}: expected 0 or 1")
+    on_tpu = jax.default_backend() == "tpu"
+    if env == "1" and on_tpu:
+        raise RuntimeError(
+            "REPRO_PALLAS_INTERPRET=1 on a TPU backend: Pallas kernels are "
+            "never interpreted on the chip; unset it")
+    if env == "0":
+        return False
+    return not on_tpu
 
 
 def vmem_tile_plan(c: int, h: int, w: int, *, budget: int,

@@ -15,7 +15,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.registry import kernel
 from . import ref
-from .common import LANE, interpret_mode, merge_complex, pad_dim, round_up, split_complex
+from .common import (LANE, SUBLANE, interpret_mode, merge_complex, pad_dim,
+                     round_up, split_complex)
 
 DEFAULT_BLOCK = 32 * LANE
 
@@ -45,18 +46,21 @@ def complex_elementprod(a: jax.Array, b: jax.Array, conjugate_b: bool = False,
     m = int(jnp.size(b))
     ar, ai = split_complex(a)
     br, bi = split_complex(b)
-    ar = ar.reshape(f, -1) if broadcast else ar.reshape(1, -1)
-    ai = ai.reshape(f, -1) if broadcast else ai.reshape(1, -1)
-    br, bi = br.reshape(-1), bi.reshape(-1)
+    ar, ai = ar.reshape(f, m), ai.reshape(f, m)
+    br, bi = br.reshape(1, m), bi.reshape(1, m)
 
+    # TPU tiling: a block's last two dims are multiples of (8, 128) or the
+    # whole array dims — so frames go 8 to a block (padded) beyond 8
+    bf = f if f <= SUBLANE else SUBLANE
+    fp = round_up(f, bf)
     blk = min(block, round_up(m, LANE))
     mp = round_up(m, blk)
-    ar, ai = pad_dim(ar, 1, mp), pad_dim(ai, 1, mp)
-    br, bi = pad_dim(br, 0, mp), pad_dim(bi, 0, mp)
+    ar, ai = (pad_dim(pad_dim(x, 0, fp), 1, mp) for x in (ar, ai))
+    br, bi = pad_dim(br, 1, mp), pad_dim(bi, 1, mp)
 
-    grid = (ar.shape[0], mp // blk)
-    a_spec = pl.BlockSpec((1, blk), lambda fi, mi: (fi, mi))
-    b_spec = pl.BlockSpec((blk,), lambda fi, mi: (mi,))  # frame-invariant
+    grid = (fp // bf, mp // blk)
+    a_spec = pl.BlockSpec((bf, blk), lambda fi, mi: (fi, mi))
+    b_spec = pl.BlockSpec((1, blk), lambda fi, mi: (0, mi))  # frame-invariant
     out_re, out_im = pl.pallas_call(
         functools.partial(_cprod_kernel, conj=conjugate_b),
         grid=grid,
@@ -65,7 +69,7 @@ def complex_elementprod(a: jax.Array, b: jax.Array, conjugate_b: bool = False,
         out_shape=[jax.ShapeDtypeStruct(ar.shape, jnp.float32)] * 2,
         interpret=interpret_mode(),
     )(ar, ai, br, bi)
-    out = merge_complex(out_re[:, :m], out_im[:, :m])
+    out = merge_complex(out_re[:f, :m], out_im[:f, :m])
     return out.reshape(a.shape).astype(a.dtype)
 
 

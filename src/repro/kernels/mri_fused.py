@@ -18,9 +18,11 @@ Two entry points, both reducing the (F, C, H, W) multicoil stack to
   Larger grids fall back to ``jnp.fft.ifft2`` + ``fused_epilogue`` (still
   one fused epilogue pass, FFT handled by XLA).
 
-Numerics note: the DFT-as-matmul path accumulates in f32 with a different
-reduction order than the radix FFT, so it matches ``jnp.fft.ifft2`` to
-~1e-5 relative (f32 roundoff over an N-term sum), not bitwise.  The
+Numerics note: the DFT-as-matmul path multiplies at full f32 precision
+(``Precision.HIGHEST``; the chip's default is one bf16 pass) and
+accumulates in f32 with a different reduction order than the radix FFT,
+so it matches ``jnp.fft.ifft2`` to ~1e-5 relative (f32 roundoff over an
+N-term sum), not bitwise.  The
 epilogue-only path does the same multiply/accumulate as the staged chain.
 """
 from __future__ import annotations
@@ -149,7 +151,10 @@ def _dft_recon_kernel(kr_ref, ki_ref, sr_ref, si_ref,
     ki = ki_ref[...][0].astype(jnp.float32)
     mhr, mhi = mhr_ref[...], mhi_ref[...]         # (Hp, Hp)
     mwr, mwi = mwr_ref[...], mwi_ref[...]         # (Wp, Wp)
-    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    # full f32 passes: the MXU's default single bf16 pass loses ~1e-2 over
+    # a 128-term sum, two orders past the recon's 1e-4 tolerance
+    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     # IFFT over rows: T[c,a,w] = Σ_h M_H[a,h]·K[c,h,w] (complex via 4 real
     # matmuls)
     tr = dot("ah,chw->caw", mhr, kr) - dot("ah,chw->caw", mhi, ki)

@@ -54,7 +54,7 @@ def _compile_cell(low, mesh):
 
 
 def _costs_of(compiled) -> Dict[str, Any]:
-    cost = cost_dict(compiled)  # dict in old JAX, [dict, ...] in new JAX
+    cost = cost_dict(compiled)
     coll = collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

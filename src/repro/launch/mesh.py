@@ -38,7 +38,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(devices: Sequence[jax.Device],
@@ -184,8 +185,8 @@ def shard_by_logical(fn: Callable,
                      in_axes: Sequence[Optional[Sequence[Optional[str]]]],
                      out_axes,
                      *, mesh: Optional[jax.sharding.Mesh] = None) -> Callable:
-    """Partition ``fn`` over the mesh with :func:`jax.experimental.shard_map
-    .shard_map`, with per-dim *logical* axis names instead of mesh axes.
+    """Partition ``fn`` over the mesh with :func:`jax.shard_map`, with
+    per-dim *logical* axis names instead of mesh axes.
 
     ``in_axes`` holds one annotation per positional argument: a tuple of
     logical names (one per dim, ``None`` = replicated dim) or ``None`` to
@@ -193,22 +194,25 @@ def shard_by_logical(fn: Callable,
     ``out_axes`` annotates a single output the same way; a **list** of
     such annotations annotates a tuple-returning ``fn`` per output.
 
-    The wrapper is a **total no-op** — it calls ``fn`` directly — whenever
-    partitioning cannot apply: no mesh (``mesh=None`` and no compile in
-    progress), every bound mesh axis trivial, or any partitioned dim not
-    divisible by its axis size.  So annotated processes stay bit-exact and
-    compile identically on 1D meshes, and degrade gracefully on shapes the
-    mesh does not divide.  ``mesh=None`` resolves the mesh the enclosing
+    The wrapper is a no-op — it calls ``fn`` directly — only where there
+    is nothing to place: no mesh (``mesh=None`` and no compile in
+    progress) or a mesh of one device.  On any larger mesh ``fn`` runs
+    under ``shard_map``, so a Pallas kernel inside it is never left to
+    the automatic partitioner (which refuses Mosaic kernels).  A
+    partitioned dim its mesh axis does not divide makes every argument
+    replicated instead.  ``mesh=None`` resolves the mesh the enclosing
     AOT compilation is lowering under (:func:`repro.core.process.
     current_compile_mesh`), which is how one annotated ``apply`` body runs
-    unsharded in a pinned per-device executable and ``model``-sharded in
-    the same pipeline's 2D mesh executable."""
+    whole in a pinned per-device executable and ``model``-sharded in the
+    same pipeline's 2D mesh executable.  Under ``vmap(...,
+    spmd_axis_name="data")`` (the sharded stream executor) the batch dim
+    is split over ``data`` as well."""
     in_axes = tuple(in_axes)
 
     def wrapped(*args):
         from repro.core.process import current_compile_mesh  # lazy: no cycle
         m = mesh if mesh is not None else current_compile_mesh()
-        if m is None:
+        if m is None or m.size == 1:
             return fn(*args)
         if len(args) != len(in_axes):
             raise ValueError(
@@ -218,26 +222,20 @@ def shard_by_logical(fn: Callable,
         in_specs = [logical_pspec(a) for a in in_axes]
         if isinstance(out_axes, list):             # list = one entry per output
             out_specs: Any = tuple(logical_pspec(a) for a in out_axes)
-            flat_out = list(out_specs)
         else:
             out_specs = logical_pspec(out_axes)
-            flat_out = [out_specs]
-        used = {ax for spec in in_specs + flat_out
-                for ax in spec if ax is not None}
-        if not any(shape.get(ax, 1) > 1 for ax in used):
-            return fn(*args)                       # nothing to partition
-        for arg, axes_ann in zip(args, in_axes):
-            if axes_ann is None:
-                continue
-            for d, name in enumerate(axes_ann):
-                ax = mesh_axis(name)
-                if ax is None:
-                    continue
-                if arg.shape[d] % shape.get(ax, 1):
-                    return fn(*args)               # indivisible: stay whole
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=m, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_rep=False)(*args)
+        whole = any(
+            axes_ann is not None and name is not None
+            and arg.shape[d] % shape.get(mesh_axis(name), 1)
+            for arg, axes_ann in zip(args, in_axes)
+            for d, name in enumerate(axes_ann or ()))
+        if whole:                                  # indivisible: stay whole
+            P = jax.sharding.PartitionSpec
+            in_specs = [P()] * len(in_specs)
+            out_specs = (tuple(P() for _ in out_specs)
+                         if isinstance(out_axes, list) else P())
+        return jax.shard_map(fn, mesh=m, in_specs=tuple(in_specs),
+                             out_specs=out_specs, check_vma=False)(*args)
 
     return wrapped
 

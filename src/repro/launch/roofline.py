@@ -4,18 +4,15 @@
     memory     = HLO_bytes_per_chip / HBM_bw
     collective = collective_bytes_per_chip / ICI_bw
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI.  ``cost_analysis()`` reports the SPMD-partitioned per-device module
+Peaks come from :data:`PEAKS`, one table keyed by ``device_kind``.  The
+dry-run estimates a v5e production mesh, so :class:`Roofline` reads the
+v5e row (:data:`V5E`) by name; the :class:`KernelChooser` looks up the
+device it runs on and breaks no tie by a bound it cannot compute.
+``cost_analysis()`` reports the SPMD-partitioned per-device module
 (verified in tests/test_roofline.py), so no device division is applied.
 collective_bytes is parsed from the compiled HLO text: max(input, output)
 bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute (including their -start forms).
-
-Compat note: ``Compiled.cost_analysis()`` changed return type across JAX
-versions — old JAX returns one flat ``{metric: value}`` dict for the
-executable, newer JAX (>= 0.4.x line used here) returns a **list** of
-per-computation dicts.  All readers must go through :func:`cost_dict`,
-which normalizes both shapes to a single summed dict.
 """
 from __future__ import annotations
 
@@ -25,30 +22,29 @@ from typing import Any, Dict, List, Optional, Tuple
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """Normalized ``cost_analysis()`` of a compiled executable.
+    """The numeric metrics of ``compiled.cost_analysis()`` as floats
+    (``{}`` when the backend reports no analysis)."""
+    cost = compiled.cost_analysis() or {}
+    return {k: float(v) for k, v in cost.items() if isinstance(v, (int, float))}
 
-    Accepts either a ``jax.stages.Compiled`` (calls ``cost_analysis()`` on
-    it) or the raw return value.  Old JAX returns a dict; new JAX returns a
-    list of per-computation dicts — these are merged by summing numeric
-    metrics, which is correct for the additive metrics this repo reads
-    ("flops", "bytes accessed").  ``None``/empty analyses give ``{}``.
-    """
-    cost = compiled.cost_analysis() if hasattr(compiled, "cost_analysis") else compiled
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return {k: float(v) for k, v in cost.items()
-                if isinstance(v, (int, float))}
-    merged: Dict[str, float] = {}
-    for comp in cost:
-        for k, v in (comp or {}).items():
-            if isinstance(v, (int, float)):
-                merged[k] = merged.get(k, 0.0) + float(v)
-    return merged
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # interconnect bytes/s per link
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s chip-to-chip (4 links of 50 GB/s).
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: The production-mesh estimate's chip (the dry-run's target, by name).
+V5E = PEAKS["TPU v5 lite"]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -136,15 +132,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / V5E.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / V5E.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / V5E.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -165,7 +161,7 @@ class Roofline:
         """Model-FLOPs utilization ceiling implied by the dominant term."""
         if self.t_bound <= 0:
             return float("nan")
-        return self.model_flops / (self.t_bound * n_chips * PEAK_FLOPS)
+        return self.model_flops / (self.t_bound * n_chips * V5E.flops)
 
     def to_dict(self, n_chips: int) -> Dict[str, Any]:
         return {
@@ -222,7 +218,9 @@ def model_flops(cfg, params_tree, kind: str, batch: int, seq: int) -> float:
 #: relative gap below which the measured times are considered a tie and the
 #: roofline bound breaks it (memory-bound -> the fused Pallas pass, which
 #: exists to cut HBM traffic; compute-bound -> XLA, whose op fusion and
-#: layout assignment win on arithmetic-heavy bodies).
+#: layout assignment win on arithmetic-heavy bodies).  On a device with no
+#: row in :data:`PEAKS` the bound is ``"unknown"`` and the faster measured
+#: backend is kept.
 CALIBRATION_TIE_BAND = 0.10
 
 _CALIB_TAG = "__kernel_calibration__"
@@ -245,8 +243,8 @@ class KernelCalibration:
     t_pallas_s: float
     t_xla_s: float
     t_compute_est_s: float         # roofline terms from the XLA compile
-    t_memory_est_s: float
-    bound: str                     # "compute" | "memory"
+    t_memory_est_s: float          # (nan on a device without peaks)
+    bound: str                     # "compute" | "memory" | "unknown"
     interpreted: bool
     reason: str
 
@@ -292,8 +290,9 @@ class KernelChooser:
     point and its pure-jnp oracle), reads the roofline estimate off the XLA
     compile's ``cost_analysis``, runs a one-shot min-of-``reps`` timing of
     each, and caches the verdict in the compile cache.  :meth:`use_pallas`
-    is the cheap cached query that ``use_pallas="auto"`` processes call at
-    trace time — it only needs shapes/dtypes, so tracers are fine.
+    is the query that ``use_pallas="auto"`` processes call at trace time —
+    it only needs shapes/dtypes, so tracers are fine: a first query for a
+    layout calibrates on concrete zero-filled examples, outside the trace.
 
     Off-TPU the Pallas backend runs in interpret mode (Python-loop
     semantics, orders of magnitude slower than its compiled self), so its
@@ -334,9 +333,10 @@ class KernelChooser:
 
     def calibrate(self, name: str, *args, force_timing: bool = False,
                   **kwargs) -> KernelCalibration:
-        """AOT-compile both backends for this concrete layout, time them,
-        and cache the verdict.  ``args`` may be tracers or abstract values —
-        only shapes/dtypes are read; timing runs on zero-filled examples."""
+        """AOT-compile both backends for this layout, time them, and cache
+        the verdict.  ``args`` may be tracers of an enclosing trace or
+        abstract values: only shapes/dtypes are read, and the timing runs
+        on zero-filled concrete examples built outside that trace."""
         import time
 
         import jax
@@ -355,8 +355,8 @@ class KernelChooser:
         # arrays become zero-filled runtime inputs; everything else (flags,
         # block sizes) stays a static Python literal inside the closure
         is_arr = [hasattr(a, "shape") and hasattr(a, "dtype") for a in args]
-        ex = [jnp.zeros(a.shape, a.dtype)
-              for a, arr in zip(args, is_arr) if arr]
+        specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                 for a, arr in zip(args, is_arr) if arr]
 
         def staged(fn):
             def g(*xs):
@@ -366,13 +366,23 @@ class KernelChooser:
                 return fn(*full, **kwargs)
             return g
 
-        fn_c = jax.jit(staged(entry.fn)).lower(*ex).compile()
-        ref_c = jax.jit(staged(entry.ref)).lower(*ex).compile()
+        fn_c = jax.jit(staged(entry.fn)).lower(*specs).compile()
+        ref_c = jax.jit(staged(entry.ref)).lower(*specs).compile()
 
-        cd = cost_dict(ref_c)
-        t_compute = cd.get("flops", 0.0) / PEAK_FLOPS
-        t_memory = cd.get("bytes accessed", 0.0) / HBM_BW
-        bound = "memory" if t_memory >= t_compute else "compute"
+        # no row for this kind (the CPU among others): no bound from a guess
+        peaks = PEAKS.get(jax.devices()[0].device_kind)
+        if peaks is None:
+            t_compute = t_memory = float("nan")
+            bound = "unknown"
+        else:
+            cd = cost_dict(ref_c)
+            t_compute = cd.get("flops", 0.0) / peaks.flops
+            t_memory = cd.get("bytes accessed", 0.0) / peaks.hbm_bw
+            bound = "memory" if t_memory >= t_compute else "compute"
+
+        # concrete even when called while an enclosing jit is tracing
+        with jax.ensure_compile_time_eval():
+            ex = [jnp.zeros(sp.shape, sp.dtype) for sp in specs]
 
         def timed(compiled) -> float:
             jax.block_until_ready(compiled(*ex))      # warmup
@@ -398,7 +408,8 @@ class KernelChooser:
         if interpreted:
             backend, reason = "xla", ("interpret-mode pallas timing recorded "
                                       "for reporting only")
-        elif abs(t_pallas - t_xla) <= CALIBRATION_TIE_BAND * max(t_pallas, t_xla):
+        elif (bound != "unknown" and abs(t_pallas - t_xla)
+              <= CALIBRATION_TIE_BAND * max(t_pallas, t_xla)):
             backend = "pallas" if bound == "memory" else "xla"
             reason = f"measured tie (<{CALIBRATION_TIE_BAND:.0%}); roofline {bound}-bound"
         elif t_pallas < t_xla:
@@ -418,8 +429,8 @@ class KernelChooser:
             kernel=name, layout=_layout_key(args, kwargs),
             device=_device_key(), backend="xla",
             t_pallas_s=float("inf"), t_xla_s=float("inf"),
-            t_compute_est_s=0.0, t_memory_est_s=0.0, bound="memory",
-            interpreted=True, reason=reason))
+            t_compute_est_s=float("nan"), t_memory_est_s=float("nan"),
+            bound="unknown", interpreted=True, reason=reason))
 
     def _store(self, name, args, kwargs, rec: KernelCalibration) -> KernelCalibration:
         key = (_CALIB_TAG, name, _layout_key(args, kwargs), _device_key())

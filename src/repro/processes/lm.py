@@ -281,7 +281,6 @@ class DecodeStep(_LMProcess):
         slot_axes = [self._slot_axis(leaf, b) for leaf in leaves]
         nm = model_axis_size(mesh) if ax == "model" else 1
         if nm > 1 and b % nm == 0 and all(a is not None for a in slot_axes):
-            from jax.experimental.shard_map import shard_map
             P = jax.sharding.PartitionSpec
             cache_specs = tuple(
                 P(*([None] * a + [ax])) for a in slot_axes)
@@ -293,11 +292,11 @@ class DecodeStep(_LMProcess):
                                         cache, pos)
                 return (t, p, act) + tuple(jax.tree_util.tree_leaves(cache))
 
-            outs = shard_map(
+            outs = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(ax, None), P(ax), P(ax)) + cache_specs,
                 out_specs=(P(ax, None), P(ax), P(ax)) + cache_specs,
-                check_rep=False)(w, token, positions, active, *leaves)
+                check_vma=False)(w, token, positions, active, *leaves)
             token, positions, active = outs[0], outs[1], outs[2]
             cache = jax.tree_util.tree_unflatten(treedef, outs[3:])
         else:

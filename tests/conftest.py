@@ -1,45 +1,13 @@
-"""Shared fixtures.  ``hypothesis`` is optional: network-less containers
-cannot install it, so when it is missing a minimal stand-in module is
-registered that auto-skips every ``@given`` test (and accepts any strategy
-expression) instead of killing collection with an ImportError."""
-import sys
-import types
-
+"""Shared fixtures and the hypothesis profile."""
 import numpy as np
 import pytest
+from hypothesis import HealthCheck, settings
 
-try:
-    from hypothesis import HealthCheck, settings
-except ModuleNotFoundError:
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def skipper():
-                pytest.skip("hypothesis not installed")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
-
-    _strategies = types.ModuleType("hypothesis.strategies")
-    # any strategy constructor (st.lists, st.integers, ...) -> opaque object
-    _strategies.__getattr__ = lambda name: (lambda *a, **k: None)
-
-    _hypothesis = types.ModuleType("hypothesis")
-    _hypothesis.given = _given
-    _hypothesis.strategies = _strategies
-    _hypothesis.HealthCheck = types.SimpleNamespace(
-        too_slow=None, data_too_large=None)
-    _hypothesis.settings = types.SimpleNamespace(
-        register_profile=lambda *a, **k: None,
-        load_profile=lambda *a, **k: None)
-    sys.modules["hypothesis"] = _hypothesis
-    sys.modules["hypothesis.strategies"] = _strategies
-else:
-    # CPU in this container is slow and single-core; disable deadlines globally.
-    settings.register_profile(
-        "repro", deadline=None, max_examples=25,
-        suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large])
-    settings.load_profile("repro")
+# CPU in this container is slow and single-core; disable deadlines globally.
+settings.register_profile(
+    "repro", deadline=None, max_examples=25,
+    suppress_health_check=[HealthCheck.too_slow, HealthCheck.data_too_large])
+settings.load_profile("repro")
 
 
 @pytest.fixture

@@ -88,3 +88,14 @@ def test_property_roundtrip(specs):
     for (s0, e0), (s1, _) in zip(spans, spans[1:]):
         assert e0 <= s1
     assert spans[-1][1] <= layout.total_bytes
+
+
+def test_device_view_offsets_past_int32():
+    """Arenas over 8 GiB: an entry past 2**31 words is sliced at its exact
+    offset, never through a wrapped int32 start index."""
+    from repro.core.arena import ArenaEntry, device_view
+    e = ArenaEntry("w", (1000, 1000), "bfloat16", 12_000_000_000, 2_000_000)
+    text = jax.jit(lambda b: device_view(b, e)).lower(
+        jax.ShapeDtypeStruct((3_100_000_000,), np.uint32)).as_text()
+    assert "3000000000:3000500000" in text
+    assert "dynamic_slice" not in text

@@ -1,9 +1,6 @@
 """Property-based round-trips for the arena packers and the sharded
-checkpoint format (PR 10 satellite).
-
-``hypothesis`` is optional (see ``conftest.py``): when it is missing the
-``@given`` tests auto-skip; the plain tests below them always run, so the
-dtype-preserving-empty-leaf contract is pinned in tier-1 either way.
+checkpoint format, plus plain pins of the dtype-preserving empty-leaf
+contract.
 
 Properties under test:
 
@@ -78,7 +75,7 @@ def test_plan_layout_alignment_and_disjointness(data):
 def test_pack_unpack_host_roundtrip(data):
     arrays = _draw_arrays(data)
     blob, layout = pack_host(arrays)
-    assert blob.dtype == np.uint8 and blob.nbytes == layout.total_bytes
+    assert blob.dtype == np.uint32 and blob.nbytes == layout.total_bytes
     back = unpack_host(blob, layout)
     assert set(back) == set(arrays)
     for k, v in arrays.items():
@@ -169,6 +166,7 @@ def test_zero_copy_unpack_views(tmp_path):
     blob, layout = pack_host(arrays)
     views = unpack_host(blob, layout)
     assert views["a"].base is not None
-    blob[layout.entry("a").offset:layout.entry("a").offset + 4] = \
+    off = layout.entry("a").offset      # bytes into the word blob
+    blob.view(np.uint8)[off:off + 4] = \
         np.frombuffer(np.float32(99.0).tobytes(), np.uint8)
     assert views["a"][0] == 99.0

@@ -757,6 +757,26 @@ def test_recon_2d_bit_identical_three_modes(rng):
 
 
 @needs_8_devices
+def test_recon_2d_outputs_resident_on_every_device(rng):
+    """On a (data=2, model=4) mesh each streamed item is computed by a
+    whole model group and stays resident on all of it: the per-item
+    outputs together span every selected device, not one per group."""
+    from repro.processes import SimpleMRIRecon
+
+    def _c(shape):
+        return (rng.standard_normal(shape)
+                + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    inputs = [KData({"kdata": _c((8, 3, 16, 16)),
+                     "sensitivity_maps": _c((3, 16, 16))}) for _ in range(4)]
+    app = CLapp().init(model_axis=4)
+    pipe = Pipeline(app) | SimpleMRIRecon(app, mode="fused_pallas")
+    outs = pipe.run(inputs, mode="stream", batch=2, sharded=True)
+    per_item = [set(o.device_blob.devices()) for o in outs]
+    assert all(len(d) == 4 for d in per_item)
+    assert set().union(*per_item) == set(app.devices)
+
+
+@needs_8_devices
 def test_decode_2d_bit_identical():
     """DecodeStep on a (2, 4) mesh: the B=4 decode batch shard_maps one
     slot per model-group device (position via exact integer pmax) and the

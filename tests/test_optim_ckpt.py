@@ -127,7 +127,8 @@ def test_elastic_restore_resharding(tmp_path, rng):
     from jax.sharding import NamedSharding, PartitionSpec as P
     state = _state(rng)
     save_checkpoint(str(tmp_path), 3, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(lambda a: NamedSharding(mesh, P()), state)
     back = restore_checkpoint(str(tmp_path), jax.tree.map(np.zeros_like, state),
                               shardings=sh)

@@ -17,7 +17,8 @@ from repro.configs import get_smoke
 from repro.models import build_model
 from repro.models.common import mesh_axes
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 checks = []
 

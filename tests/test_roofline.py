@@ -92,3 +92,23 @@ def test_default_microbatches_scaling():
     assert default_microbatches(qwen, SHAPES["train_4k"]) >= \
         default_microbatches(granite, SHAPES["train_4k"])
     assert default_microbatches(qwen, SHAPES["decode_32k"]) == 1
+
+
+def test_peak_table_unknown_kind_leaves_bound_unknown(monkeypatch):
+    """Peaks are looked up by ``device_kind``; a kind with no row (the CPU
+    here) gets no bound, and adding a row for it is what gives one."""
+    import repro.kernels.coil_combine  # noqa: F401  (registers xImageSum)
+    from repro.launch import roofline
+    kind = jax.devices()[0].device_kind
+    chooser = roofline.KernelChooser(reps=1)
+    monkeypatch.setattr(roofline, "PEAKS", {})
+    rec = chooser.calibrate("xImageSum", jnp.zeros((1, 2, 8, 8), jnp.complex64),
+                            force_timing=True)
+    assert rec.bound == "unknown"
+    assert np.isnan(rec.t_compute_est_s) and np.isnan(rec.t_memory_est_s)
+    # a new layout (so no cached verdict) on a kind the table now holds
+    monkeypatch.setattr(roofline, "PEAKS", {kind: roofline.V5E})
+    rec = chooser.calibrate("xImageSum", jnp.zeros((1, 2, 8, 16), jnp.complex64),
+                            force_timing=True)
+    assert rec.bound in ("compute", "memory")
+    assert rec.t_memory_est_s > 0

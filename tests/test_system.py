@@ -105,7 +105,8 @@ import jax
 from repro.launch.dryrun import run_cell
 from repro.configs import get_smoke
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke("granite-moe-1b-a400m").scaled(param_dtype="bfloat16",
                                                dtype="bfloat16")
 rec = run_cell("granite-moe-1b-a400m", "train_4k", mesh=mesh, verbose=False,
@@ -129,3 +130,51 @@ def test_dryrun_pipeline_subprocess():
     rec = json.loads(line[len("RESULT "):])
     assert rec["status"] == "ok"
     assert rec["flops"] > 0 and rec["coll"] >= 0
+
+
+def test_compile_cache_dir_follows_env(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache is
+    the fixed ``<checkout>/.jax_cache`` (never a temp, pid or time name)."""
+    from repro.core import compile_cache_dir
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == os.path.join(root, ".jax_cache")
+    assert compile_cache_dir() == compile_cache_dir()
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_refuses_cpu():
+    """``chip_smoke.py`` never runs on the CPU: it exits non-zero, names
+    the platform it found and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(_ROOT, "chip_smoke.py")],
+                         env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode != 0
+    assert "JAX found 1 cpu device" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_phases_at_smoke_size(monkeypatch):
+    """The smoke script's phases, run on the CPU at smoke sizes (interpret
+    mode), so the script keeps working between chip runs."""
+    import dataclasses
+    import importlib.util
+
+    from repro.configs.h2o_danube_1_8b import SMOKE as DANUBE_SMOKE
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
+    cs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    for name, value in (("N_SCANS", 4), ("SCAN_BATCH", 2), ("DFT_SIZE", 32),
+                        ("LM_PROMPT", 8), ("LM_NEW", 6), ("LM_MAX_LEN", 32)):
+        monkeypatch.setattr(cs, name, value)
+    app = CLapp().init(device_traits=DeviceTraits(count=1))
+    mri = dataclasses.replace(MRI_SMOKE, height=32, width=32)
+    cs.mri_modes(app, mri, 0)
+    cs.mri_stream_serve(app, mri, 1)
+    cs.mri_dft(app, mri, 0)
+    cs.lm_serve(DANUBE_SMOKE, 0)
