@@ -178,7 +178,7 @@ def lm_serve(cfg, seed: int) -> None:
     against a greedy loop of full forwards with the same weights."""
     import jax
 
-    from repro.core import CLapp, DeviceTraits
+    from repro.core import CLapp, DeviceTraits, trace
     from repro.models import build_model
     from repro.serve import LMServer, SamplingConfig
     app = CLapp().init(device_traits=DeviceTraits(count=1))
@@ -204,6 +204,7 @@ def lm_serve(cfg, seed: int) -> None:
     t_build = time.perf_counter() - t0
     for p in prompts:
         server.submit(p.tolist())
+    before = {c.span.id for c in trace.calls("lm.step")}
     t0 = time.perf_counter()
     outs = server.run()
     t_run = time.perf_counter() - t0
@@ -211,7 +212,9 @@ def lm_serve(cfg, seed: int) -> None:
         raise RuntimeError(f"LMServer answered {[len(o) for o in outs]} "
                            f"tokens, expected {LM_NEW} each")
     tokens = np.asarray(outs, np.int32)
-    step_s = server.decode_profile.p50()
+    steady = sorted(c.duration_s for c in trace.calls("lm.step")
+                    if c.span.id not in before and "lm.admit" not in c.counts)
+    step_s = steady[len(steady) // 2]
     log(f"[lm] LMServer batch={LM_BATCH} max_len={LM_MAX_LEN}: {LM_BATCH} "
         f"requests of {LM_PROMPT} prompt tokens, {LM_NEW} tokens each, "
         f"{server.steps} decode steps")

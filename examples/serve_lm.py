@@ -6,8 +6,8 @@ behind the legacy ``ServeEngine`` wrapper):
 
 * the KV cache is ONE persistent arena-backed Data — device-resident and
   donated from step to step, so after the one-time zero-state upload the
-  cache edge moves zero bytes host<->device (the decode profile's phase
-  breakdown proves it below);
+  cache edge moves zero bytes host<->device (the ``repro_h2d_bytes_total``
+  counter over each steady decode step proves it below);
 * each queued prompt claims a free slot via a single-row prefill Pipeline
   plus an in-place cache splice, joining the in-flight decode batch;
 * whisper requests carry per-request audio frames, and their prefill graph
@@ -25,7 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke
-from repro.core import enable_compile_cache
+from repro.core import enable_compile_cache, trace
 from repro.models import build_model
 from repro.serve import CallableReplica, FrontDoor, LMServer, SamplingConfig
 
@@ -44,6 +44,7 @@ def serve_transformer() -> None:
     for p in prompts:
         server.submit(p)
 
+    before = {c.span.id for c in trace.calls("lm.step")}
     t0 = time.perf_counter()
     outputs = server.run()
     dt = time.perf_counter() - t0
@@ -54,10 +55,12 @@ def serve_transformer() -> None:
     for i, o in enumerate(outputs[:4]):
         print(f"  request {i}: {len(o)} tokens -> {o[:8]}...")
     assert all(len(o) > 0 for o in outputs)
-    transfer = server.decode_profile.phase_total("transfer")
-    print(f"  decode-side host2device on the cache edge: {transfer:.6f}s "
-          f"over {server.steps} steps")
-    assert transfer == 0.0
+    steady = [c for c in trace.calls("lm.step")
+              if c.span.id not in before and "lm.admit" not in c.counts]
+    h2d = sum(c.deltas["repro_h2d_bytes_total"] for c in steady)
+    print(f"  host2device bytes over {len(steady)} steady decode steps: "
+          f"{h2d:.0f}")
+    assert steady and h2d == 0
 
 
 def serve_whisper() -> None:

@@ -4,6 +4,7 @@ Public API mirrors OpenCLIPER's class names (CLapp, Data, XData, KData,
 NDArray, Process) with JAX/TPU semantics.  See the paper->JAX concept map
 in README.md and the layer guide in docs/architecture.md.
 """
+from . import trace
 from .app import (
     CLapp,
     CLIPERApp,
@@ -61,6 +62,6 @@ __all__ = [
     "batched_spec", "compile_cache_dir", "compile_cache_stats",
     "device_view", "enable_compile_cache", "kernel",
     "pack_device", "pack_host", "pack_tree_host", "plan_layout",
-    "split_batched_blob", "stack_host_blobs", "stream_launch",
+    "split_batched_blob", "stack_host_blobs", "stream_launch", "trace",
     "unpack_device", "unpack_host", "unpack_tree_host",
 ]

@@ -36,6 +36,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from . import trace
 from .arena import blob_spec
 from .data import Data
 from .registry import KernelRegistry
@@ -318,6 +319,7 @@ class CLapp:
             coherence = Coherence.DEVICE_FRESH
         data.device_blob = jax.device_put(
             blob, sharding if sharding is not None else self.default_sharding)
+        trace.H2D_BYTES.inc(blob.nbytes)
         data.donated_by = None  # explicit re-upload resurrects a donated Data
         if wait:
             self._in_flight.pop(handle, None)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .arena import ArenaLayout, pack_host, plan_layout, unpack_device, unpack_host
 from .sync import Coherence, SyncSource, resolve_source
 
@@ -248,10 +249,12 @@ class Data:
             if self.donated_by is not None:
                 self._raise_donated()
             raise ValueError("no device buffer to sync from")
-        blob = np.asarray(self.device_blob)
-        views = unpack_host(blob, self.layout)
-        for a in self._arrays:
-            a.set_host(views[a.name])
+        with trace.span("data.to_host", bytes=self.device_blob.nbytes):
+            blob = np.asarray(self.device_blob)
+            trace.D2H_BYTES.inc(blob.nbytes)
+            views = unpack_host(blob, self.layout)
+            for a in self._arrays:
+                a.set_host(views[a.name])
         self.coherence = Coherence.IN_SYNC
 
     def authoritative(self, sync: SyncSource = SyncSource.AUTO) -> str:

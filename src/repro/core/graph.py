@@ -65,6 +65,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 
+from . import trace
 from .app import CLapp, DataHandle
 from .data import Data
 from .process import (Port, PortError, Process, ProcessChain,
@@ -810,75 +811,80 @@ class Pipeline:
         modes and to the legacy imperative protocol, and a streamed join is
         bit-identical to the same port bound as a static aux broadcast.
         """
-        if mode == "launch":
-            if inputs is not None and not isinstance(
-                    inputs, (Data, Mapping, tuple)):
-                raise TypeError(
-                    f"mode='launch' takes one Data (or a {{edge: Data}} "
-                    f"mapping / positional tuple for fan-in graphs), got "
-                    f"{type(inputs).__name__}; use mode='stream' for "
-                    "sequences")
-            built = self.build(inputs)
-            app = self.app
-            sources = self._example_inputs(inputs)
-            t_up = time.perf_counter()
-            uploaded = []
-            for edge in built.input_edges:
-                src = sources[edge]
-                d_reg = app.getData(built.input_handles[edge])
-                if src is not d_reg:
-                    self._copy_into(d_reg, src, edge=edge)
-                    app.host2device(built.input_handles[edge])
-                    uploaded.append(edge)
-                elif d_reg.device_blob is None:
-                    # handle-bound input: the caller manages the registered
-                    # Data; only transfer if it has never reached the device
-                    app.host2device(built.input_handles[edge])
-                    uploaded.append(edge)
-            if uploaded and profile is not None and profile.enable:
-                # phase covers the landed transfers: with the residency plan
-                # these graph-input uploads are the ONLY host2device traffic
-                # of the whole chain (internal edges stay device-resident)
-                for edge in uploaded:
-                    jax.block_until_ready(
-                        app.getData(built.input_handles[edge]).device_blob)
-                profile.record_phase("transfer", time.perf_counter() - t_up)
-            built.executor.launch(profile)
-            out = app.getData(built.output_handle)
-            if sync:
-                out.sync_to_host()
-            return out
-        if mode == "stream":
-            datasets = list(inputs or ())
-            if not datasets:
-                return []
-            built = self.build(datasets[0])
-            items = [self._item_tuple(built, d, what=f"inputs[{i}]")
-                     for i, d in enumerate(datasets)]
-            return built.executor.stream(
-                items, batch=batch, depth=depth, sync=sync,
-                sharded=sharded, tail_waste_threshold=tail_waste_threshold,
-                split=split, lanes=lanes, profile=profile)
-        if mode == "serve":
-            requests = list(inputs or ())
-            if not requests:
-                return []
-            server = self.serve(batch=batch, sharded=sharded, depth=depth,
-                                tail_waste_threshold=tail_waste_threshold,
-                                split=split, lanes=lanes)
-            rids = [server.submit(d) for d in requests]
-            by_rid = {r.rid: r for r in server.drain()}
-            outs = []
-            for rid in rids:
-                resp = by_rid[rid]
-                if profile is not None and profile.enable:
-                    profile.record(resp.latency_s)
+        with trace.span("pipeline.run", mode=mode):
+            if mode == "launch":
+                if inputs is not None and not isinstance(
+                        inputs, (Data, Mapping, tuple)):
+                    raise TypeError(
+                        f"mode='launch' takes one Data (or a {{edge: Data}} "
+                        f"mapping / positional tuple for fan-in graphs), got "
+                        f"{type(inputs).__name__}; use mode='stream' for "
+                        "sequences")
+                built = self.build(inputs)
+                app = self.app
+                sources = self._example_inputs(inputs)
+                t_up = time.perf_counter()
+                uploaded = []
+                for edge in built.input_edges:
+                    src = sources[edge]
+                    d_reg = app.getData(built.input_handles[edge])
+                    if src is not d_reg:
+                        self._copy_into(d_reg, src, edge=edge)
+                        app.host2device(built.input_handles[edge])
+                        uploaded.append(edge)
+                    elif d_reg.device_blob is None:
+                        # handle-bound input: the caller manages the
+                        # registered Data; only transfer if it has never
+                        # reached the device
+                        app.host2device(built.input_handles[edge])
+                        uploaded.append(edge)
+                if uploaded and profile is not None and profile.enable:
+                    # phase covers the landed transfers: with the residency
+                    # plan these graph-input uploads are the ONLY
+                    # host2device traffic of the whole chain (internal
+                    # edges stay device-resident)
+                    for edge in uploaded:
+                        jax.block_until_ready(
+                            app.getData(built.input_handles[edge]).device_blob)
+                    profile.record_phase("transfer",
+                                         time.perf_counter() - t_up)
+                built.executor.launch(profile)
+                out = app.getData(built.output_handle)
                 if sync:
-                    resp.data.sync_to_host()
-                outs.append(resp.data)
-            return outs
-        raise ValueError(f"unknown mode {mode!r}: expected "
-                         "'launch' | 'stream' | 'serve'")
+                    out.sync_to_host()
+                return out
+            if mode == "stream":
+                datasets = list(inputs or ())
+                if not datasets:
+                    return []
+                with trace.span("stream.plan"):
+                    built = self.build(datasets[0])
+                    items = [self._item_tuple(built, d, what=f"inputs[{i}]")
+                             for i, d in enumerate(datasets)]
+                return built.executor.stream(
+                    items, batch=batch, depth=depth, sync=sync,
+                    sharded=sharded, tail_waste_threshold=tail_waste_threshold,
+                    split=split, lanes=lanes, profile=profile)
+            if mode == "serve":
+                requests = list(inputs or ())
+                if not requests:
+                    return []
+                server = self.serve(batch=batch, sharded=sharded, depth=depth,
+                                    tail_waste_threshold=tail_waste_threshold,
+                                    split=split, lanes=lanes)
+                rids = [server.submit(d) for d in requests]
+                by_rid = {r.rid: r for r in server.drain()}
+                outs = []
+                for rid in rids:
+                    resp = by_rid[rid]
+                    if profile is not None and profile.enable:
+                        profile.record(resp.latency_s)
+                    if sync:
+                        resp.data.sync_to_host()
+                    outs.append(resp.data)
+                return outs
+            raise ValueError(f"unknown mode {mode!r}: expected "
+                             "'launch' | 'stream' | 'serve'")
 
     def serve(self, *, batch: int = 8, sharded: bool = False, depth: int = 2,
               tail_waste_threshold: float = 0.5, split: str = "equal",

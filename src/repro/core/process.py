@@ -81,6 +81,8 @@ would silently hand the caller's live blob to XLA); see
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 import time
 import warnings
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -89,6 +91,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .app import CLapp, DataHandle, INVALID_HANDLE
 from .arena import ArenaLayout, blob_spec, pack_device, unpack_device
 from .sync import Coherence
@@ -261,9 +264,19 @@ def current_compile_mesh():
 
 
 def compile_cache_stats() -> Tuple[int, int]:
-    hits = _COMPILE_CACHE.get("__hits__", 0)
-    misses = _COMPILE_CACHE.get("__misses__", 0)
-    return hits, misses
+    """``(hits, misses)`` of :func:`aot_compile`'s executable cache over
+    the process's life (``repro_compile_cache_{hits,misses}_total``)."""
+    return (int(trace.CACHE_HITS.total()), int(trace.CACHE_MISSES.total()))
+
+
+def program_name(tag: str) -> str:
+    """The name of the function :func:`aot_compile` jits for ``tag``: the
+    tag without its leading module path, the characters XLA does not keep
+    in a module name (``[]@=,:`` and the like) replaced by ``_``.  So a
+    program in a profiler trace reads ``jit_DecodeStep`` or
+    ``jit_ProcessChain_FusedMRIRecon_vmap``, not ``jit_fn``."""
+    name = re.sub(r"^(?:\w+\.)+(?=\w)", "", tag)
+    return re.sub(r"[^\w.-]+", "_", name).strip("_") or "program"
 
 
 def _sharding_key(sharding) -> Any:
@@ -315,15 +328,21 @@ def aot_compile(fn: Callable, specs: Sequence[Any], *, tag: str,
                      in_shardings, out_shardings)
     cached = _COMPILE_CACHE.get(key)
     if cached is not None:
-        _COMPILE_CACHE["__hits__"] = _COMPILE_CACHE.get("__hits__", 0) + 1
+        trace.CACHE_HITS.inc()
         return cached
-    _COMPILE_CACHE["__misses__"] = _COMPILE_CACHE.get("__misses__", 0) + 1
+    trace.CACHE_MISSES.inc()
     kwargs: Dict[str, Any] = {}
     if in_shardings is not None:
         kwargs["in_shardings"] = in_shardings
     if out_shardings is not None:
         kwargs["out_shardings"] = out_shardings
-    jitted = jax.jit(fn, donate_argnums=donate_argnums, **kwargs)
+
+    @functools.wraps(fn)
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = program_name(tag)
+    jitted = jax.jit(named, donate_argnums=donate_argnums, **kwargs)
     t0 = time.perf_counter()
     global _CURRENT_COMPILE_MESH
     prev_mesh = _CURRENT_COMPILE_MESH
@@ -759,53 +778,59 @@ class Process:
 
     def launch(self, profile: ProfileParameters | None = None) -> None:
         """Hot path: execute the compiled program.  No tracing, no transfer."""
-        if not self._initialized or self._compiled is None:
-            self.init()  # lazily init, but callers should init() explicitly
-        self._check_donation()
-        app = self.getApp()
-        # input and aux handles are read live (not snapshotted at init) so
-        # re-wiring to a same-layout Data between launches takes effect, as
-        # it always did; order matches launchable()'s positional order
-        in_blobs = []
-        in_datas = []
-        t_up = time.perf_counter()
-        uploaded = False
-        for name in self._compiled_in_names:
-            d = app.getData(self.in_handles[name])
-            if d.device_blob is None:
-                if d.donated_by is not None and \
-                        d.coherence is not Coherence.HOST_FRESH:
-                    # re-uploading would fabricate a zero blob for a buffer
-                    # a downstream stage consumed; fail with graph context
-                    d._raise_donated()
-                app.host2device(self.in_handles[name])
-                uploaded = True
-            in_blobs.append(d.device_blob)
-            in_datas.append(d)
-        aux_blobs = []
-        for h in self._current_aux_handles():
-            d = app.getData(h)
-            if d.device_blob is None:
-                app.host2device(h)
-                uploaded = True
-            aux_blobs.append(d.device_blob)
-        blobs, moved = _conform_blobs(self._compiled, in_blobs + aux_blobs)
-        if (uploaded or moved) and profile is not None and profile.enable:
-            profile.record_phase("transfer", time.perf_counter() - t_up)
-        t0 = time.perf_counter()
-        out_blob = self._compiled(*blobs)
-        if profile is not None and profile.enable:
-            jax.block_until_ready(out_blob)
-            dt = time.perf_counter() - t0
-            profile.record(dt)
-            profile.record_phase("compute", dt)
-        if self._compiled_donate_name is not None:
-            # the donated input's blob is dead; mark it so a later read
-            # raises DonatedBufferError with this stage's graph context
-            in_datas[
-                self._compiled_in_names.index(self._compiled_donate_name)
-            ].mark_donated(self.graph_name or type(self).__name__)
-        app._set_device_blob(self.out_handle, out_blob)
+        with trace.span("process.launch",
+                        process=self.graph_name or type(self).__name__):
+            if not self._initialized or self._compiled is None:
+                self.init()  # lazily; callers should init() explicitly
+            self._check_donation()
+            app = self.getApp()
+            # input and aux handles are read live (not snapshotted at init)
+            # so re-wiring to a same-layout Data between launches takes
+            # effect, as it always did; order matches launchable()'s
+            # positional order
+            in_blobs = []
+            in_datas = []
+            t_up = time.perf_counter()
+            uploaded = False
+            for name in self._compiled_in_names:
+                d = app.getData(self.in_handles[name])
+                if d.device_blob is None:
+                    if d.donated_by is not None and \
+                            d.coherence is not Coherence.HOST_FRESH:
+                        # re-uploading would fabricate a zero blob for a
+                        # buffer a downstream stage consumed; fail with
+                        # graph context
+                        d._raise_donated()
+                    app.host2device(self.in_handles[name])
+                    uploaded = True
+                in_blobs.append(d.device_blob)
+                in_datas.append(d)
+            aux_blobs = []
+            for h in self._current_aux_handles():
+                d = app.getData(h)
+                if d.device_blob is None:
+                    app.host2device(h)
+                    uploaded = True
+                aux_blobs.append(d.device_blob)
+            blobs, moved = _conform_blobs(self._compiled,
+                                          in_blobs + aux_blobs)
+            if (uploaded or moved) and profile is not None \
+                    and profile.enable:
+                profile.record_phase("transfer", time.perf_counter() - t_up)
+            t0 = time.perf_counter()
+            out_blob = self._compiled(*blobs)
+            if profile is not None and profile.enable:
+                jax.block_until_ready(out_blob)
+                dt = time.perf_counter() - t0
+                profile.record(dt)
+                profile.record_phase("compute", dt)
+            if self._compiled_donate_name is not None:
+                # the donated input's blob is dead; mark it so a later read
+                # raises DonatedBufferError with this stage's graph context
+                in_datas[
+                    self._compiled_in_names.index(self._compiled_donate_name)
+                ].mark_donated(self.graph_name or type(self).__name__)
+            app._set_device_blob(self.out_handle, out_blob)
 
     # -- streaming (beyond paper; see repro.core.stream) -----------------------
     def stream(self, datasets: Sequence[Any], batch: int = 1, *,
@@ -968,7 +993,8 @@ class ProcessChain(Process):
             in_handles=tuple(chain_inputs),
             out_layout=out_layout,
             aux_handles=tuple(aux_handles),
-            tag=f"ProcessChain[{len(parts)}]",
+            tag="ProcessChain[" + ",".join(
+                type(s).__name__ for s, *_ in parts) + "]",
             static_key=tuple(static_parts),
             donate_idx=(chain_inputs.index(last_out)
                         if last_out in chain_inputs else None),

@@ -152,6 +152,7 @@ from typing import (Any, Iterable, Iterator, List, Mapping, Optional,
 import jax
 import numpy as np
 
+from . import trace
 from .arena import (batched_spec, blob_spec, split_batched_blob,
                     stack_host_blobs)
 from .data import Data
@@ -193,7 +194,7 @@ class StreamQueue:
         self._it = iter(items)
         self._device = device
         self._place = device if callable(device) else \
-            (lambda item: jax.device_put(item, device))
+            (lambda item: _put(item, device))
         self._depth = depth
         self._profile = profile
         self._fifo: deque = deque()
@@ -276,6 +277,17 @@ class StreamQueue:
             if blob is not None and not _is_deleted(blob):
                 jax.block_until_ready(blob)
         self._issued.clear()
+
+
+def _put(blob: Any, target: Any) -> jax.Array:
+    """One placement dispatch (``jax.device_put``), recorded as a
+    ``stream.place`` span; a host array's bytes count as host-to-device
+    traffic."""
+    nbytes = blob.nbytes if isinstance(blob, np.ndarray) else 0
+    with trace.span("stream.place", bytes=nbytes):
+        out = jax.device_put(blob, target)
+    trace.H2D_BYTES.inc(nbytes)
+    return out
 
 
 def _is_deleted(blob: jax.Array) -> bool:
@@ -743,9 +755,10 @@ class _BatchPlan:
         group — and attached to every edge's stack, so a join's edges can
         never disagree on the carve."""
         rows = self.launch_rows(len(items))
-        stacks = [
-            _stack_blobs(_pad_rows([it[e] for it in items], rows), lay)
-            for e, lay in enumerate(self.launchable.in_layouts)]
+        with trace.span("stream.stack", rows=rows):
+            stacks = [
+                _stack_blobs(_pad_rows([it[e] for it in items], rows), lay)
+                for e, lay in enumerate(self.launchable.in_layouts)]
         if not self.per_device:
             return stacks
         split = self.split_vector(rows)
@@ -759,13 +772,13 @@ class _BatchPlan:
         async ``device_put`` per device with a non-zero share)."""
         if not isinstance(item, _SplitStack):
             target = self.batch_sharding or self.process.getApp().device
-            return jax.device_put(item, target)
+            return _put(item, target)
         parts, counts, devices = [], [], []
         off = 0
         for dev, c in zip(self._devices, item.split):
             if c:
                 sharding = self.device_executable(dev, c).batch_sharding
-                parts.append(jax.device_put(item.blob[off:off + c], sharding))
+                parts.append(_put(item.blob[off:off + c], sharding))
                 counts.append(c)
                 devices.append(dev)
             off += c
@@ -779,23 +792,24 @@ class _BatchPlan:
         the devices compute concurrently, with a completion timer per
         device feeding measured items/sec back into the registry (and the
         ``"compute"`` phase bucket when the plan carries a profile)."""
-        if not isinstance(dev_blobs[0], SplitBatch):
-            t0 = time.perf_counter()
-            out = self.executable(int(dev_blobs[0].shape[0]))(
-                tuple(dev_blobs), aux_blobs)
-            if self.profile is not None and self.profile.enable:
-                self._time_completion(None, 0, t0, out)
-            return out
-        sb0 = dev_blobs[0]
-        out_parts = []
-        for j, (dev, c) in enumerate(zip(sb0.devices, sb0.counts)):
-            bp = self.device_executable(dev, c)       # may compile (cached)
-            aux = self._device_aux(dev, aux_blobs)
-            t0 = time.perf_counter()
-            out = bp(tuple(sb.parts[j] for sb in dev_blobs), aux)
-            out_parts.append(out)
-            self._time_completion(dev, c, t0, out)
-        return SplitBatch(out_parts, sb0.counts, sb0.devices)
+        with trace.span("stream.launch"):
+            if not isinstance(dev_blobs[0], SplitBatch):
+                t0 = time.perf_counter()
+                out = self.executable(int(dev_blobs[0].shape[0]))(
+                    tuple(dev_blobs), aux_blobs)
+                if self.profile is not None and self.profile.enable:
+                    self._time_completion(None, 0, t0, out)
+                return out
+            sb0 = dev_blobs[0]
+            out_parts = []
+            for j, (dev, c) in enumerate(zip(sb0.devices, sb0.counts)):
+                bp = self.device_executable(dev, c)   # may compile (cached)
+                aux = self._device_aux(dev, aux_blobs)
+                t0 = time.perf_counter()
+                out = bp(tuple(sb.parts[j] for sb in dev_blobs), aux)
+                out_parts.append(out)
+                self._time_completion(dev, c, t0, out)
+            return SplitBatch(out_parts, sb0.counts, sb0.devices)
 
     def split_output(self, out: Any) -> List[jax.Array]:
         """Per-item output blobs of one launched group, in item order."""
@@ -1159,18 +1173,20 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
     if not datasets:
         return []
     app = process.getApp()
-    plan = _BatchPlan(process, batch, sharded=sharded,
-                      tail_waste_threshold=tail_waste_threshold,
-                      split=split, lanes=lanes, profile=profile).init()
-    la = plan.launchable
+    with trace.span("stream.plan"):
+        plan = _BatchPlan(process, batch, sharded=sharded,
+                          tail_waste_threshold=tail_waste_threshold,
+                          split=split, lanes=lanes, profile=profile).init()
+        la = plan.launchable
 
-    aux_blobs = plan.prepare_aux()
+        aux_blobs = plan.prepare_aux()
 
-    tail = len(datasets) % batch
-    if tail:
-        # compile the tail executable(s) (if the policy wants them) BEFORE
-        # the launch loop, so compilation never stalls the double buffer
-        plan.precompile(tail)
+        tail = len(datasets) % batch
+        if tail:
+            # compile the tail executable(s) (if the policy wants them)
+            # BEFORE the launch loop, so compilation never stalls the
+            # double buffer
+            plan.precompile(tail)
 
     # one row-aligned feed per input edge — a multi-input launchable gets
     # per-edge StreamQueues whose batches are zipped before each launch.
@@ -1180,8 +1196,11 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
         buf: List[Tuple[np.ndarray, ...]] = []
         for i, d in enumerate(datasets):
             what = f"datasets[{i}]"
-            buf.append(_edge_blobs(normalize_stream_item(d, la, what=what),
-                                   la, what=what))
+            with trace.span("stream.pack", item=i) as s:
+                blobs = _edge_blobs(normalize_stream_item(d, la, what=what),
+                                    la, what=what)
+                s.attrs["bytes"] = sum(b.nbytes for b in blobs)
+            buf.append(blobs)
             if len(buf) == batch:
                 yield buf
                 buf = []
@@ -1210,16 +1229,17 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
     # per-item output blobs: rows sliced shard-locally, so with sharded=True
     # (and per-device under split="proportional") each item's result stays
     # on the device that computed it
-    per_item: List[jax.Array] = []
-    for b in out_batches:
-        per_item.extend(plan.split_output(b))
+    with trace.span("stream.split"):
+        per_item: List[jax.Array] = []
+        for b in out_batches:
+            per_item.extend(plan.split_output(b))
 
-    results: List[Data] = []
-    for i in range(len(datasets)):
-        out = Data.from_layout(la.out_layout)
-        out.device_blob = per_item[i]
-        out.coherence = Coherence.DEVICE_FRESH
-        results.append(out)
+        results: List[Data] = []
+        for i in range(len(datasets)):
+            out = Data.from_layout(la.out_layout)
+            out.device_blob = per_item[i]
+            out.coherence = Coherence.DEVICE_FRESH
+            results.append(out)
     if sync:
         for r in results:
             r.sync_to_host()          # np.asarray blocks per result

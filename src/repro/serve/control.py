@@ -29,9 +29,11 @@ as much framework housekeeping as device selection was:
   after every completed batch, so the split across replicas
   self-calibrates exactly like the proportional batch split does across
   devices.
-* **Observability** — a :class:`Metrics` registry (counters / gauges /
-  histograms with label sets and a Prometheus-exposition
-  :meth:`Metrics.render`), and a :meth:`FrontDoor.health` snapshot.  A
+* **Observability** — a :class:`~repro.core.trace.Metrics` registry
+  (counters / gauges / histograms with label sets and a Prometheus-
+  exposition :meth:`Metrics.render`; pass ``metrics=trace.METRICS`` to
+  render the program's own counters in the same payload), and a
+  :meth:`FrontDoor.health` snapshot.  A
   replica whose launches raise is marked unhealthy, its queued work is
   re-routed (bounded by ``max_retries``), and it is excluded from
   routing until a background probe succeeds — graceful degradation, not
@@ -57,215 +59,17 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
-import re
 import threading
 import time
-from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
-                    Tuple)
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.trace import Counter, Gauge, Histogram, Metrics  # noqa: F401
 from repro.launch.mesh import DeviceProfile
 
 __all__ = [
     "AdmissionRejected", "CallableReplica", "FrontDoor", "Metrics",
     "Outcome", "PipelineReplica", "PriorityClass", "Replica", "Router",
 ]
-
-
-# ---------------------------------------------------------------------------
-# Metrics: counters / gauges / histograms + Prometheus exposition
-# ---------------------------------------------------------------------------
-
-_NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
-_LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
-
-
-def _label_key(labels: Mapping[str, str]) -> Tuple[Tuple[str, str], ...]:
-    for k in labels:
-        if not _LABEL_RE.match(k):
-            raise ValueError(f"invalid metric label name {k!r}")
-    return tuple(sorted((k, str(v)) for k, v in labels.items()))
-
-
-def _fmt_labels(key: Tuple[Tuple[str, str], ...],
-                extra: Tuple[Tuple[str, str], ...] = ()) -> str:
-    items = key + extra
-    if not items:
-        return ""
-    body = ",".join(f'{k}="{v}"' for k, v in items)
-    return "{" + body + "}"
-
-
-class _Metric:
-    """Common label-set bookkeeping for one named metric."""
-
-    kind = "untyped"
-
-    def __init__(self, name: str, help: str = ""):
-        if not _NAME_RE.match(name):
-            raise ValueError(f"invalid metric name {name!r}")
-        self.name = name
-        self.help = help
-        self._lock = threading.Lock()
-        self._series: Dict[Tuple[Tuple[str, str], ...], Any] = {}
-
-    def _header(self) -> List[str]:
-        lines = []
-        if self.help:
-            lines.append(f"# HELP {self.name} {self.help}")
-        lines.append(f"# TYPE {self.name} {self.kind}")
-        return lines
-
-
-class Counter(_Metric):
-    """Monotonically increasing count, optionally per label set."""
-
-    kind = "counter"
-
-    def inc(self, value: float = 1.0, **labels: str) -> None:
-        if value < 0:
-            raise ValueError("counters only go up")
-        key = _label_key(labels)
-        with self._lock:
-            self._series[key] = self._series.get(key, 0.0) + value
-
-    def value(self, **labels: str) -> float:
-        with self._lock:
-            return float(self._series.get(_label_key(labels), 0.0))
-
-    def total(self) -> float:
-        """Sum over every label set."""
-        with self._lock:
-            return float(sum(self._series.values()))
-
-    def render(self) -> List[str]:
-        with self._lock:
-            series = sorted(self._series.items())
-        lines = self._header()
-        for key, v in series:
-            lines.append(f"{self.name}{_fmt_labels(key)} {_num(v)}")
-        return lines
-
-
-class Gauge(_Metric):
-    """A value that goes up and down (queue depth, in-flight, liveness)."""
-
-    kind = "gauge"
-
-    def set(self, value: float, **labels: str) -> None:
-        with self._lock:
-            self._series[_label_key(labels)] = float(value)
-
-    def value(self, **labels: str) -> float:
-        with self._lock:
-            return float(self._series.get(_label_key(labels), float("nan")))
-
-    def render(self) -> List[str]:
-        with self._lock:
-            series = sorted(self._series.items())
-        lines = self._header()
-        for key, v in series:
-            lines.append(f"{self.name}{_fmt_labels(key)} {_num(v)}")
-        return lines
-
-
-class Histogram(_Metric):
-    """Sampled observations (latencies), rendered as a Prometheus summary
-    with p50/p99/p999 quantiles computed by
-    :meth:`repro.core.process.ProfileParameters.percentile` — the same
-    statistic every benchmark in this repo reports."""
-
-    kind = "summary"
-    quantiles = (50.0, 99.0, 99.9)
-
-    def observe(self, value: float, **labels: str) -> None:
-        key = _label_key(labels)
-        with self._lock:
-            prof = self._series.get(key)
-            if prof is None:
-                from repro.core.process import ProfileParameters
-                prof = ProfileParameters(enable=True)
-                self._series[key] = prof
-            prof.record(float(value))
-
-    def percentile(self, p: float, **labels: str) -> float:
-        """p-th percentile of the observations; nan when empty."""
-        with self._lock:
-            prof = self._series.get(_label_key(labels))
-        if prof is None:
-            return float("nan")
-        return prof.percentile(p)
-
-    def count(self, **labels: str) -> int:
-        with self._lock:
-            prof = self._series.get(_label_key(labels))
-        return 0 if prof is None else len(prof.samples)
-
-    def render(self) -> List[str]:
-        with self._lock:
-            series = sorted(self._series.items())
-        lines = self._header()
-        for key, prof in series:
-            for q in self.quantiles:
-                ql = (("quantile", f"{q / 100.0:.10g}"),)
-                lines.append(
-                    f"{self.name}{_fmt_labels(key, ql)} "
-                    f"{_num(prof.percentile(q))}")
-            lines.append(f"{self.name}_count{_fmt_labels(key)} "
-                         f"{len(prof.samples)}")
-            lines.append(f"{self.name}_sum{_fmt_labels(key)} "
-                         f"{_num(sum(prof.samples))}")
-        return lines
-
-
-def _num(v: float) -> str:
-    """Prometheus number formatting: integers without a trailing .0."""
-    f = float(v)
-    if f != f:
-        return "NaN"
-    if f == int(f) and abs(f) < 1e15:
-        return str(int(f))
-    return repr(f)
-
-
-class Metrics:
-    """Registry of named metrics.  ``counter``/``gauge``/``histogram``
-    get-or-create (re-registering with a different kind raises), and
-    :meth:`render` produces the whole registry in Prometheus text
-    exposition format — the ``/metrics`` payload of a deployment."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._metrics: Dict[str, _Metric] = {}
-
-    def _get(self, cls, name: str, help: str):
-        with self._lock:
-            m = self._metrics.get(name)
-            if m is None:
-                m = cls(name, help)
-                self._metrics[name] = m
-            elif not isinstance(m, cls):
-                raise ValueError(
-                    f"metric {name!r} already registered as {m.kind}")
-            return m
-
-    def counter(self, name: str, help: str = "") -> Counter:
-        return self._get(Counter, name, help)
-
-    def gauge(self, name: str, help: str = "") -> Gauge:
-        return self._get(Gauge, name, help)
-
-    def histogram(self, name: str, help: str = "") -> Histogram:
-        return self._get(Histogram, name, help)
-
-    def render(self) -> str:
-        """The registry as Prometheus text exposition (one block per
-        metric, label sets sorted — deterministic for tests)."""
-        with self._lock:
-            metrics = [self._metrics[k] for k in sorted(self._metrics)]
-        lines: List[str] = []
-        for m in metrics:
-            lines.extend(m.render())
-        return "\n".join(lines) + ("\n" if lines else "")
 
 
 # ---------------------------------------------------------------------------
@@ -598,6 +402,11 @@ class FrontDoor:
     ``max_retries``
         How many times a request bounced by a replica failure is
         re-routed before completing as ``"error"``.
+    ``metrics``
+        The registry the front door's metrics go to (a new one by
+        default).  ``repro.core.trace.METRICS`` puts them beside the
+        program's own counters, so one :meth:`Metrics.render` is the
+        whole ``/metrics`` payload.
     ``auto_start``
         Start the dispatcher/worker threads on the first ``submit()``
         (default).  ``False`` queues submissions until an explicit

@@ -58,10 +58,11 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.core import trace
 from repro.core.app import CLapp
 from repro.core.data import Data
 from repro.core.graph import Pipeline
-from repro.core.process import PortError, ProfileParameters
+from repro.core.process import PortError
 from repro.core.stream import (StreamQueue, _BatchPlan, _JoinFeed,
                                _edge_blobs)
 from repro.core.sync import Coherence
@@ -231,7 +232,9 @@ class PipelineServer:
         response.  With ``flush_timeout`` set this also (lazily) starts
         the background drain thread and wakes it."""
         self._ensure_built(request)
-        blobs = self._pack_request(request)
+        with trace.span("stream.pack") as s:
+            blobs = self._pack_request(request)
+            s.attrs["bytes"] = sum(b.nbytes for b in blobs)
         with self._cv:
             self._check_closed()
             self._check_worker_error()
@@ -271,16 +274,17 @@ class PipelineServer:
     def _responses_for(self, group: Sequence[_Request],
                        out: jax.Array, t_done: float) -> List[ServeResponse]:
         la = self._plan.launchable
-        per_item = self._plan.split_output(out)[:len(group)]
-        self.launches += 1
-        responses = []
-        for req, blob in zip(group, per_item):
-            d = Data.from_layout(la.out_layout)
-            d.device_blob = blob
-            d.coherence = Coherence.DEVICE_FRESH
-            responses.append(ServeResponse(
-                rid=req.rid, data=d, submitted_s=req.submitted_s,
-                completed_s=t_done))
+        with trace.span("stream.split"):
+            per_item = self._plan.split_output(out)[:len(group)]
+            self.launches += 1
+            responses = []
+            for req, blob in zip(group, per_item):
+                d = Data.from_layout(la.out_layout)
+                d.device_blob = blob
+                d.coherence = Coherence.DEVICE_FRESH
+                responses.append(ServeResponse(
+                    rid=req.rid, data=d, submitted_s=req.submitted_s,
+                    completed_s=t_done))
         return responses
 
     def drain(self) -> List[ServeResponse]:
@@ -347,11 +351,12 @@ class PipelineServer:
                   for e in range(la.n_inputs)]
         responses: List[ServeResponse] = []
         for dev_blobs in zip(*queues):  # next flush transfers while this runs
-            out = plan.launch(dev_blobs, aux_blobs)
-            jax.block_until_ready(out)      # latency = result actually ready
-            t_done = time.perf_counter()
-            responses.extend(self._responses_for(groups.popleft(), out,
-                                                 t_done))
+            group = groups.popleft()
+            with _flush_span(group):
+                out = plan.launch(dev_blobs, aux_blobs)
+                jax.block_until_ready(out)  # latency = result actually ready
+                t_done = time.perf_counter()
+                responses.extend(self._responses_for(group, out, t_done))
         self.served += len(responses)
         plan.join_timers()      # results are ready; settle the rate timers
         return responses
@@ -383,13 +388,15 @@ class PipelineServer:
             responses: List[ServeResponse] = []
             error: Optional[BaseException] = None
             try:
-                stacked = tuple(
-                    plan.place(blob)
-                    for blob in plan.stack_group([r.blobs for r in group]))
-                out = plan.launch(stacked, self._aux_blobs)
-                jax.block_until_ready(out)
-                responses = self._responses_for(group, out,
-                                                time.perf_counter())
+                with _flush_span(group):
+                    stacked = tuple(
+                        plan.place(blob)
+                        for blob in plan.stack_group([r.blobs
+                                                      for r in group]))
+                    out = plan.launch(stacked, self._aux_blobs)
+                    jax.block_until_ready(out)
+                    responses = self._responses_for(group, out,
+                                                    time.perf_counter())
             except BaseException as e:    # noqa: BLE001 — must not die silent
                 error = e
             finally:
@@ -465,6 +472,14 @@ class PipelineServer:
         self.close()
 
 
+def _flush_span(group: Sequence[_Request]) -> trace.span:
+    """The span of one batch flush: how many requests it carries and how
+    long the oldest of them waited in the queue."""
+    return trace.span(
+        "serve.flush", fill=len(group),
+        oldest_wait_s=time.perf_counter() - group[0].submitted_s)
+
+
 class LMServer:
     """Slot-based continuous batching for autoregressive decode, built
     entirely from Pipeline-stack primitives (the ONE batching
@@ -484,9 +499,9 @@ class LMServer:
     * **decode** — one in-place :class:`~repro.processes.lm.DecodeStep`
       launch per token advances every active slot; the state blob is
       donated step-to-step and stays ``DEVICE_RESIDENT``, so the only
-      per-step traffic is the (B, 1) token readback (``decode_profile``
-      records zero ``"transfer"`` time on the cache edge — the PR-6
-      phase breakdown proves it).
+      per-step traffic is the (B, 1) token readback: the
+      ``repro_h2d_bytes_total`` counter does not grow across a decode
+      step.
     * **release** — a finished request retires its slot with an in-place
       :class:`~repro.processes.lm.SlotRelease` (device ``active`` flag
       zeroed; position/token freeze exactly like the legacy host-side
@@ -543,12 +558,6 @@ class LMServer:
         self.queue: List[tuple] = []
         self.steps = 0
         self.admitted = 0
-        #: admission-side phases: prompt upload ("transfer"), prefill/splice
-        #: compile + compute.  The one-time zero-state upload lands here.
-        self.prefill_profile = ProfileParameters(enable=True)
-        #: decode-side phases: per-step compute only — ``phase_total(
-        #: "transfer") == 0.0`` is the zero-host2device cache-edge proof.
-        self.decode_profile = ProfileParameters(enable=True)
 
     # -- request lifecycle ----------------------------------------------------
     def submit(self, prompt: Sequence[int],
@@ -607,30 +616,33 @@ class LMServer:
                 break
             slot = int(slot)
             rid, prompt, frames = self.queue.pop(0)
-            toks = Data({"tokens": np.asarray(prompt, np.int32)[None, :]})
-            if self.encdec:
-                key = (len(prompt), frames.shape)
-                inputs: Any = {"tokens": toks,
-                               "frames": Data({"frames": frames})}
-            else:
-                key = len(prompt)
-                inputs = toks
-            pipe = self._prefill_pipe(key)
-            row = pipe.run(inputs, sync=False,
-                           profile=self.prefill_profile)
-            tok = int(np.asarray(row.device_view("token"))[0, 0])
-            sp = self._splice.get(slot)
-            if sp is None:
-                sp = self._lmp.CacheSplice(self.app, slot)
-                sp.in_handles["in"] = self.state_h
-                sp.out_handle = self.state_h
-                sp.graph_name = f"CacheSplice[slot={slot}]"
-                self._splice[slot] = sp
-            # the row aux is read live at launch: re-point it at THIS
-            # prompt-shape pipe's output (all row states share one layout,
-            # so the compiled splice executable is reused as-is)
-            sp.aux_handles["row"] = pipe._built.output_handle
-            sp.launch(self.prefill_profile)
+            with trace.span("lm.admit", rid=rid):
+                toks = Data({"tokens": np.asarray(prompt, np.int32)[None, :]})
+                if self.encdec:
+                    key = (len(prompt), frames.shape)
+                    inputs: Any = {"tokens": toks,
+                                   "frames": Data({"frames": frames})}
+                else:
+                    key = len(prompt)
+                    inputs = toks
+                pipe = self._prefill_pipe(key)
+                with trace.span("lm.prefill", rid=rid):
+                    row = pipe.run(inputs, sync=False)
+                with trace.span("lm.prefill_token", rid=rid):
+                    tok = int(_read_token(row)[0, 0])
+                sp = self._splice.get(slot)
+                if sp is None:
+                    sp = self._lmp.CacheSplice(self.app, slot)
+                    sp.in_handles["in"] = self.state_h
+                    sp.out_handle = self.state_h
+                    sp.graph_name = f"CacheSplice[slot={slot}]"
+                    self._splice[slot] = sp
+                # the row aux is read live at launch: re-point it at THIS
+                # prompt-shape pipe's output (all row states share one
+                # layout, so the compiled splice executable is reused as-is)
+                sp.aux_handles["row"] = pipe._built.output_handle
+                with trace.span("lm.splice", rid=rid):
+                    sp.launch()
             self.active[slot] = True
             self.positions[slot] = len(prompt)
             self.req_of_slot[slot] = rid
@@ -645,29 +657,34 @@ class LMServer:
             rl.out_handle = self.state_h
             rl.graph_name = f"SlotRelease[slot={slot}]"
             self._release[slot] = rl
-        rl.launch(self.decode_profile)
+        with trace.span("lm.release", rid=int(self.req_of_slot[slot])):
+            rl.launch()
 
     # -- decode ----------------------------------------------------------------
     def step(self) -> None:
         """Admit whatever fits, then one batched decode step for every
         active slot (a single in-place donated launch)."""
-        self._admit()
-        if not self.active.any():
-            return
-        self._decode_pipe.run(None, sync=False, profile=self.decode_profile)
-        self.steps += 1
-        new = np.asarray(self.state.device_view("token"))   # (B, 1) readback
-        for slot in np.where(self.active)[0]:
-            slot = int(slot)
-            t = int(new[slot, 0])
-            rid = int(self.req_of_slot[slot])
-            self.results[rid].append(t)
-            self.positions[slot] += 1
-            done = (self.sampling.eos_id is not None
-                    and t == self.sampling.eos_id)
-            if done or len(self.results[rid]) >= self.sampling.max_new_tokens:
-                self.active[slot] = False
-                self._release_slot(slot)
+        with trace.span("lm.step"):
+            self._admit()
+            if not self.active.any():
+                return
+            with trace.span("lm.decode"):
+                self._decode_pipe.run(None, sync=False)
+            self.steps += 1
+            with trace.span("lm.token_readback"):
+                new = _read_token(self.state)           # (B, 1) readback
+            for slot in np.where(self.active)[0]:
+                slot = int(slot)
+                t = int(new[slot, 0])
+                rid = int(self.req_of_slot[slot])
+                self.results[rid].append(t)
+                self.positions[slot] += 1
+                done = (self.sampling.eos_id is not None
+                        and t == self.sampling.eos_id)
+                if done or len(self.results[rid]) >= \
+                        self.sampling.max_new_tokens:
+                    self.active[slot] = False
+                    self._release_slot(slot)
 
     def run(self, max_steps: int = 10_000) -> List[List[int]]:
         steps = 0
@@ -675,3 +692,10 @@ class LMServer:
             self.step()
             steps += 1
         return self.results
+
+
+def _read_token(state: Data) -> np.ndarray:
+    """The ``token`` entry of a decode state, copied to the host."""
+    token = np.asarray(state.device_view("token"))
+    trace.D2H_BYTES.inc(token.nbytes)
+    return token

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import trace
 from repro.core.app import CLapp
 from repro.core.data import Coherence
 from repro.core.process import ProfileParameters
@@ -35,6 +36,13 @@ from repro.serve import LMServer, SamplingConfig, ServeEngine
 
 TINY = dict(n_layers=2, d_model=16, n_heads=2, n_kv_heads=2, d_ff=32,
             vocab=48, remat=False, dtype="float32", param_dtype="float32")
+
+
+def _steady_decode_steps(before):
+    """The ``lm.step`` calls recorded since ``before`` (a set of span ids)
+    that admitted nothing: steady decode steps."""
+    return [c for c in trace.calls("lm.step")
+            if c.span.id not in before and "lm.admit" not in c.counts]
 
 
 def _tiny_model(family: str):
@@ -292,12 +300,15 @@ def test_lmserver_matches_legacy_engine(family):
                       sampling=sampling, enc_len=enc_len)
     for p, f in zip(prompts, frames):
         server.submit(p, frames=f)
+    before = {c.span.id for c in trace.calls("lm.step")}
     got = server.run()
 
     assert got == want
-    # continuous batching through the graph: the decode pipe's profile
-    # never records a transfer — the cache edge stays on device.
-    assert server.decode_profile.phase_total("transfer") == 0.0
+    # continuous batching through the graph: no steady decode step copies
+    # a byte to the device — the cache edge stays on device.
+    steady = _steady_decode_steps(before)
+    assert steady and all(c.deltas["repro_h2d_bytes_total"] == 0
+                          for c in steady)
     assert server.steps > 0
     assert server.state.coherence is Coherence.DEVICE_RESIDENT
 
@@ -318,10 +329,13 @@ def test_serve_engine_shim_delegates_and_matches():
     eng = ServeEngine(model, params, batch=2, max_len=32, sampling=sampling)
     for p in prompts:
         eng.submit(p)
+    before = {c.span.id for c in trace.calls("lm.step")}
     assert eng.run() == want
     assert not eng.active.any()
     assert eng.positions.shape == (2,)
-    assert eng.server.decode_profile.phase_total("transfer") == 0.0
+    steady = _steady_decode_steps(before)
+    assert steady and all(c.deltas["repro_h2d_bytes_total"] == 0
+                          for c in steady)
 
 
 # ---------------------------------------------------------------------------
