@@ -1,5 +1,6 @@
 from .checkpoint import (
     CheckpointCorruptError,
+    CheckpointFormatError,
     CheckpointManager,
     cleanup,
     latest_step,
@@ -7,5 +8,6 @@ from .checkpoint import (
     save_checkpoint,
 )
 
-__all__ = ["CheckpointCorruptError", "CheckpointManager", "cleanup",
+__all__ = ["CheckpointCorruptError", "CheckpointFormatError",
+           "CheckpointManager", "cleanup",
            "latest_step", "restore_checkpoint", "save_checkpoint"]

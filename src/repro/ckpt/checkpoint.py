@@ -52,7 +52,38 @@ _BLOB = "state.arena"
 _META = "layout.json"
 _MANIFEST = "manifest.json"
 _HOST = "host.arena"
-_FORMAT = "sharded-v1"
+#: on-disk formats: v2 stores 8- and 16-bit leaves planar by lanes
+#: (``repro.core.arena``); v1 (``layout.json`` without a ``format`` key,
+#: or ``sharded-v1``) stored them as interleaved pairs
+_FORMAT = "sharded-v2"
+_LOGICAL_FORMAT = "logical-v2"
+
+
+class CheckpointFormatError(ValueError):
+    """A checkpoint holds 8- or 16-bit leaves in the arena codec before
+    the planar one: its bytes would be misread, so it is refused."""
+
+    def __init__(self, step: int, fmt: str, leaf: str, dtype: str):
+        self.step = step
+        self.format = fmt
+        super().__init__(
+            f"checkpoint step {step} is in format {fmt!r}, which stored "
+            f"8- and 16-bit leaves as interleaved pairs; this version "
+            f"stores them planar by lanes ({_LOGICAL_FORMAT!r}, "
+            f"{_FORMAT!r}) and cannot read leaf {leaf!r} ({dtype})")
+
+
+def _check_codec(step: int, fmt: str, current: str,
+                 layouts: List[Dict[str, Any]]) -> None:
+    """Refuse a checkpoint of another format whose layouts (as stored in
+    JSON) hold a sub-word leaf; word-sized leaves are stored alike in
+    every format."""
+    if fmt == current:
+        return
+    for layout in layouts:
+        for e in layout["entries"]:
+            if np.dtype(jnp.dtype(e["dtype"])).itemsize < 4:
+                raise CheckpointFormatError(step, fmt, e["name"], e["dtype"])
 
 
 class CheckpointCorruptError(RuntimeError):
@@ -248,7 +279,8 @@ def save_checkpoint(directory: str, step: int, state: Any,
     tmp = final + ".tmp"
     os.makedirs(tmp, exist_ok=True)
     with open(os.path.join(tmp, _META), "w") as f:
-        f.write(layout.to_json())
+        json.dump({"format": _LOGICAL_FORMAT, **json.loads(layout.to_json())},
+                  f)
     blob.tofile(os.path.join(tmp, _BLOB))
     if os.path.exists(final):
         shutil.rmtree(final)
@@ -328,7 +360,11 @@ def _restore_legacy(path: str, step: int, state_like: Any,
     if not os.path.exists(meta):
         raise CheckpointCorruptError(step, _META)
     with open(meta) as f:
-        layout = ArenaLayout.from_json(f.read())
+        text = f.read()
+    stored = json.loads(text)
+    _check_codec(step, stored.get("format", "logical-v1"), _LOGICAL_FORMAT,
+                 [stored])
+    layout = ArenaLayout.from_json(text)
     bp = os.path.join(path, _BLOB)
     if not os.path.exists(bp):
         raise CheckpointCorruptError(step, _BLOB)
@@ -366,6 +402,10 @@ def _restore_sharded(path: str, step: int, state_like: Any,
     missing = _manifest_missing(path, manifest)
     if missing is not None:
         raise CheckpointCorruptError(step, missing)
+    blobs = manifest["shards"] + ([manifest["host"]]
+                                  if manifest.get("host") else [])
+    _check_codec(step, manifest.get("format"), _FORMAT,
+                 [b["layout"] for b in blobs])
 
     blob_cache: Dict[str, Dict[str, np.ndarray]] = {}
 

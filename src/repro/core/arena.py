@@ -21,11 +21,22 @@ Offsets and sizes are in bytes, but a blob is held as 32-bit words
 (``uint32``) on host and device alike.  On a TPU a byte array is a poor
 carrier: turning ``u8[4n]`` into ``f32[n]`` goes through a ``u8[n, 4]``
 array whose minor dimension of 4 is padded to the 128-lane tile, 32x the
-data.  From words, every 4-byte view is a free bitcast and a narrower one
-(bf16, int8) splits each word in place.  For the same reason a complex
-entry is stored planar, all real parts and then all imaginary parts:
-numpy's interleaved pairs would need an ``(n, 2)`` shuffle on the device.
-Every other entry is stored in numpy's memory order.
+data.  From words, every 4-byte view is a free bitcast.  For the same
+reason no entry is stored interleaved:
+
+* a complex entry is planar, all real parts and then all imaginary parts
+  (numpy's interleaved pairs would need an ``(n, 2)`` shuffle);
+* an entry of 8- or 16-bit items (bf16, f16, int16, uint16, int8, uint8,
+  bool as uint8) is planar by lanes: with ``per = 4 // itemsize`` items
+  a word and ``q = ceil(n / per)`` words, word ``j`` holds item
+  ``j + k*q`` in its ``k``-th lane of ``8 * itemsize`` bits, counted from
+  the least significant.  Packing is shifts and ors of ``per`` contiguous
+  slices, a view the shifts narrowed and concatenated: elementwise work
+  on 1-D arrays, with no array whose minor dimension is the packing
+  factor.
+
+Every other entry is stored in numpy's memory order.  Host and device
+produce the same bytes.
 """
 from __future__ import annotations
 
@@ -37,8 +48,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
+
 ALIGN = 128  # bytes; TPU lane width (128 x f32) and a safe DMA alignment
 WORD = np.dtype(np.uint32)  # the blob's element type
+#: words of a sub-word entry the host packs at a time (bounds the
+#: temporaries to a few MiB, however large the entry)
+_CHUNK = 1 << 20
 
 
 def _round_up(n: int, align: int = ALIGN) -> int:
@@ -159,15 +175,20 @@ def pack_host(arrays: Mapping[str, Any], layout: ArenaLayout | None = None) -> T
             a = a.astype(want)
         if np.iscomplexobj(a):
             a = np.concatenate([a.real.reshape(-1), a.imag.reshape(-1)])
-        raw = np.ascontiguousarray(a).view(np.uint8).reshape(-1)
-        blob[e.offset : e.offset + e.nbytes] = raw
+        a = np.ascontiguousarray(a).reshape(-1)
+        if a.dtype.itemsize < WORD.itemsize:
+            start = e.offset // WORD.itemsize
+            _pack_lanes(words[start : start + _n_words(e)], a)
+            trace.SUBWORD_BYTES.inc(e.nbytes)
+        else:
+            blob[e.offset : e.offset + e.nbytes] = a.view(np.uint8)
     return words, layout
 
 
 def unpack_host(blob: np.ndarray, layout: ArenaLayout) -> Dict[str, np.ndarray]:
     """Zero-copy views of each entry out of a host blob (words, or the
-    same bytes as read back from a file).  Complex entries, stored planar,
-    come back as assembled copies."""
+    same bytes as read back from a file).  Complex and sub-word entries,
+    stored planar, come back as assembled copies."""
     blob = blob.view(np.uint8)
     out: Dict[str, np.ndarray] = {}
     for e in layout.entries:
@@ -179,43 +200,117 @@ def unpack_host(blob: np.ndarray, layout: ArenaLayout) -> Dict[str, np.ndarray]:
             arr.real = parts[:arr.size].reshape(e.shape)
             arr.imag = parts[arr.size:].reshape(e.shape)
             out[e.name] = arr
+        elif dt.itemsize < WORD.itemsize:
+            words = blob[e.offset : e.offset + _n_words(e) * WORD.itemsize]
+            items = _unpack_lanes(words.view(WORD), dt, e.nbytes // dt.itemsize)
+            trace.SUBWORD_BYTES.inc(e.nbytes)
+            out[e.name] = items.reshape(e.shape)
         else:
             out[e.name] = raw.view(dt).reshape(e.shape)
     return out
+
+
+def _n_words(e: ArenaEntry) -> int:
+    return -(-e.nbytes // WORD.itemsize)
+
+
+def _lanes(dt) -> Tuple[int, np.dtype]:
+    """Items of a sub-word dtype a word holds, and the unsigned dtype of
+    their width."""
+    item = np.dtype(dt).itemsize
+    return WORD.itemsize // item, np.dtype(f"uint{8 * item}")
+
+
+def _pack_lanes(words: np.ndarray, a: np.ndarray) -> None:
+    """Write the 1-D sub-word array ``a`` into the zeroed ``words`` in
+    place, planar by lanes, a chunk of words at a time."""
+    per, unsigned = _lanes(a.dtype)
+    bits = 8 * a.dtype.itemsize
+    items = a.view(unsigned)
+    q, n = words.shape[0], items.shape[0]
+    lane = np.empty(min(q, _CHUNK), WORD)
+    for c0 in range(0, q, _CHUNK):
+        out = words[c0 : c0 + _CHUNK]
+        for k in range(per):
+            part = items[k * q + c0 : min(k * q + c0 + out.shape[0], n)]
+            if k == 0:
+                out[: part.shape[0]] = part
+            elif part.shape[0]:
+                tmp = lane[: part.shape[0]]
+                np.left_shift(part, bits * k, out=tmp, dtype=WORD)
+                np.bitwise_or(out[: part.shape[0]], tmp,
+                              out=out[: part.shape[0]])
+
+
+def _unpack_lanes(words: np.ndarray, dt: np.dtype, n: int) -> np.ndarray:
+    """The ``n`` items of dtype ``dt`` that :func:`_pack_lanes` wrote into
+    ``words``, as a new 1-D array."""
+    per, unsigned = _lanes(dt)
+    q = words.shape[0]
+    items = np.empty(per * q, unsigned)
+    for k in range(per):
+        # the assignment narrows to the lane's width, keeping the low bits
+        items[k * q : (k + 1) * q] = words >> (8 * dt.itemsize * k)
+    return items[:n].view(dt)
 
 
 # ---------------------------------------------------------------------------
 # Device-side unpack (lazy slice + bitcast inside jit; no host round trip)
 # ---------------------------------------------------------------------------
 
-def _from_words(words: jax.Array, dt, n: int) -> jax.Array:
-    """The first ``n`` items of dtype ``dt`` held in ``words`` (1-D)."""
-    item = np.dtype(dt).itemsize
+def _from_words(words: jax.Array, dt, shape: Tuple[int, ...]) -> jax.Array:
+    """The items of dtype ``dt`` and shape ``shape`` held in ``words``
+    (1-D)."""
+    dt = jnp.dtype(dt)
+    item = dt.itemsize
     if item == WORD.itemsize:
-        return jax.lax.bitcast_convert_type(words, dt)
+        return jax.lax.bitcast_convert_type(words, dt).reshape(shape)
     if item > WORD.itemsize:
         return jax.lax.bitcast_convert_type(
-            words.reshape(-1, item // WORD.itemsize), dt)
-    # narrower: each word splits in place into (words, 4 // item) items
-    return jax.lax.bitcast_convert_type(words, dt).reshape(-1)[:n]
+            words.reshape(-1, item // WORD.itemsize), dt).reshape(shape)
+    per, unsigned = _lanes(dt)
+    # narrowing keeps the low bits: lane k is the word shifted right by k
+    items = jnp.concatenate(
+        [(words >> (8 * item * k)).astype(unsigned) for k in range(per)])
+    n = int(np.prod(shape, dtype=np.int64))
+    if n < items.shape[0]:
+        items = items[:n]
+    return jax.lax.bitcast_convert_type(items, dt).reshape(shape)
 
 
 def _to_words(a: jax.Array) -> jax.Array:
-    """Inverse of :func:`_from_words`: a 1-D array as words (zero-padded
-    to a whole word)."""
+    """Inverse of :func:`_from_words`: ``a`` as 1-D words, a sub-word
+    array planar by lanes (zero-padded to a whole word)."""
     item = a.dtype.itemsize
     if item >= WORD.itemsize:
-        return jax.lax.bitcast_convert_type(a, WORD).reshape(-1)
-    per = WORD.itemsize // item
-    a = jnp.pad(a, (0, -a.shape[0] % per))
-    return jax.lax.bitcast_convert_type(a.reshape(-1, per), WORD)
+        return jax.lax.bitcast_convert_type(a.reshape(-1), WORD).reshape(-1)
+    per, unsigned = _lanes(a.dtype)
+    a = _flatten(jax.lax.bitcast_convert_type(a, unsigned))
+    q = -(-a.shape[0] // per)
+    a = jnp.pad(a, (0, per * q - a.shape[0]))
+    words = a[:q].astype(WORD)
+    for k in range(1, per):
+        words = words | (a[k * q : (k + 1) * q].astype(WORD) << (8 * item * k))
+    return words
+
+
+def _flatten(x: jax.Array) -> jax.Array:
+    """``x.reshape(-1)``, by way of rows of 128 where the size allows.
+
+    The TPU compiler spends minutes on one large reshape straight to 1-D
+    from a minor dimension that is not a multiple of 128 (a 63 MB cache
+    leaf of minor dimension 80: about two), and about a second on the
+    two steps; the barrier keeps it from merging them back into one."""
+    if x.ndim < 2 or x.size % 128:
+        return x.reshape(-1)
+    return jax.lax.optimization_barrier(x.reshape(-1, 128)).reshape(-1)
 
 
 def device_view(blob: jax.Array, entry: ArenaEntry) -> jax.Array:
     """Slice one logical array out of a device-resident word blob.
 
-    Works under ``jit``; the compiler folds the slice+bitcast into the
-    consumer so chained Processes read the arena in place (zero copy).
+    Works under ``jit``; the compiler folds the slice and the decode into
+    the consumer so chained Processes read the arena in place (zero copy).
     ``bitcast_convert_type`` rejects bool/complex, so those are read as
     uint8 / the real and the imaginary plane.
     """
@@ -224,17 +319,15 @@ def device_view(blob: jax.Array, entry: ArenaEntry) -> jax.Array:
     start = entry.offset // WORD.itemsize
     # a static slice: offsets past 2**31 (arenas over 8 GiB) stay exact,
     # where a dynamic slice's int32 index would wrap
-    words = jax.lax.slice_in_dim(
-        blob, start, start + -(-entry.nbytes // WORD.itemsize))
+    words = jax.lax.slice_in_dim(blob, start, start + _n_words(entry))
     if dt == jnp.bool_:
-        arr = _from_words(words, jnp.uint8, n) != 0
-    elif jnp.issubdtype(dt, jnp.complexfloating):
+        return _from_words(words, jnp.uint8, entry.shape) != 0
+    if jnp.issubdtype(dt, jnp.complexfloating):
         real_dt = jnp.float32 if dt == jnp.complex64 else jnp.float64
-        parts = _from_words(words, real_dt, 2 * n)
-        arr = jax.lax.complex(parts[:n], parts[n:]).astype(dt)
-    else:
-        arr = _from_words(words, dt, n)
-    return arr.reshape(entry.shape)
+        parts = _from_words(words, real_dt, (2 * n,))
+        return jax.lax.complex(parts[:n], parts[n:]).astype(dt).reshape(
+            entry.shape)
+    return _from_words(words, dt, entry.shape)
 
 
 def unpack_device(blob: jax.Array, layout: ArenaLayout) -> Dict[str, jax.Array]:
@@ -248,10 +341,11 @@ def pack_device(arrays: Mapping[str, jax.Array], layout: ArenaLayout) -> jax.Arr
     end = 0
     for e in layout.entries:
         dt = jnp.dtype(e.dtype)
-        a = arrays[e.name].astype(dt).reshape(-1)
+        a = jnp.asarray(arrays[e.name]).astype(dt)
         if dt == jnp.bool_:
             a = a.astype(jnp.uint8)
         elif jnp.issubdtype(dt, jnp.complexfloating):
+            a = a.reshape(-1)
             a = jnp.concatenate([jnp.real(a), jnp.imag(a)])
         start = e.offset // WORD.itemsize
         raw = _to_words(a)

@@ -270,8 +270,11 @@ CACHE_HITS = METRICS.counter(
 CACHE_MISSES = METRICS.counter(
     "repro_compile_cache_misses_total",
     "aot_compile calls that traced, lowered and compiled")
+SUBWORD_BYTES = METRICS.counter(
+    "repro_arena_subword_bytes_total",
+    "bytes of 8- and 16-bit arena entries packed or unpacked on the host")
 _PROCESS_COUNTERS = (H2D_BYTES, D2H_BYTES, COMPILES, COMPILE_SECONDS,
-                     GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES)
+                     GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES, SUBWORD_BYTES)
 for _c in _PROCESS_COUNTERS:
     _c.inc(0.0)         # a series from the start: rendered at 0, and read
                         # unlocked by _counter_values

@@ -119,7 +119,7 @@ def test_sharded_checkpoint_roundtrip_and_manifest(data):
         path = save_checkpoint(directory, step, tree, sharded=True)
         with open(os.path.join(path, "manifest.json")) as f:
             manifest = json.load(f)
-        assert manifest["format"] == "sharded-v1"
+        assert manifest["format"] == "sharded-v2"
         assert manifest["step"] == step
         # every leaf accounted for exactly once
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)

@@ -22,6 +22,7 @@ Single-device versions run here in tier-1; the multi-device round-trips
 (8 shards, elastic restore across mesh shapes) live in
 ``test_mesh_stream.py``'s forced-8-device section.
 """
+import json
 import os
 import shutil
 
@@ -29,8 +30,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.ckpt import (CheckpointCorruptError, CheckpointManager, cleanup,
-                        latest_step, restore_checkpoint, save_checkpoint)
+from repro.ckpt import (CheckpointCorruptError, CheckpointFormatError,
+                        CheckpointManager, cleanup, latest_step,
+                        restore_checkpoint, save_checkpoint)
 from repro.core import CLapp, Data, Pipeline, Port, Process, ProfileParameters
 
 
@@ -141,6 +143,63 @@ def test_legacy_missing_blob_typed_error(tmp_path, rng):
     # and with no complete checkpoint at all, discovery still says so
     with pytest.raises(FileNotFoundError):
         restore_checkpoint(str(tmp_path), _like(state))
+
+
+def _plant_v1(path: str, sharded: bool, named) -> None:
+    """Rewrite a checkpoint just saved as the writer before the planar
+    sub-word codec left it: the old format marker (``sharded-v1``; no
+    marker in ``layout.json``) and every leaf in numpy's bytes, 16-bit
+    items interleaved two to a word."""
+    from repro.core.arena import ArenaLayout
+    if sharded:
+        with open(os.path.join(path, "manifest.json")) as f:
+            manifest = json.load(f)
+        manifest["format"] = "sharded-v1"
+        with open(os.path.join(path, "manifest.json"), "w") as f:
+            json.dump(manifest, f)
+        layout, blob_file = manifest["host"]["layout"], "host.arena"
+    else:
+        with open(os.path.join(path, "layout.json")) as f:
+            layout = json.load(f)
+        del layout["format"]
+        with open(os.path.join(path, "layout.json"), "w") as f:
+            json.dump(layout, f)
+        blob_file = "state.arena"
+    layout = ArenaLayout.from_json(json.dumps(layout))
+    blob = np.zeros(layout.total_bytes, np.uint8)
+    for e in layout.entries:
+        raw = np.ascontiguousarray(named[e.name]).view(np.uint8).reshape(-1)
+        blob[e.offset:e.offset + e.nbytes] = raw
+    blob.tofile(os.path.join(path, blob_file))
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["legacy", "sharded"])
+def test_old_subword_codec_checkpoint_refused(tmp_path, rng, sharded):
+    """A checkpoint whose bf16 leaf was written with the interleaved
+    sub-word codec is refused with an error naming the codec change,
+    never read back as wrong numbers."""
+    import ml_dtypes
+    state = {"w": rng.standard_normal((4, 8)).astype(ml_dtypes.bfloat16),
+             "b": rng.standard_normal((3,)).astype(np.float32)}
+    path = save_checkpoint(str(tmp_path), 1, state, sharded=sharded)
+    _plant_v1(path, sharded, {"['w']": state["w"], "['b']": state["b"]})
+    assert latest_step(str(tmp_path)) == 1
+    with pytest.raises(CheckpointFormatError) as ei:
+        restore_checkpoint(str(tmp_path), _like(state))
+    msg = str(ei.value)
+    assert "interleaved" in msg and "planar" in msg and "['w']" in msg
+    assert ei.value.step == 1
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["legacy", "sharded"])
+def test_old_format_with_word_sized_leaves_restores(tmp_path, rng, sharded):
+    """Leaves of 4 bytes or more are stored alike before and after the
+    codec change, so such an old checkpoint still restores exactly."""
+    state = {"w": rng.standard_normal((4, 8)).astype(np.float32),
+             "i": rng.integers(0, 9, (5,)).astype(np.int32)}
+    path = save_checkpoint(str(tmp_path), 1, state, sharded=sharded)
+    _plant_v1(path, sharded, {"['w']": state["w"], "['i']": state["i"]})
+    _assert_equal_tree(restore_checkpoint(str(tmp_path), _like(state)), state)
 
 
 def test_stale_tmp_ignored_and_reaped(tmp_path, rng):

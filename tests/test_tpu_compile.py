@@ -12,6 +12,8 @@ The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -155,3 +157,52 @@ def test_sharded_mri_stream_compiles_for_v5e_host(one_chip, topo, monkeypatch,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * mem.argument_size_in_bytes
+
+
+def _danube_state_layout():
+    from repro.models import build_model
+    from repro.processes.lm import decode_state_data
+    state, _ = decode_state_data(build_model(DANUBE), 2, 1024)
+    return state.plan()
+
+
+def _danube_weight_layout():
+    from repro.core.arena import plan_layout
+    return plan_layout([("w", (_D, DANUBE.d_ff), _BF16)])
+
+
+#: a u32 or narrower array whose minor dimension is a packing factor: the
+#: TPU pads that dimension to 128 lanes (an ``(n, 2)`` interleave, 64x)
+_PAIRS = re.compile(r"\b(?:u32|bf16|u16|u8|s8)\[(?:\d+,)+[24]\]")
+
+
+@pytest.mark.parametrize("direction", ["pack", "unpack"])
+@pytest.mark.parametrize("layout_of", [_danube_state_layout,
+                                       _danube_weight_layout],
+                         ids=["decode_state_2x1024", "weight_2560x6912"])
+def test_arena_codec_is_lane_dense_for_v5e(one_chip, layout_of, direction):
+    """danube's decode state at 2 x 1024 (two bf16 cache leaves of 63 MB)
+    and one bf16 weight at published widths, packed into and viewed out
+    of arena words for a described v5e: the sub-word codec engages (its
+    shifts are in the program), no array of the packing factor's minor
+    dimension is left, and the temporaries stay within twice the
+    arena."""
+    from repro.core.arena import pack_device, unpack_device
+    layout = layout_of()
+    if direction == "pack":
+        fn = lambda arrays: pack_device(arrays, layout)  # noqa: E731
+        arg = {e.name: jax.ShapeDtypeStruct(e.shape, e.dtype,
+                                            sharding=one_chip)
+               for e in layout.entries}
+        shift = "shift-left("
+    else:
+        fn = lambda blob: unpack_device(blob, layout)  # noqa: E731
+        arg = jax.ShapeDtypeStruct((layout.total_words,), jnp.uint32,
+                                   sharding=one_chip)
+        shift = "shift-right-logical("
+    compiled = jax.jit(fn).lower(arg).compile()
+    text = compiled.as_text()
+    assert shift in text
+    assert not _PAIRS.findall(text), sorted(set(_PAIRS.findall(text)))
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        2 * layout.total_bytes

@@ -227,7 +227,8 @@ def test_metrics_registry_renders_the_program_counters():
                  "repro_compiles_total", "repro_compile_seconds_total",
                  "repro_gc_pause_seconds_total",
                  "repro_compile_cache_hits_total",
-                 "repro_compile_cache_misses_total"):
+                 "repro_compile_cache_misses_total",
+                 "repro_arena_subword_bytes_total"):
         assert f"\n{name} " in "\n" + text, name
     from repro.serve import control
     assert control.Metrics is trace.Metrics
