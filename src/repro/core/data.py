@@ -24,6 +24,7 @@ import numpy as np
 
 from . import trace
 from .arena import ArenaLayout, pack_host, plan_layout, unpack_device, unpack_host
+from .arena import device_view as entry_view
 from .sync import Coherence, SyncSource, resolve_source
 
 
@@ -228,18 +229,23 @@ class Data:
             f"exists; re-upload with host2device before reusing this Data")
 
     # -- device views ----------------------------------------------------------
-    def device_views(self) -> Dict[str, jax.Array]:
+    def _device_blob(self) -> jax.Array:
         if self.device_blob is None or self.layout is None:
             if self.donated_by is not None:
                 self._raise_donated()
             raise ValueError("Data not registered on a device (use CLapp.addData)")
-        return unpack_device(self.device_blob, self.layout)
+        return self.device_blob
+
+    def device_views(self) -> Dict[str, jax.Array]:
+        return unpack_device(self._device_blob(), self.layout)
 
     def device_view(self, name_or_idx) -> jax.Array:
-        views = self.device_views()
-        if isinstance(name_or_idx, int):
-            return views[self._arrays[name_or_idx].name]
-        return views[name_or_idx]
+        """One entry, unpacked alone: called eagerly, unpacking every entry
+        would allocate and decode the whole blob to read one of them."""
+        blob = self._device_blob()
+        name = (self._arrays[name_or_idx].name
+                if isinstance(name_or_idx, int) else name_or_idx)
+        return entry_view(blob, self.layout.entry(name))
 
     # -- host sync --------------------------------------------------------------
     def sync_to_host(self) -> None:

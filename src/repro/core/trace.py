@@ -273,8 +273,17 @@ CACHE_MISSES = METRICS.counter(
 SUBWORD_BYTES = METRICS.counter(
     "repro_arena_subword_bytes_total",
     "bytes of 8- and 16-bit arena entries packed or unpacked on the host")
+MOE_ASSIGNMENTS = METRICS.counter(
+    "repro_moe_assignments_total",
+    "(token, expert) pairs an expert layer's router chose, over all experts")
+MOE_HELD_ASSIGNMENTS = METRICS.counter(
+    "repro_moe_held_assignments_total",
+    "(token, expert) pairs computed here: the chosen expert is held here")
+MOE_ROWS = METRICS.counter(
+    "repro_moe_rows_total", "token rows through an expert layer")
 _PROCESS_COUNTERS = (H2D_BYTES, D2H_BYTES, COMPILES, COMPILE_SECONDS,
-                     GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES, SUBWORD_BYTES)
+                     GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES, SUBWORD_BYTES,
+                     MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_ROWS)
 for _c in _PROCESS_COUNTERS:
     _c.inc(0.0)         # a series from the start: rendered at 0, and read
                         # unlocked by _counter_values

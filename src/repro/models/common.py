@@ -24,6 +24,23 @@ BATCH_AXES = (POD, DATA)
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN RoPE scaling (arXiv:2309.00071), as DeepSeek-V2 configures it:
+    frequencies past ``original_max_position_embeddings`` ramp from their
+    own value to ``1/factor`` of it between the correction dimensions of
+    ``beta_fast`` and ``beta_slow`` rotations; cos and sin take
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` and an MLA
+    softmax scale ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """One architecture.  Field presence is governed by ``family``."""
 
@@ -41,6 +58,7 @@ class ArchConfig:
     qkv_bias: bool = False
     window: Optional[int] = None   # sliding-window attention (h2o-danube)
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRN] = None    # deepseek-v2: YaRN
     use_rope: bool = True          # whisper uses absolute positions instead
     rotary_pct: float = 1.0        # minitron/nemotron: partial rotary
     causal: bool = True
@@ -52,7 +70,12 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     first_dense_ff: Optional[int] = None   # deepseek: layer 0 is dense
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True            # renormalise the top-k gates
+    #: the routed experts this device holds (expert parallelism): experts
+    #: ``expert_offset .. expert_offset + experts_held - 1`` of the router's
+    #: ``n_experts``; None holds all of them
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
     router_aux_weight: float = 0.01
     # MLA (deepseek)
     mla: bool = False
@@ -103,6 +126,11 @@ class ArchConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head if self.d_head is not None else self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_experts if self.experts_held is None else self.experts_held
 
     @property
     def adtype(self):

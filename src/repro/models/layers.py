@@ -8,6 +8,7 @@ see — and through the Pallas kernels when ``cfg.use_pallas`` (tests, TPU).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as kref
-from .common import ArchConfig, KeyGen, dense_init, embed_init, constrain, MODEL, BATCH_AXES
+from .common import (ArchConfig, KeyGen, YaRN, dense_init, embed_init, constrain,
+                     MODEL, BATCH_AXES)
 
 
 # ---------------------------------------------------------------------------
@@ -51,17 +53,54 @@ def rope_freqs(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 m ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(dim: int, theta: float, ys: YaRN) -> Tuple[int, int]:
+    """The rotary pairs ``[low, high]`` over which YaRN ramps: the pairs
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+    def pair(rotations):
+        return dim * math.log(ys.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(pair(ys.beta_fast)), 0),
+            min(math.ceil(pair(ys.beta_slow)), dim - 1))
+
+
+def yarn_freqs(dim: int, theta: float, ys: YaRN) -> jax.Array:
+    """Frequencies ``freq / factor * (1 - m) + freq * m``, ``m`` one below
+    pair ``low``, zero from pair ``high``, linear between."""
+    freq = rope_freqs(dim, theta)
+    low, high = yarn_range(dim, theta, ys)
+    ramp = (np.arange(dim // 2, dtype=np.float32) - low) / max(high - low, 1e-3)
+    m = jnp.asarray(1.0 - np.clip(ramp, 0.0, 1.0))
+    return freq / ys.factor * (1.0 - m) + freq * m
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               rotary_pct: float = 1.0) -> jax.Array:
-    """x: (B, H, S, D); positions: (B, S) int32."""
+               rotary_pct: float = 1.0,
+               scaling: Optional[YaRN] = None) -> jax.Array:
+    """x: (B, H, S, D); positions: (B, S) int32.  ``scaling`` applies
+    YaRN (:func:`yarn_freqs`) to the frequencies and scales cos and sin by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
     d = x.shape[-1]
     rd = int(d * rotary_pct)
     rd -= rd % 2
     if rd == 0:
         return x
-    freqs = rope_freqs(rd, theta)                       # (rd/2,)
+    if scaling is None:
+        freqs = rope_freqs(rd, theta)                   # (rd/2,)
+    else:
+        freqs = yarn_freqs(rd, theta, scaling)
     ang = positions[:, None, :, None].astype(jnp.float32) * freqs  # (B,1,S,rd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        amp = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     xr, xp = x[..., :rd], x[..., rd:]
     x1, x2 = xr[..., : rd // 2], xr[..., rd // 2 :]
     rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

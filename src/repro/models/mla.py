@@ -6,6 +6,10 @@ stores ONLY (c_kv, k_rope) per token — the whole point of MLA: cache bytes
 per token = rank + rope_dim instead of 2*H*dh — and the up-projections are
 *absorbed* into the query/output paths so scores are computed in latent
 space (q W_uk^T) . c_kv without materialising per-head keys.
+
+With ``cfg.rope_scaling`` (DeepSeek-V2's YaRN) the rope part of q and k
+takes YaRN's frequencies and the softmax scale is multiplied by
+``mscale(factor, mscale_all_dim) ** 2``, in both forms alike.
 """
 from __future__ import annotations
 
@@ -15,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import ArchConfig, KeyGen, dense_init, constrain, MODEL, BATCH_AXES
-from .layers import apply_rope, init_norm, apply_norm
+from .layers import apply_rope, init_norm, apply_norm, yarn_mscale
 
 
 def init_mla(key, cfg: ArchConfig) -> Dict[str, Any]:
@@ -38,15 +42,25 @@ def _q_proj(p, x, cfg: ArchConfig, positions):
     h, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     q = (x @ p["w_q"]).reshape(b, s, h, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
     return q_nope, q_pe
 
 
 def _latents(p, x, cfg: ArchConfig, positions):
     c_kv = apply_norm(p["kv_norm"], x @ p["w_dkv"], cfg)          # (B,S,rank)
     k_pe = (x @ p["w_kr"])[:, None, :, :]                          # (B,1,S,dr)
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)[:, 0]       # (B,S,dr)
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)[:, 0]              # (B,S,dr)
     return c_kv, k_pe
+
+
+def softmax_scale(cfg: ArchConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale ** 2``."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
 
 
 #: q-chunking bound, mirroring kernels.ref.attention (and following its
@@ -75,6 +89,11 @@ def _mla_attend_block(q_nope, q_pe, k_nope, k_pe, v, q_off, s_kv, scale):
 def mla_full(p, x, cfg: ArchConfig, positions) -> jax.Array:
     """Full-sequence MLA (train / prefill), direct expansion form; long
     sequences scan over q-chunks (bounded logits buffer)."""
+    with jax.named_scope("mla"):
+        return _mla_full(p, x, cfg, positions)
+
+
+def _mla_full(p, x, cfg: ArchConfig, positions) -> jax.Array:
     b, s, _ = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     q_nope, q_pe = _q_proj(p, x, cfg, positions)
@@ -84,7 +103,7 @@ def mla_full(p, x, cfg: ArchConfig, positions) -> jax.Array:
     k_nope = constrain(k_nope, BATCH_AXES, MODEL, None, None)
     v = constrain(v, BATCH_AXES, MODEL, None, None)
 
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     qn = q_nope.astype(jnp.float32)
     qp = q_pe.astype(jnp.float32)
     kn = k_nope.astype(jnp.float32)
@@ -134,6 +153,11 @@ def mla_decode(p, x, cfg: ArchConfig, pos, layer_cache):
     """Absorbed one-token decode.  Scores live in latent space:
     (q_nope @ W_uk) . c_kv; context is combined in latent space and expanded
     once through W_uv."""
+    with jax.named_scope("mla"):
+        return _mla_decode(p, x, cfg, pos, layer_cache)
+
+
+def _mla_decode(p, x, cfg: ArchConfig, pos, layer_cache):
     b = x.shape[0]
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     positions = jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32)
@@ -149,7 +173,7 @@ def mla_decode(p, x, cfg: ArchConfig, pos, layer_cache):
 
     q_lat = jnp.einsum("bhsd,rhd->bhsr", q_nope.astype(jnp.float32),
                        p["w_uk"].astype(jnp.float32))      # (B,H,1,rank)
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     logits = (jnp.einsum("bhsr,btr->bhst", q_lat, c_kv.astype(jnp.float32))
               + jnp.einsum("bhsd,btd->bhst", q_pe.astype(jnp.float32),
                            k_pe.astype(jnp.float32))) * scale

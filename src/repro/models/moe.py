@@ -1,15 +1,21 @@
-"""Mixture-of-Experts layer: top-k router + capacity-bounded scatter dispatch.
+"""Mixture-of-Experts layer: a dropless share of the routed experts.
 
-Dispatch strategy (GShard 'group' = batch row): routing positions, capacity
-and the scatter are LOCAL to each batch row, so the (data-sharded) batch
-axis is never crossed — the only cross-shard traffic is the (B,E,C,D)
-buffer <-> (E over ``model``) expert-weight contraction (expert
-parallelism).  Capacity C = cf * S * top_k / E per row; overflow tokens are
-dropped (Switch semantics) and reported in the aux metrics.
+The router keeps its full width: a float32 softmax over all ``n_experts``
+and a greedy top-k, the gates renormalised over the k only where
+``cfg.norm_topk_prob`` says so.  This device holds the routed experts
+``expert_offset .. expert_offset + n_held - 1`` (expert parallelism: the
+others live on other devices).  The (token, expert) pairs that fall on held
+experts are sorted by expert and go through one grouped matmul per
+projection over the held experts' stacks (``jax.lax.ragged_dot``; on a TPU
+a Mosaic grouped-matmul kernel whose ops are named ``ragged-dot-*``), are
+put back in token order and combined with their gates.  Pairs routed to
+experts held elsewhere add nothing here, and no token is ever dropped:
+every held pair is computed, whatever the routing.  The shared experts
+(one SwiGLU of width ``n_shared_experts * d_ff``) are added once.  The
+same code runs at prefill and at decode.
 
-This keeps HLO FLOPs proportional to *active* expert compute (unlike the
-all-experts-dense fallback) so the roofline's MODEL_FLOPS/HLO_FLOPs ratio
-stays honest.  A shard_map all-to-all dispatch is the §Perf upgrade path.
+Besides the output, :func:`apply_moe` returns the Switch load-balance loss
+(training) and per-row routing counts (serving; see :data:`COUNTS`).
 """
 from __future__ import annotations
 
@@ -18,15 +24,20 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import ArchConfig, KeyGen, dense_init, constrain, MODEL, BATCH_AXES
+from .common import ArchConfig, KeyGen, dense_init
 from .layers import init_mlp, apply_mlp
+
+#: the routing counts :func:`apply_moe` returns per batch row, in order:
+#: (token, expert) pairs routed over all experts, the pairs computed on
+#: this device, token rows through the layer
+COUNTS = ("assignments", "held_assignments", "rows")
 
 
 def init_moe(key, cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict[str, Any]:
     kg = KeyGen(key)
-    d, f, e = cfg.d_model, d_ff or cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, d_ff or cfg.d_ff, cfg.n_held
     p = {
-        "router": dense_init(kg("router"), (d, e), jnp.float32),
+        "router": dense_init(kg("router"), (d, cfg.n_experts), jnp.float32),
         "w_gate": dense_init(kg("w_gate"), (e, d, f), cfg.pdtype),
         "w_up": dense_init(kg("w_up"), (e, d, f), cfg.pdtype),
         "w_down": dense_init(kg("w_down"), (e, f, d), cfg.pdtype),
@@ -36,75 +47,61 @@ def init_moe(key, cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict[str, Any]
     return p
 
 
-def _row_capacity(s: int, cfg: ArchConfig) -> int:
-    c = int(cfg.capacity_factor * s * cfg.top_k / cfg.n_experts)
-    return max(8, -(-c // 8) * 8)  # round up to 8
+def route(router: jax.Array, x: jax.Array, cfg: ArchConfig):
+    """x: (T, D) -> softmax probabilities (T, E), gates (T, K) f32 and
+    expert ids (T, K)."""
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ router, axis=-1)
+    gates, eids = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-20)
+    return probs, gates, eids
 
 
-def apply_moe(p: Dict[str, Any], x: jax.Array, cfg: ArchConfig,
-              d_ff: Optional[int] = None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """x: (B, S, D) -> (B, S, D), aux metrics (load-balance loss, drop rate).
+def expert_share(p: Dict[str, Any], x: jax.Array, gates: jax.Array,
+                 eids: jax.Array, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the routed output.  x: (T, D); gates and
+    eids (T, K).  Returns (T, D) in float32 and the held mask (T, K)."""
+    t, d = x.shape
+    k, e = cfg.top_k, cfg.n_held
+    local = eids.reshape(t * k) - cfg.expert_offset
+    held = (local >= 0) & (local < e)
+    group = jnp.where(held, local, e)              # absent experts sort last
+    order = jnp.argsort(group, stable=True)        # pairs in expert order
+    sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+    rows = jnp.take(x, order // k, axis=0)         # (T*K, D)
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, p["w_gate"], sizes)) \
+        * jax.lax.ragged_dot(rows, p["w_up"], sizes)
+    out = jax.lax.ragged_dot(h, p["w_down"], sizes)   # (T*K, D)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))        # the inverse permutation
+    out = jnp.take(out, back, axis=0).astype(jnp.float32)
+    # rows past the held groups are no expert's: select, never multiply
+    w = jnp.where(held, gates.reshape(t * k), 0.0)[:, None]
+    out = jnp.where(held[:, None], out, 0.0) * w
+    return out.reshape(t, k, d).sum(axis=1), held.reshape(t, k)
 
-    Dispatch is LOCAL to each batch row (GShard 'group' = row): positions,
-    capacity and the scatter never cross the (data-sharded) batch axis, so
-    SPMD keeps the activation sharding end-to-end and the only cross-shard
-    traffic is the (B,E,C,D) buffer <-> (E over model) expert weights
-    contraction — measured ~100x less all-gather bytes than a global-buffer
-    dispatch.  Overflowing tokens are dropped (Switch/GShard semantics) and
-    reported in the metrics.
-    """
+
+def apply_moe(p: Dict[str, Any], x: jax.Array, cfg: ArchConfig
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, D) -> (B, S, D), aux: ``moe_aux_loss`` (the Switch
+    load-balance loss, over all experts) and ``moe_counts`` (B, 3) int32,
+    :data:`COUNTS` for each batch row."""
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    cap = _row_capacity(s, cfg)
-
-    logits = (x.astype(jnp.float32) @ p["router"])             # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, eids = jax.lax.top_k(probs, k)                  # (B, S, K)
-    gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
-
-    # slot position within (row, expert) via row-local cumsum (never crosses
-    # the sharded batch axis; a global cumsum is an SPMD catastrophe)
-    onehot = jax.nn.one_hot(eids, e, dtype=jnp.int32)          # (B, S, K, E)
-    oh_rows = onehot.reshape(b, s * k, e)
-    pos = jnp.cumsum(oh_rows, axis=1) - oh_rows                # (B, S*K, E)
-    slot_pos = jnp.sum(pos * oh_rows, axis=-1)                 # (B, S*K)
-    flat_eid = eids.reshape(b, s * k)
-    keep = slot_pos < cap
-    dest = jnp.where(keep, flat_eid * cap + slot_pos, e * cap) # (B, S*K)
-
-    # row-local scatter into (B, E*C+1, D); batch sharding is preserved
-    token_of_slot = jnp.repeat(jnp.arange(s), k)               # (S*K,)
-    vals = jnp.take(x, token_of_slot, axis=1).astype(cfg.adtype)  # (B, S*K, D)
-
-    def scatter_row(dest_r, vals_r):
-        return jnp.zeros((e * cap + 1, d), cfg.adtype).at[dest_r].set(
-            vals_r, mode="drop")
-
-    buf = jax.vmap(scatter_row)(dest, vals)[:, : e * cap]      # (B, E*C, D)
-    buf = buf.reshape(b, e, cap, d)
-    buf = constrain(buf, BATCH_AXES, None, None, None)
-
-    # expert FFN (SwiGLU); E contracts against model-sharded expert stacks
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf, p["w_gate"])) * \
-        jnp.einsum("becd,edf->becf", buf, p["w_up"])
-    h = constrain(h, BATCH_AXES, MODEL, None, None)
-    y_e = jnp.einsum("becf,efd->becd", h, p["w_down"])         # (B, E, C, D)
-    y_flat = jnp.concatenate(
-        [y_e.reshape(b, e * cap, d),
-         jnp.zeros((b, 1, d), y_e.dtype)], axis=1)             # (B, E*C+1, D)
-
-    # combine: gather each slot's output, weight by gate, sum over k
-    slot_out = jnp.take_along_axis(y_flat, dest[..., None], axis=1)
-    slot_out = slot_out * gate_vals.reshape(b, s * k, 1).astype(slot_out.dtype)
-    y = jnp.sum(slot_out.reshape(b, s, k, d), axis=2).astype(cfg.adtype)
-
+    x2 = x.reshape(b * s, d)
+    with jax.named_scope("moe.route"):
+        probs, gates, eids = route(p["router"], x2, cfg)
+    with jax.named_scope("moe.experts"):
+        y, held = expert_share(p, x2.astype(cfg.adtype), gates, eids, cfg)
+    y = y.astype(cfg.adtype).reshape(b, s, d)
     if cfg.n_shared_experts:
-        y = y + apply_mlp(p["shared"], x, cfg)
+        with jax.named_scope("moe.shared"):
+            y = y + apply_mlp(p["shared"], x, cfg)
 
-    # Switch-style load-balance aux loss + drop-rate metric
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(eids[..., 0], e, dtype=jnp.float32), axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=(0, 1))
+    e = cfg.n_experts
+    frac_tokens = jnp.mean(jax.nn.one_hot(eids[:, 0], e, dtype=jnp.float32), 0)
+    frac_probs = jnp.mean(probs, axis=0)
     aux_loss = e * jnp.sum(frac_tokens * frac_probs) * cfg.router_aux_weight
-    drop_rate = 1.0 - jnp.mean(keep.astype(jnp.float32))
-    return y, {"moe_aux_loss": aux_loss, "moe_drop_rate": drop_rate}
+    held_rows = jnp.sum(held.reshape(b, s * cfg.top_k), axis=1, dtype=jnp.int32)
+    counts = jnp.stack([jnp.full((b,), s * cfg.top_k, jnp.int32), held_rows,
+                        jnp.full((b,), s, jnp.int32)], axis=1)
+    return y, {"moe_aux_loss": aux_loss, "moe_counts": counts}

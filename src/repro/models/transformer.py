@@ -104,8 +104,7 @@ class DecoderLM:
             x = constrain(x, BATCH_AXES, None, None)
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
-        aux_sums = {"moe_aux_loss": jnp.zeros((), jnp.float32),
-                    "moe_drop_rate": jnp.zeros((), jnp.float32)}
+        aux_sums = {"moe_aux_loss": jnp.zeros((), jnp.float32)}
         if cfg.first_dense_ff:
             x, _ = self._layer_fwd(params["layer0"], x, positions, use_moe=False)
 
@@ -146,12 +145,19 @@ class DecoderLM:
 
     # ------------------------------------------------------------- serve
     def init_cache(self, batch: int, max_len: int) -> Dict[str, Any]:
+        """KV (or MLA latent) cache per layer; MoE models add
+        ``moe_counts`` (batch, 3) int32: each row's routing counts
+        (:data:`repro.models.moe.COUNTS`) summed over the expert layers
+        since its prefill, kept with the cache so that they accumulate on
+        the device step after step."""
         cfg = self.cfg
         n_scan = cfg.n_layers - (1 if cfg.first_dense_ff else 0)
         mk = (MLA.init_mla_cache if cfg.mla else L.init_kv_cache)
         cache = {"scan": mk(cfg, n_scan, batch, max_len, cfg.adtype)}
         if cfg.first_dense_ff:
             cache["layer0"] = jax.tree.map(lambda a: a[0], mk(cfg, 1, batch, max_len, cfg.adtype))
+        if cfg.n_experts:
+            cache["moe_counts"] = jnp.zeros((batch, len(MOE.COUNTS)), jnp.int32)
         return cache
 
     def _layer_decode(self, p, x, pos, lcache, *, use_moe: bool):
@@ -164,10 +170,10 @@ class DecoderLM:
         x = x + attn
         h = L.apply_norm(p["ln_mlp"], x, cfg)
         if use_moe:
-            y, _ = MOE.apply_moe(p["moe"], h, cfg)
-        else:
-            y = L.apply_mlp(p["mlp"], h, cfg)
-        return x + y, lcache
+            y, aux = MOE.apply_moe(p["moe"], h, cfg)
+            return x + y, lcache, aux["moe_counts"]
+        y = L.apply_mlp(p["mlp"], h, cfg)
+        return x + y, lcache, None
 
     def decode_step(self, params, token: jax.Array, pos, cache):
         """token: (B, 1) int32; pos: scalar int32 (position of this token).
@@ -176,24 +182,36 @@ class DecoderLM:
         x = L.embed_tokens(params["embed"], token, cfg)
         use_moe = bool(cfg.n_experts)
         if cfg.first_dense_ff:
-            x, l0 = self._layer_decode(params["layer0"], x, pos, cache["layer0"],
-                                       use_moe=False)
+            x, l0, _ = self._layer_decode(params["layer0"], x, pos,
+                                          cache["layer0"], use_moe=False)
         else:
             l0 = cache.get("layer0")
 
         def body(xc, xs):
             layer_params, lcache = xs
-            xo, lcache = self._layer_decode(layer_params, xc, pos, lcache, use_moe=use_moe)
-            return xo, lcache
+            xo, lcache, counts = self._layer_decode(layer_params, xc, pos, lcache,
+                                                    use_moe=use_moe)
+            return xo, (lcache, counts)
 
-        x, new_scan = scan_layers(body, x, (params["layers"], cache["scan"]),
-                                  unroll=cfg.unroll_layers)
+        x, ys = scan_layers(body, x, (params["layers"], cache["scan"]),
+                            unroll=cfg.unroll_layers)
         x = L.apply_norm(params["final_norm"], x, cfg)
         logits = L.logits_from_hidden(params["embed"], x, cfg)
+        return logits, self._cache_out(cache, ys, l0)
+
+    @staticmethod
+    def _cache_out(cache, ys, l0):
+        """The cache a prefill or decode step hands back from the scan's
+        ``(per-layer cache, routing counts or None)``: for MoE models the
+        call's counts (summed over layers) added to the rows' running
+        counts."""
+        new_scan, counts = ys
         new_cache = {"scan": new_scan}
         if l0 is not None:
             new_cache["layer0"] = l0
-        return logits, new_cache
+        if counts is not None:
+            new_cache["moe_counts"] = cache["moe_counts"] + jnp.sum(counts, axis=0)
+        return new_cache
 
     def prefill(self, params, tokens: jax.Array, cache,
                 prefix_embeds: Optional[jax.Array] = None):
@@ -226,20 +244,17 @@ class DecoderLM:
             xc = xc + attn
             h = L.apply_norm(layer_params["ln_mlp"], xc, cfg)
             if use_moe:
-                y, _ = MOE.apply_moe(layer_params["moe"], h, cfg)
-            else:
-                y = L.apply_mlp(layer_params["mlp"], h, cfg)
-            return xc + y, lcache
+                y, aux = MOE.apply_moe(layer_params["moe"], h, cfg)
+                return xc + y, (lcache, aux["moe_counts"])
+            y = L.apply_mlp(layer_params["mlp"], h, cfg)
+            return xc + y, (lcache, None)
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        x, new_scan = scan_layers(body_fn, x, (params["layers"], cache["scan"]),
-                                  unroll=cfg.unroll_layers)
+        x, ys = scan_layers(body_fn, x, (params["layers"], cache["scan"]),
+                            unroll=cfg.unroll_layers)
         x = L.apply_norm(params["final_norm"], x[:, -1:], cfg)
         logits = L.logits_from_hidden(params["embed"], x, cfg)
-        new_cache = {"scan": new_scan}
-        if l0 is not None:
-            new_cache["layer0"] = l0
-        return logits, new_cache
+        return logits, self._cache_out(cache, ys, l0)
 
     # ---------------------------------------------------------- sharding
     def partition_rules(self) -> Rules:

@@ -319,48 +319,54 @@ def _splice_row(full: jax.Array, row: jax.Array, slot) -> jax.Array:
     return jax.lax.dynamic_update_slice_in_dim(full, row, slot, axis=1)
 
 
+def slot_data(slot: int) -> Data:
+    """The ``slot`` aux of :class:`CacheSplice` and :class:`SlotRelease`:
+    the slot index as a traced int32, so one executable serves every
+    slot."""
+    return Data({"slot": np.asarray([slot], np.int32)})
+
+
 class CacheSplice(Process):
     """Continuous-batching admission: splice a single-row prefilled state
     (the ``row`` aux, batch 1) into slot ``slot`` of the batched persistent
     state.  Wired in place (``in`` == ``out`` == the state handle) so the
-    old state blob is donated, not copied.  ``slot`` is a launch parameter:
-    one cached executable per slot."""
+    old state blob is donated, not copied.  The slot is the traced ``slot``
+    aux (:func:`slot_data`): one executable for every slot."""
 
     ports = {"in": Port(names=("token", "positions", "active")),
              "out": Port(names=("token", "positions", "active")),
-             "row": Port(aux=True, doc="batch-1 state from a row prefill")}
+             "row": Port(aux=True, doc="batch-1 state from a row prefill"),
+             "slot": Port(aux=True, names=("slot",),
+                          doc="(1,) int32 slot index")}
 
-    def __init__(self, app, slot: int = 0):
+    def __init__(self, app):
         super().__init__(app)
-        self.set_slot(slot)
-
-    def set_slot(self, slot: int) -> None:
-        self.set_launch_parameters(("cache_splice", int(slot)))
+        self.set_launch_parameters(("cache_splice",))
 
     def apply(self, views, aux, params):
-        slot = int(params[1])
+        slot = aux["slot"]["slot"][0]
         row = aux["row"]
         return {name: _splice_row(full, row[name], slot)
                 for name, full in views.items()}
 
 
 class SlotRelease(Process):
-    """Retire slot ``slot``: zero its ``active`` flag on device (freezing
-    its position and token exactly like the legacy host-side bookkeeping)
-    while passing the rest of the state through in place."""
+    """Retire the slot the traced ``slot`` aux names: zero its ``active``
+    flag on device (freezing its position and token exactly like the
+    legacy host-side bookkeeping) while passing the rest of the state
+    through in place.  One executable for every slot."""
 
     ports = {"in": Port(names=("token", "positions", "active")),
-             "out": Port(names=("token", "positions", "active"))}
+             "out": Port(names=("token", "positions", "active")),
+             "slot": Port(aux=True, names=("slot",),
+                          doc="(1,) int32 slot index")}
 
-    def __init__(self, app, slot: int = 0):
+    def __init__(self, app):
         super().__init__(app)
-        self.set_slot(slot)
-
-    def set_slot(self, slot: int) -> None:
-        self.set_launch_parameters(("slot_release", int(slot)))
+        self.set_launch_parameters(("slot_release",))
 
     def apply(self, views, aux, params):
-        slot = int(params[1])
+        slot = aux["slot"]["slot"][0]
         out = dict(views)
         out["active"] = jax.lax.dynamic_update_slice_in_dim(
             views["active"], jnp.zeros((1,), jnp.int32), slot, axis=0)

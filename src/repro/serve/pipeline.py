@@ -506,6 +506,14 @@ class LMServer:
       :class:`~repro.processes.lm.SlotRelease` (device ``active`` flag
       zeroed; position/token freeze exactly like the legacy host-side
       bookkeeping, keeping ``pos = positions.max()`` bit-compatible).
+      For an MoE model the step that releases reads the rows' routing
+      counts (the state's ``moe_counts`` leaf, accumulated on the device
+      by prefill and decode) once, and adds each released row's to the
+      ``repro_moe_*`` counters of :data:`repro.core.trace.METRICS`.
+
+    The splice and the release take the slot as a traced argument (one
+    ``slot`` Data a slot, uploaded at construction), so each is one
+    executable whatever the batch.
 
     Decoding is greedy (``temperature=0``) — the sampling math runs on
     device inside the compiled step, so the host loop never sees logits.
@@ -547,8 +555,17 @@ class LMServer:
                 weights=self._weights_h)
         self._decode_pipe.build()        # AOT at construction
         self._prefill_pipes: Dict[Any, Pipeline] = {}   # prompt-shape keyed
-        self._splice: Dict[int, Any] = {}
-        self._release: Dict[int, Any] = {}
+        self._splice = lmp.CacheSplice(self.app)
+        self._release = lmp.SlotRelease(self.app)
+        for proc in (self._splice, self._release):
+            proc.in_handles["in"] = self.state_h
+            proc.out_handle = self.state_h
+            proc.graph_name = type(proc).__name__
+        self._slot_h = [self.app.addData(lmp.slot_data(slot))
+                        for slot in range(batch)]       # uploaded once
+        counts = "cache['moe_counts']"
+        self._counts_name = counts if counts in self._ccodec.names else None
+        self._counts: Optional[np.ndarray] = None       # read this step
         # host mirrors — identical bookkeeping (and attribute names) to the
         # legacy ServeEngine so callers and tests carry over unchanged
         self.active = np.zeros(batch, dtype=bool)
@@ -630,17 +647,13 @@ class LMServer:
                     row = pipe.run(inputs, sync=False)
                 with trace.span("lm.prefill_token", rid=rid):
                     tok = int(_read_token(row)[0, 0])
-                sp = self._splice.get(slot)
-                if sp is None:
-                    sp = self._lmp.CacheSplice(self.app, slot)
-                    sp.in_handles["in"] = self.state_h
-                    sp.out_handle = self.state_h
-                    sp.graph_name = f"CacheSplice[slot={slot}]"
-                    self._splice[slot] = sp
-                # the row aux is read live at launch: re-point it at THIS
-                # prompt-shape pipe's output (all row states share one
-                # layout, so the compiled splice executable is reused as-is)
+                # the aux handles are read live at launch: re-point them at
+                # THIS prompt-shape pipe's output and this slot (all row
+                # states share one layout, all slots another, so the one
+                # compiled splice executable is reused as-is)
+                sp = self._splice
                 sp.aux_handles["row"] = pipe._built.output_handle
+                sp.aux_handles["slot"] = self._slot_h[slot]
                 with trace.span("lm.splice", rid=rid):
                     sp.launch()
             self.active[slot] = True
@@ -650,14 +663,19 @@ class LMServer:
             self.admitted += 1
 
     def _release_slot(self, slot: int) -> None:
-        rl = self._release.get(slot)
-        if rl is None:
-            rl = self._lmp.SlotRelease(self.app, slot)
-            rl.in_handles["in"] = self.state_h
-            rl.out_handle = self.state_h
-            rl.graph_name = f"SlotRelease[slot={slot}]"
-            self._release[slot] = rl
+        rl = self._release
+        rl.aux_handles["slot"] = self._slot_h[slot]
         with trace.span("lm.release", rid=int(self.req_of_slot[slot])):
+            if self._counts_name is not None:
+                with trace.span("lm.moe_counts"):
+                    if self._counts is None:        # once a step
+                        self._counts = np.asarray(
+                            self.state.device_view(self._counts_name))
+                        trace.D2H_BYTES.inc(self._counts.nbytes)
+                    routed, held, rows = (int(v) for v in self._counts[slot])
+                    trace.MOE_ASSIGNMENTS.inc(routed)
+                    trace.MOE_HELD_ASSIGNMENTS.inc(held)
+                    trace.MOE_ROWS.inc(rows)
             rl.launch()
 
     # -- decode ----------------------------------------------------------------
@@ -673,6 +691,7 @@ class LMServer:
             self.steps += 1
             with trace.span("lm.token_readback"):
                 new = _read_token(self.state)           # (B, 1) readback
+            self._counts = None
             for slot in np.where(self.active)[0]:
                 slot = int(slot)
                 t = int(new[slot, 0])

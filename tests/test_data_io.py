@@ -107,3 +107,28 @@ def test_spec_only_data_gets_zero_blob():
     d.add(NDArray(shape=(4, 4), dtype=np.float32, name="x"))
     h = app.addData(d)
     assert float(np.abs(np.asarray(d.device_view("x"))).sum()) == 0.0
+
+
+def test_device_view_unpacks_one_entry_alone(monkeypatch):
+    """``device_view`` reads one entry by name or index without unpacking
+    the others (an eager whole-blob unpack to read one entry allocates
+    and decodes the whole arena); an unknown name is a KeyError."""
+    from repro.core import data as data_mod
+    rng = np.random.default_rng(0)
+    arrays = {"state": rng.standard_normal((3, 40)).astype(np.float16),
+              "token": rng.integers(0, 99, (4, 1)).astype(np.int32),
+              "mask": rng.integers(0, 2, (5,)).astype(np.uint8),
+              "k": (rng.standard_normal(6)
+                    + 1j * rng.standard_normal(6)).astype(np.complex64)}
+    app = CLapp().init()
+    d = Data(arrays)
+    app.addData(d)
+
+    def whole(*a, **k):
+        raise AssertionError("unpacked every entry")
+    monkeypatch.setattr(data_mod, "unpack_device", whole)
+    for i, (name, want) in enumerate(arrays.items()):
+        np.testing.assert_array_equal(np.asarray(d.device_view(name)), want)
+        np.testing.assert_array_equal(np.asarray(d.device_view(i)), want)
+    with pytest.raises(KeyError):
+        d.device_view("absent")

@@ -313,6 +313,32 @@ def test_lmserver_matches_legacy_engine(family):
     assert server.state.coherence is Coherence.DEVICE_RESIDENT
 
 
+def test_splice_and_release_build_one_executable_for_64_slots():
+    """The slot is a traced argument of CacheSplice and SlotRelease: 64
+    slots admitted and released compile one program of each, where a
+    slot baked into the program compiled 128."""
+    cfg, model, params = _tiny_model("dense")
+    server = LMServer(model, params, batch=64, max_len=16,
+                      sampling=SamplingConfig(max_new_tokens=2))
+    rng = np.random.default_rng(5)
+    prompts = [list(rng.integers(0, cfg.vocab, size=3)) for _ in range(64)]
+    for p in prompts:
+        server.submit(p)
+    since = trace.records()[-1].id
+    got = server.run()
+    assert all(len(r) == 2 for r in got) and not server.active.any()
+    compiled = [r.attrs.get("fun") or "" for r in trace.records()
+                if r.id > since and r.name == "compile"]
+    assert sum("CacheSplice" in f for f in compiled) == 1, compiled
+    assert sum("SlotRelease" in f for f in compiled) == 1, compiled
+    # the last slot's row lands in the last slot: same tokens as a server
+    # that admits that prompt alone
+    alone = LMServer(model, params, batch=1, max_len=16,
+                     sampling=SamplingConfig(max_new_tokens=2))
+    alone.submit(prompts[-1])
+    assert alone.run()[0] == got[-1]
+
+
 def test_serve_engine_shim_delegates_and_matches():
     """The compatibility wrapper serves the same results and exposes the
     legacy introspection attributes."""

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.deepseek_v2_lite_16b import CONFIG as DSV2
 from repro.configs.h2o_danube_1_8b import CONFIG as DANUBE
 from repro.configs.mri_recon import CONFIG as MRI
 
@@ -115,6 +116,27 @@ CASES = {
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = CASES[name]
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+def test_expert_share_is_a_grouped_matmul_kernel_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's expert layer at a 64-row decode step, 8 of 64
+    experts held: the three projections lower to the chip's grouped-matmul
+    kernel (ops named ``ragged-dot-*``, which ``moe_roofline.dsv2``
+    reads), not to a dense matmul over every row and expert."""
+    from repro.models.moe import expert_share
+    cfg = DSV2.scaled(experts_held=8)
+    d, f, t, k = cfg.d_model, cfg.d_ff, 64, cfg.top_k
+
+    def share(x, gates, eids, w_gate, w_up, w_down):
+        p = {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+        return expert_share(p, x, gates, eids, cfg)[0]
+
+    text = _compiled_text(share, one_chip, ((t, d), _BF16),
+                          ((t, k), jnp.float32), ((t, k), jnp.int32),
+                          ((8, d, f), _BF16), ((8, d, f), _BF16),
+                          ((8, f, d), _BF16))
+    assert "tpu_custom_call" in text
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
 
 
 @pytest.mark.parametrize("grid", [(4, 1), (2, 2)], ids=["data4", "data2xmodel2"])
