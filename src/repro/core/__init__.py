@@ -28,10 +28,10 @@ from .arena import (
     pack_tree_host,
     plan_layout,
     split_batched_blob,
-    stack_host_blobs,
     unpack_device,
     unpack_host,
     unpack_tree_host,
+    write_host,
 )
 from .data import Data, KData, NDArray, XData
 from .process import (
@@ -62,6 +62,6 @@ __all__ = [
     "batched_spec", "compile_cache_dir", "compile_cache_stats",
     "device_view", "enable_compile_cache", "kernel",
     "pack_device", "pack_host", "pack_tree_host", "plan_layout",
-    "split_batched_blob", "stack_host_blobs", "stream_launch", "trace",
-    "unpack_device", "unpack_host", "unpack_tree_host",
+    "split_batched_blob", "stream_launch", "trace",
+    "unpack_device", "unpack_host", "unpack_tree_host", "write_host",
 ]

@@ -2,9 +2,10 @@
 
 Owns: device discovery & selection by traits, the data registry
 (handle -> Data, device-resident arena blobs), the kernel registry, the
-``("data", "model")`` device mesh built over the selected devices, and the
+``("data", "model")`` device mesh built over the selected devices, the
 per-device throughput profiles (:attr:`CLapp.device_profiles`) that drive
-throughput-proportional batch splitting.  This is the single place where
+throughput-proportional batch splitting, and the host staging buffers
+streamed batches are written into (:attr:`CLapp.staging`).  This is the single place where
 "housekeeping" lives, exactly as in the paper: ``init()`` selects devices
 in one call, and everything downstream — transfers (``host2device`` places
 via ``NamedSharding``), launches, sharded streaming, proportional splits —
@@ -30,14 +31,16 @@ from __future__ import annotations
 import dataclasses
 import enum
 import os
+import threading
+import weakref
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from . import trace
-from .arena import blob_spec
+from .arena import WORD, blob_spec
 from .data import Data
 from .registry import KernelRegistry
 from .sync import Coherence, SyncSource
@@ -107,6 +110,115 @@ class NoMatchingDeviceError(RuntimeError):
     pass
 
 
+class _Staged:
+    """One pooled staging buffer: the rows still to be placed since it was
+    last handed out, the view it was handed out as (weakly: a view dropped
+    before it was placed frees the buffer), and the device arrays whose
+    readiness proves that everything placed from it has been read."""
+
+    __slots__ = ("buf", "view", "unplaced", "fences")
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self.view: Optional[weakref.ref] = None
+        self.unplaced = 0
+        self.fences: List[jax.Array] = []
+
+    def hand_out(self) -> np.ndarray:
+        view = self.buf[:]
+        self.view = weakref.ref(view)
+        self.unplaced = self.buf.shape[0]
+        self.fences = []
+        return view
+
+    def busy(self) -> bool:
+        """Handed out and still being written: not every row is placed."""
+        return self.unplaced > 0 and self.view() is not None
+
+    def landed(self, block: bool) -> bool:
+        """Whether every transfer placed from the buffer has landed (with
+        ``block``, after waiting for them).  Fences that have landed are
+        dropped, so the pool holds no device memory past them."""
+        self.fences = [f for f in self.fences if not _ready(f, block)]
+        return not self.fences
+
+
+def _ready(x: jax.Array, block: bool) -> bool:
+    """``x`` is ready (with ``block``, once waited for).  An array deleted
+    by a donation that no launch has reported yet is not."""
+    try:
+        if x.is_deleted():
+            return False
+        if block:
+            x.block_until_ready()
+            return True
+        return x.is_ready()
+    except jax.errors.JaxRuntimeError:     # donated while being asked
+        return False
+
+
+class StagingPool:
+    """Host staging buffers of stacked batches, allocated once and reused
+    across calls (the paper's pinned buffers, §III-A.2, allocated once for
+    every transfer).  Buffers are ``(rows, total_words)`` words, pooled by
+    shape.  A buffer is handed out again only once every transfer that
+    read it has landed: the placed array, or, once a launch consumed that
+    array, the launch's output.  A shape's pool grows only while every
+    buffer of it is in flight, to at most ``cap``; past that the oldest is
+    waited for, and a buffer made while every one is still being written
+    is not kept."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pool: Dict[Tuple[int, int], List[_Staged]] = {}
+
+    def acquire(self, shape: Tuple[int, int], cap: int) -> np.ndarray:
+        """A ``shape`` buffer of words to write a batch into."""
+        with self._lock:
+            pool = self._pool.setdefault(shape, [])
+            idle = [s for s in pool if not s.busy()]      # oldest first
+            got = next((s for s in idle if s.landed(block=False)), None)
+            if got is None and idle and len(pool) >= cap \
+                    and idle[0].landed(block=True):
+                got = idle[0]
+            if got is not None:
+                pool.remove(got)
+                pool.append(got)
+                trace.STAGING_REUSES.inc()
+                return got.hand_out()
+            trace.STAGING_ALLOCS.inc()
+            buf = np.empty(shape, WORD)
+            if len(pool) >= cap:
+                return buf
+            got = _Staged(buf)
+            pool.append(got)
+            return got.hand_out()
+
+    def placed(self, blob: np.ndarray, out: jax.Array) -> None:
+        """Rows of a pooled buffer (``blob``, the buffer or a slice of it)
+        were placed as ``out``."""
+        owner = blob if blob.base is None else blob.base
+        with self._lock:
+            for s in self._pool.get(owner.shape, ()):
+                if s.buf is owner:
+                    s.fences.append(out)
+                    s.unplaced -= blob.shape[0]
+                    return
+
+    def consumed(self, inputs: Sequence[jax.Array],
+                 outputs: Sequence[jax.Array]) -> None:
+        """A launch read ``inputs`` and made ``outputs``: buffers placed as
+        one of the inputs are fenced by the outputs from now on, since a
+        donated input can no longer be waited for."""
+        with self._lock:
+            for pool in self._pool.values():
+                for s in pool:
+                    kept = [f for f in s.fences
+                            if not any(f is x for x in inputs)]
+                    if len(kept) < len(s.fences):
+                        s.fences = kept + list(outputs)
+
+
 class CLapp:
     """Main framework object.  ``init`` selects devices in a single call
     (paper §III-A.1a); ``addData`` registers + transfers a Data set in a
@@ -129,6 +241,9 @@ class CLapp:
         # handle -> coherence state to settle into once the dispatched
         # host->device transfer lands (see host2device(wait=False))
         self._in_flight: Dict[DataHandle, Coherence] = {}
+        #: host buffers the streaming executor stacks batches into, kept
+        #: across calls (a call of one batch must still find last call's)
+        self.staging = StagingPool()
 
     # ------------------------------------------------------------------ init
     def init(self, platform_traits: PlatformTraits | None = None,

@@ -164,25 +164,45 @@ def pack_host(arrays: Mapping[str, Any], layout: ArenaLayout | None = None) -> T
         layout = plan_layout(
             (name, _as_numpy(a).shape, _as_numpy(a).dtype) for name, a in arrays.items()
         )
+    # zeros, not empty: a GB blob of lanes fills faster on zeroed pages
     words = np.zeros(layout.total_words, dtype=WORD)
-    blob = words.view(np.uint8)
+    write_host(words, arrays, layout)
+    return words, layout
+
+
+def write_host(row: np.ndarray, arrays: Mapping[str, Any], layout: ArenaLayout) -> None:
+    """Write named host arrays into the ``(total_words,)`` word row ``row``
+    in place: the words :func:`pack_host` makes, each array copied once
+    (a complex one as a copy of its real plane and one of its imaginary
+    plane).  Every word of the row is assigned, padding included, so a
+    reused row keeps no bit of what it held before."""
+    if row.shape != (layout.total_words,) or row.dtype != WORD:
+        raise ValueError(
+            f"row shape {row.shape}/{row.dtype} does not match layout "
+            f"({layout.total_words},)/{WORD}")
+    blob = row.view(np.uint8)
+    end = 0                                   # first word not yet written
     for e in layout.entries:
         a = _as_numpy(arrays[e.name])
         if tuple(a.shape) != e.shape:
             raise ValueError(f"{e.name}: shape {a.shape} != layout {e.shape}")
-        want = np.dtype(jnp.dtype(e.dtype))
+        want = e.np_dtype
         if a.dtype != want:
             a = a.astype(want)
-        if np.iscomplexobj(a):
-            a = np.concatenate([a.real.reshape(-1), a.imag.reshape(-1)])
-        a = np.ascontiguousarray(a).reshape(-1)
-        if a.dtype.itemsize < WORD.itemsize:
-            start = e.offset // WORD.itemsize
-            _pack_lanes(words[start : start + _n_words(e)], a)
+        start = e.offset // WORD.itemsize
+        row[end:start] = 0
+        end = start + _n_words(e)
+        raw = blob[e.offset : e.offset + e.nbytes]
+        if want.kind == "c":
+            planes = raw.view(np.finfo(want).dtype)
+            np.copyto(planes[: a.size].reshape(e.shape), a.real)
+            np.copyto(planes[a.size :].reshape(e.shape), a.imag)
+        elif want.itemsize < WORD.itemsize:
+            _pack_lanes(row[start:end], np.ascontiguousarray(a).reshape(-1))
             trace.SUBWORD_BYTES.inc(e.nbytes)
         else:
-            blob[e.offset : e.offset + e.nbytes] = a.view(np.uint8)
-    return words, layout
+            np.copyto(raw.view(want).reshape(e.shape), a)
+    row[end:] = 0
 
 
 def unpack_host(blob: np.ndarray, layout: ArenaLayout) -> Dict[str, np.ndarray]:
@@ -222,8 +242,9 @@ def _lanes(dt) -> Tuple[int, np.dtype]:
 
 
 def _pack_lanes(words: np.ndarray, a: np.ndarray) -> None:
-    """Write the 1-D sub-word array ``a`` into the zeroed ``words`` in
-    place, planar by lanes, a chunk of words at a time."""
+    """Write the 1-D sub-word array ``a`` into ``words`` in place, planar
+    by lanes, a chunk of words at a time.  Lane 0 covers every word and is
+    assigned, so nothing ``words`` held before survives."""
     per, unsigned = _lanes(a.dtype)
     bits = 8 * a.dtype.itemsize
     items = a.view(unsigned)
@@ -409,18 +430,6 @@ def split_batched_blob(stacked: jax.Array) -> List[jax.Array]:
             (stacked.shape[1],), group_sharding(devices),
             [row[d] for d in devices]))
     return items
-
-
-def stack_host_blobs(blobs: Sequence[np.ndarray], layout: ArenaLayout) -> np.ndarray:
-    """Stack per-item host blobs into one contiguous ``(k, total_words)``
-    array — the single-call batched transfer (one ``device_put`` moves k
-    Data sets; fewer, larger DMAs, as the paper prescribes per set)."""
-    for b in blobs:
-        if b.shape != (layout.total_words,) or b.dtype != WORD:
-            raise ValueError(
-                f"blob shape {b.shape}/{b.dtype} does not match layout "
-                f"({layout.total_words},)/{WORD}")
-    return np.stack(blobs, axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import trace
-from .arena import ArenaLayout, pack_host, plan_layout, unpack_device, unpack_host
+from .arena import (ArenaLayout, pack_host, plan_layout, unpack_device,
+                    unpack_host, write_host)
 from .arena import device_view as entry_view
 from .sync import Coherence, SyncSource, resolve_source
 
@@ -196,14 +197,20 @@ class Data:
         self.layout = plan_layout((a.name, a.shape, a.dtype) for a in self._arrays)
         return self.layout
 
-    def pack_host(self) -> np.ndarray:
+    def pack_host(self, out: np.ndarray | None = None) -> np.ndarray:
+        """The host arrays as arena words: a new blob, or written in place
+        into the word row ``out``."""
         if self.layout is None:
             self.plan()
         missing = [a.name for a in self._arrays if a.host is None]
         if missing:
             raise ValueError(f"cannot pack spec-only arrays: {missing}")
-        blob, _ = pack_host({a.name: a.host for a in self._arrays}, self.layout)
-        return blob
+        arrays = {a.name: a.host for a in self._arrays}
+        if out is None:
+            out, _ = pack_host(arrays, self.layout)
+        else:
+            write_host(out, arrays, self.layout)
+        return out
 
     # -- donation bookkeeping ---------------------------------------------------
     def mark_donated(self, consumer: str) -> None:

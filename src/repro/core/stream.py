@@ -23,9 +23,11 @@ inference requests):
   always a transfer temporary, so donation is safe by construction).
 
 * :func:`stream_launch` — the engine behind ``Process.stream(datasets,
-  batch=k)`` and the Pipeline's ``mode="stream"``: pack host-side, group
-  into batches, feed through a StreamQueue, launch batched, and scatter
-  the per-item output blobs into fresh output Data objects.
+  batch=k)`` and the Pipeline's ``mode="stream"``: group into batches,
+  pack each item once, straight into its row of a host staging buffer
+  the app keeps across calls (:class:`~repro.core.app.StagingPool`),
+  feed through a StreamQueue, launch batched, and scatter the per-item
+  output blobs into fresh output Data objects.
 
 * :class:`_JoinFeed` — multi-input (fan-in) streaming.  A launchable with
   N streaming inputs gets N per-edge StreamQueues whose batches are
@@ -142,6 +144,7 @@ it.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 import weakref
@@ -153,8 +156,7 @@ import jax
 import numpy as np
 
 from . import trace
-from .arena import (batched_spec, blob_spec, split_batched_blob,
-                    stack_host_blobs)
+from .arena import batched_spec, blob_spec, split_batched_blob
 from .data import Data
 from .process import (PureLaunchable, ProfileParameters, aot_compile,
                       _layout_fingerprint)
@@ -171,12 +173,13 @@ class StreamQueue:
     buffering; larger depths trade memory for more dispatch-ahead slack.
 
     ``device`` may be a :class:`jax.Device`, a :class:`jax.sharding.
-    Sharding` — the sharded streaming path passes ``NamedSharding(mesh,
-    P("data"))`` so every dispatched stacked batch is scattered across the
-    mesh's ``data`` axis in the same single ``device_put`` call — or a
-    **callable placement** ``item -> device batch`` (the proportional
-    split path passes :meth:`_BatchPlan.place`, which carves each stacked
-    host blob into per-device sub-batches as a :class:`SplitBatch`).
+    Sharding`, or a **callable placement** ``item -> device batch``: the
+    streaming executor passes :meth:`_BatchPlan.place`, which puts a
+    stacked batch on the plan's device or, sharded, scatters it across the
+    mesh's ``data`` axis (``NamedSharding(mesh, P("data"))``) in one
+    ``device_put``, carves a proportional split's batch into per-device
+    sub-batches as a :class:`SplitBatch`, and reports every staging
+    buffer it placed to the app's pool.
 
     ``profile`` (a :class:`~repro.core.process.ProfileParameters`) records
     each dispatched placement's dispatch-to-landed wall time — measured
@@ -279,13 +282,13 @@ class StreamQueue:
         self._issued.clear()
 
 
-def _put(blob: Any, target: Any) -> jax.Array:
-    """One placement dispatch (``jax.device_put``), recorded as a
-    ``stream.place`` span; a host array's bytes count as host-to-device
-    traffic."""
+def _put(blob: Any, target: Any, copy: bool = False) -> jax.Array:
+    """One placement dispatch (``jax.device_put``, of a copy of ``blob``
+    with ``copy``), recorded as a ``stream.place`` span; a host array's
+    bytes count as host-to-device traffic."""
     nbytes = blob.nbytes if isinstance(blob, np.ndarray) else 0
     with trace.span("stream.place", bytes=nbytes):
-        out = jax.device_put(blob, target)
+        out = jax.device_put(blob.copy() if copy else blob, target)
     trace.H2D_BYTES.inc(nbytes)
     return out
 
@@ -527,7 +530,7 @@ class _BatchPlan:
 
     def __init__(self, process, batch: int, *, sharded: bool = False,
                  tail_waste_threshold: float = 0.5, split: str = "equal",
-                 lanes: bool = False,
+                 lanes: bool = False, depth: int = 2,
                  profile: ProfileParameters | None = None):
         if split not in ("equal", "proportional"):
             raise ValueError(
@@ -548,6 +551,9 @@ class _BatchPlan:
         self.split = split
         self.lanes = lanes
         self.profile = profile
+        # staging buffers a batch shape may hold: the queue's depth, the
+        # batch being launched and the one being written
+        self.staging_cap = depth + 2
         self.tail_waste_threshold = float(tail_waste_threshold)
         self.main = BatchedProcess(process, batch, sharded=sharded,
                                    profile=profile)
@@ -624,13 +630,8 @@ class _BatchPlan:
         return None if self.per_device else self.main.batch_sharding
 
     @property
-    def queue_target(self):
-        """What the per-edge :class:`StreamQueue` s place batches with:
-        the per-device placement callable, the mesh sharding, or the
-        primary device."""
-        if self.per_device:
-            return self.place
-        return self.main.batch_sharding or self.process.getApp().device
+    def staging(self):
+        return self.process.getApp().staging
 
     @property
     def registry(self):
@@ -744,41 +745,81 @@ class _BatchPlan:
             out[i] = c
         return tuple(out)
 
-    def stack_group(self, items: Sequence[Tuple[np.ndarray, ...]]
-                    ) -> List[Any]:
-        """Stacked per-edge host blobs for one row-aligned group of items
-        (each a per-edge blob tuple): ``launch_rows`` decides the row
-        count, padding repeats the last item.  The one place the group ->
-        stacked-batch policy lives: :class:`_JoinFeed` (stream + manual
-        serve drain) and the background serve flush both call it.  In
-        proportional mode the split vector is ALSO decided here — once per
-        group — and attached to every edge's stack, so a join's edges can
-        never disagree on the carve."""
+    def stack_group(self, items: Sequence[Tuple[Any, ...]]) -> List[Any]:
+        """Stacked per-edge batches for one row-aligned group of items
+        (each a per-edge tuple of Data, or of blobs packed at admission):
+        ``launch_rows`` decides the row count, padding repeats the last
+        item.  The one place the group -> stacked-batch policy lives:
+        :class:`_JoinFeed` (stream + manual serve drain) and the
+        background serve flush both call it.  An edge whose items all
+        live whole on one device stacks there; every other edge is
+        written row by row into a staging buffer from the app's pool, a
+        Data's host arrays packed straight into their row (one
+        ``stream.pack`` span an item), a packed blob copied.  In
+        proportional mode the split vector is ALSO decided here — once
+        per group — and attached to every edge's stack, so a join's edges
+        can never disagree on the carve."""
         rows = self.launch_rows(len(items))
+        layouts = self.launchable.in_layouts
         with trace.span("stream.stack", rows=rows):
-            stacks = [
-                _stack_blobs(_pad_rows([it[e] for it in items], rows), lay)
-                for e, lay in enumerate(self.launchable.in_layouts)]
+            cols = [[_source(it[e]) for it in items]
+                    for e in range(len(layouts))]
+            stacks: List[Any] = []
+            host = []
+            for e, (col, lay) in enumerate(zip(cols, layouts)):
+                if _on_one_device(col):
+                    stacks.append(_stack_device(_pad_rows(col, rows), lay))
+                else:
+                    stacks.append(self.staging.acquire(
+                        (rows, lay.total_words), self.staging_cap))
+                    host.append(e)
+            for r in range(len(items)):
+                writes = [(stacks[e][r], cols[e][r]) for e in host]
+                if any(isinstance(src, Data) for _, src in writes):
+                    with trace.span("stream.pack", row=r) as sp:
+                        sp.attrs["bytes"] = sum(_write_row(*w)
+                                                for w in writes)
+                else:
+                    for w in writes:
+                        _write_row(*w)
+            for e in host:
+                stacks[e][len(items):] = stacks[e][len(items) - 1]
         if not self.per_device:
             return stacks
         split = self.split_vector(rows)
         return [_SplitStack(s, split) for s in stacks]
 
     # ---------------------------------------------------- placement + launch
+    def put(self, blob: Any, target: Any) -> jax.Array:
+        """One placement (:func:`_put`); rows of a staging buffer are
+        reported to the app's pool, which waits for them before handing
+        the buffer out again."""
+        if not isinstance(blob, np.ndarray):
+            return _put(blob, target)
+        # a CPU device may adopt an aligned host buffer as its own memory
+        # (JAX 0.9 does so even under may_alias=False), and the staging
+        # buffer is written again: that placement copies first
+        devices = (target.device_set
+                   if isinstance(target, jax.sharding.Sharding) else {target})
+        out = _put(blob, target,
+                   copy=any(d.platform == "cpu" for d in devices))
+        self.staging.placed(blob, out)
+        return out
+
     def place(self, item: Any) -> Any:
-        """Place one edge's stacked host blob: a plain array goes to the
+        """Place one edge's stacked blob: a plain array goes to the
         plan's sharding/device in one ``device_put``; a
         :class:`_SplitStack` is carved into per-device sub-batches (one
         async ``device_put`` per device with a non-zero share)."""
         if not isinstance(item, _SplitStack):
             target = self.batch_sharding or self.process.getApp().device
-            return _put(item, target)
+            return self.put(item, target)
         parts, counts, devices = [], [], []
         off = 0
         for dev, c in zip(self._devices, item.split):
             if c:
                 sharding = self.device_executable(dev, c).batch_sharding
-                parts.append(_put(item.blob[off:off + c], sharding))
+                parts.append(self.put(item.blob[off:off + c], sharding))
                 counts.append(c)
                 devices.append(dev)
             off += c
@@ -793,23 +834,31 @@ class _BatchPlan:
         device feeding measured items/sec back into the registry (and the
         ``"compute"`` phase bucket when the plan carries a profile)."""
         with trace.span("stream.launch"):
-            if not isinstance(dev_blobs[0], SplitBatch):
-                t0 = time.perf_counter()
-                out = self.executable(int(dev_blobs[0].shape[0]))(
-                    tuple(dev_blobs), aux_blobs)
-                if self.profile is not None and self.profile.enable:
-                    self._time_completion(None, 0, t0, out)
-                return out
-            sb0 = dev_blobs[0]
-            out_parts = []
-            for j, (dev, c) in enumerate(zip(sb0.devices, sb0.counts)):
-                bp = self.device_executable(dev, c)   # may compile (cached)
-                aux = self._device_aux(dev, aux_blobs)
-                t0 = time.perf_counter()
-                out = bp(tuple(sb.parts[j] for sb in dev_blobs), aux)
-                out_parts.append(out)
-                self._time_completion(dev, c, t0, out)
-            return SplitBatch(out_parts, sb0.counts, sb0.devices)
+            out = self._launch(dev_blobs, aux_blobs)
+            # a donated input can no longer be waited for: the staging
+            # buffers placed as the inputs are fenced by the outputs
+            self.staging.consumed(_parts(dev_blobs), _parts((out,)))
+        return out
+
+    def _launch(self, dev_blobs: Sequence[Any],
+                aux_blobs: Sequence[jax.Array]) -> Any:
+        if not isinstance(dev_blobs[0], SplitBatch):
+            t0 = time.perf_counter()
+            out = self.executable(int(dev_blobs[0].shape[0]))(
+                tuple(dev_blobs), aux_blobs)
+            if self.profile is not None and self.profile.enable:
+                self._time_completion(None, 0, t0, out)
+            return out
+        sb0 = dev_blobs[0]
+        out_parts = []
+        for j, (dev, c) in enumerate(zip(sb0.devices, sb0.counts)):
+            bp = self.device_executable(dev, c)   # may compile (cached)
+            aux = self._device_aux(dev, aux_blobs)
+            t0 = time.perf_counter()
+            out = bp(tuple(sb.parts[j] for sb in dev_blobs), aux)
+            out_parts.append(out)
+            self._time_completion(dev, c, t0, out)
+        return SplitBatch(out_parts, sb0.counts, sb0.devices)
 
     def split_output(self, out: Any) -> List[jax.Array]:
         """Per-item output blobs of one launched group, in item order."""
@@ -884,50 +933,69 @@ class _BatchPlan:
         self._timers = [t for t in self._timers if t.is_alive()]
 
 
-def _host_blob_of(data: Data) -> "np.ndarray | jax.Array":
-    """Authoritative blob of one input Data.  Host arrays present → packed
-    host blob (the classic path).  A Data that lives ONLY on the device
-    (device-resident pipeline output, or any device-fresh Data whose host
-    arrays were never materialised) returns its device blob directly when
-    it sits whole on a single device — the device-to-device streaming fast
-    path: chained ``stream()`` calls never bounce intermediates through
-    the host (:func:`_stack_blobs` stacks them in place).  Multi-device
-    blobs still sync (stacking sharded rows device-side would shuffle
-    items across devices)."""
-    if data.layout is None:
-        data.plan()
-    if any(a.host is None for a in data):
-        blob = data.device_blob
+def _parts(batches: Sequence[Any]) -> List[jax.Array]:
+    """The device arrays of launched batches, a SplitBatch's parts each."""
+    return [p for b in batches
+            for p in (b.parts if isinstance(b, SplitBatch) else (b,))]
+
+
+def _source(item: Any) -> Any:
+    """What one item of one edge is stacked from.  A Data that lives ONLY
+    on the device (device-resident pipeline output, or any device-fresh
+    Data whose host arrays were never materialised) gives its device blob
+    when it sits whole on a single device — the device-to-device
+    streaming fast path: chained ``stream()`` calls never bounce
+    intermediates through the host.  Multi-device blobs sync to the host
+    first (stacking sharded rows device-side would shuffle items across
+    devices).  Any other Data is packed from its host arrays; a blob
+    packed at admission is stacked as it is."""
+    if not isinstance(item, Data):
+        return item
+    if item.layout is None:
+        item.plan()
+    if any(a.host is None for a in item):
+        blob = item.device_blob
         if (isinstance(blob, jax.Array) and not _is_deleted(blob)
                 and blob.ndim == 1 and len(blob.devices()) == 1):
             return blob                         # device-resident: no host trip
-        data.sync_to_host()  # raises if there is no device copy either
-    return data.pack_host()
+        item.sync_to_host()  # raises if there is no device copy either
+    return item
 
 
-def _stack_blobs(blobs: Sequence["np.ndarray | jax.Array"],
-                 layout) -> "np.ndarray | jax.Array":
-    """Stack one group's per-item blobs into a ``(rows, total_words)``
-    batch.  A group resident entirely on ONE device stacks there
-    (``jnp.stack`` — the device-to-device edge: zero host2device traffic,
-    and the downstream :class:`StreamQueue` placement becomes a
-    device-side move recorded under the ``"transfer_d2d"`` phase).  Mixed
-    or host groups take the validated host path, pulling any stray device
-    blobs back once."""
-    if all(isinstance(b, jax.Array) for b in blobs):
-        devices = {d for b in blobs for d in b.devices()}
-        if len(devices) == 1:
-            want = blob_spec(layout)
-            for b in blobs:
-                if tuple(b.shape) != want.shape or b.dtype != want.dtype:
-                    raise ValueError(
-                        f"device blob shape {tuple(b.shape)}/{b.dtype} does "
-                        f"not match the arena layout "
-                        f"{want.shape}/{want.dtype}")
-            import jax.numpy as jnp
-            return jnp.stack(blobs)
-    host = [np.asarray(b) if isinstance(b, jax.Array) else b for b in blobs]
-    return stack_host_blobs(host, layout)
+def _on_one_device(col: Sequence[Any]) -> bool:
+    return (all(isinstance(b, jax.Array) for b in col)
+            and len({d for b in col for d in b.devices()}) == 1)
+
+
+def _stack_device(blobs: Sequence[jax.Array], layout) -> jax.Array:
+    """Stack a group resident entirely on ONE device there (``jnp.stack``
+    — the device-to-device edge: zero host2device traffic, and the
+    downstream :class:`StreamQueue` placement becomes a device-side move
+    recorded under the ``"transfer_d2d"`` phase)."""
+    want = blob_spec(layout)
+    for b in blobs:
+        if tuple(b.shape) != want.shape or b.dtype != want.dtype:
+            raise ValueError(
+                f"device blob shape {tuple(b.shape)}/{b.dtype} does not "
+                f"match the arena layout {want.shape}/{want.dtype}")
+    import jax.numpy as jnp
+    return jnp.stack(blobs)
+
+
+def _write_row(row: np.ndarray, src: Any) -> int:
+    """Write one item into its row of a staging buffer: a Data's host
+    arrays packed in place, a blob (stray device blobs pulled back once)
+    copied.  Returns the row's bytes."""
+    if isinstance(src, Data):
+        src.pack_host(out=row)
+        return row.nbytes
+    blob = np.asarray(src)
+    if blob.shape != row.shape or blob.dtype != row.dtype:
+        raise ValueError(
+            f"blob shape {blob.shape}/{blob.dtype} does not match layout "
+            f"{row.shape}/{row.dtype}")
+    row[...] = blob
+    return row.nbytes
 
 
 def normalize_stream_item(item: Any, la: PureLaunchable,
@@ -967,16 +1035,15 @@ def normalize_stream_item(item: Any, la: PureLaunchable,
         f"tuple (got {type(item).__name__})")
 
 
-def _edge_blobs(item: Tuple[Data, ...], la: PureLaunchable,
-                *, what: str = "dataset",
-                names: Optional[Sequence[str]] = None,
-                err: type = ValueError) -> Tuple[np.ndarray, ...]:
-    """Per-edge packed host blobs of one normalized item, layout-checked
-    against every input edge (mismatches name the offending edge).  The
-    ONE pack-and-validate loop shared by streaming and serving —
-    ``names`` overrides the display names (serving shows graph edge names
-    instead of launchable input names), ``err`` the exception type."""
-    blobs = []
+def _checked_edges(item: Tuple[Data, ...], la: PureLaunchable,
+                   *, what: str = "dataset",
+                   names: Optional[Sequence[str]] = None,
+                   err: type = ValueError) -> Tuple[Data, ...]:
+    """One normalized item, layout-checked against every input edge
+    (mismatches name the offending edge).  The ONE validate loop shared
+    by streaming and serving — ``names`` overrides the display names
+    (serving shows graph edge names instead of launchable input names),
+    ``err`` the exception type."""
     for name, layout, d in zip(names or la.in_names, la.in_layouts, item):
         if d.layout is None:
             d.plan()
@@ -985,11 +1052,22 @@ def _edge_blobs(item: Tuple[Data, ...], la: PureLaunchable,
                 f"{what} layout for input edge {name!r} ({d.layout}) does "
                 f"not match the wired layout {layout}; all streamed Data "
                 "sets must be homogeneous per edge")
-        blobs.append(_host_blob_of(d))
+    return item
+
+
+def _edge_blobs(item: Tuple[Data, ...], la: PureLaunchable,
+                **check: Any) -> Tuple[Any, ...]:
+    """Per-edge blobs of one normalized, checked item, packed now (a
+    serving request is packed at admission, before its batch exists): a
+    packed host blob, or a device-resident Data's device blob."""
+    blobs = []
+    for d in _checked_edges(item, la, **check):
+        src = _source(d)
+        blobs.append(src.pack_host() if isinstance(src, Data) else src)
     return tuple(blobs)
 
 
-def _pad_rows(blobs: List[np.ndarray], rows: int) -> List[np.ndarray]:
+def _pad_rows(blobs: List[Any], rows: int) -> List[Any]:
     """Pad a group's blob list to ``rows`` by repeating the last item
     (padded outputs are dropped downstream)."""
     return blobs + [blobs[-1]] * (rows - len(blobs))
@@ -998,8 +1076,8 @@ def _pad_rows(blobs: List[np.ndarray], rows: int) -> List[np.ndarray]:
 class _JoinFeed:
     """Row-aligned per-edge batch feeds sharing ONE group plan.
 
-    ``groups`` yields lists of per-item blob tuples (one blob per input
-    edge, at most ``plan.batch`` items per list).  Each edge's
+    ``groups`` yields lists of per-item tuples (one Data or blob per
+    input edge, at most ``plan.batch`` items per list).  Each edge's
     :meth:`feed` generator yields that edge's stacked batch for exactly
     the same item groups — built by :meth:`_BatchPlan.stack_group`, so
     row count and padding are decided once for ALL edges — and zipping
@@ -1010,7 +1088,7 @@ class _JoinFeed:
     """
 
     def __init__(self, plan: _BatchPlan,
-                 groups: Iterator[List[Tuple[np.ndarray, ...]]]):
+                 groups: Iterator[List[Tuple[Any, ...]]]):
         self.plan = plan
         self.n_edges = plan.launchable.n_inputs
         self._it = groups
@@ -1107,8 +1185,9 @@ class _UploadLanes:
 
         self._devices = devices
         self._lanes = [
-            StreamQueue(lane_rows(j), device=plan.lane_sharding(dev),
-                        depth=depth, profile=profile)
+            StreamQueue(lane_rows(j), depth=depth, profile=profile,
+                        device=functools.partial(
+                            plan.put, target=plan.lane_sharding(dev)))
             for j, dev in enumerate(devices)]
         self._splits = fan.branch(len(devices))
 
@@ -1176,7 +1255,8 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
     with trace.span("stream.plan"):
         plan = _BatchPlan(process, batch, sharded=sharded,
                           tail_waste_threshold=tail_waste_threshold,
-                          split=split, lanes=lanes, profile=profile).init()
+                          split=split, lanes=lanes, depth=depth,
+                          profile=profile).init()
         la = plan.launchable
 
         aux_blobs = plan.prepare_aux()
@@ -1190,17 +1270,14 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
 
     # one row-aligned feed per input edge — a multi-input launchable gets
     # per-edge StreamQueues whose batches are zipped before each launch.
-    # Items are packed lazily as the queues pull (memory stays bounded by
-    # queue depth, as in the single-input path)
-    def groups() -> Iterator[List[Tuple[np.ndarray, ...]]]:
-        buf: List[Tuple[np.ndarray, ...]] = []
+    # Items are packed lazily, into their rows, as the queues pull (memory
+    # stays bounded by queue depth, as in the single-input path)
+    def groups() -> Iterator[List[Tuple[Data, ...]]]:
+        buf: List[Tuple[Data, ...]] = []
         for i, d in enumerate(datasets):
             what = f"datasets[{i}]"
-            with trace.span("stream.pack", item=i) as s:
-                blobs = _edge_blobs(normalize_stream_item(d, la, what=what),
-                                    la, what=what)
-                s.attrs["bytes"] = sum(b.nbytes for b in blobs)
-            buf.append(blobs)
+            buf.append(_checked_edges(
+                normalize_stream_item(d, la, what=what), la, what=what))
             if len(buf) == batch:
                 yield buf
                 buf = []
@@ -1215,7 +1292,7 @@ def stream_launch(process, datasets: Sequence[Any], *, batch: int = 1,
             _UploadLanes(plan, feed.feed(e), depth=depth, profile=profile)
             for e in range(la.n_inputs)]
     else:
-        queues = [StreamQueue(feed.feed(e), device=plan.queue_target,
+        queues = [StreamQueue(feed.feed(e), device=plan.place,
                               depth=depth, profile=profile)
                   for e in range(la.n_inputs)]
     t0 = time.perf_counter()

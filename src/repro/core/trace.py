@@ -2,15 +2,14 @@
 
 One recorder, always on.  :class:`span` marks a phase of a call::
 
-    with trace.span("stream.pack", item=i) as s:
-        blobs = pack(...)
-        s.attrs["bytes"] = sum(b.nbytes for b in blobs)
+    with trace.span("stream.pack", row=r) as s:
+        s.attrs["bytes"] = write(...)
 
 Each span records its name, ``start_ns``/``end_ns`` from
 ``time.perf_counter_ns()``, the id of the span that encloses it on the same
 thread (the span that caused it; ``None`` for a root) and its attrs, a
-request id among them where one exists (the LM ``rid``, the stream item
-index).  Records go into a ring of the last :data:`RING_SPANS` spans in
+request id among them where one exists (the LM ``rid``, a streamed
+item's row in its batch).  Records go into a ring of the last :data:`RING_SPANS` spans in
 memory; nothing is written anywhere.  Each span also enters a
 ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so that in a
 profiler trace it lands on the host plane, on the device trace's clock.
@@ -281,9 +280,16 @@ MOE_HELD_ASSIGNMENTS = METRICS.counter(
     "(token, expert) pairs computed here: the chosen expert is held here")
 MOE_ROWS = METRICS.counter(
     "repro_moe_rows_total", "token rows through an expert layer")
+STAGING_REUSES = METRICS.counter(
+    "repro_staging_reuses_total",
+    "host staging buffers of stacked batches handed out again")
+STAGING_ALLOCS = METRICS.counter(
+    "repro_staging_allocs_total",
+    "host staging buffers of stacked batches allocated")
 _PROCESS_COUNTERS = (H2D_BYTES, D2H_BYTES, COMPILES, COMPILE_SECONDS,
                      GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES, SUBWORD_BYTES,
-                     MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_ROWS)
+                     MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_ROWS,
+                     STAGING_REUSES, STAGING_ALLOCS)
 for _c in _PROCESS_COUNTERS:
     _c.inc(0.0)         # a series from the start: rendered at 0, and read
                         # unlocked by _counter_values

@@ -177,7 +177,7 @@ class PipelineServer:
         self._plan = _BatchPlan(
             built.executor, self.batch, sharded=self.sharded,
             tail_waste_threshold=self.tail_waste_threshold,
-            split=self.split, lanes=self.lanes).init()
+            split=self.split, lanes=self.lanes, depth=self.depth).init()
         # aux wiring is fixed for the server's lifetime: prepare (and, when
         # sharded, mesh-replicate) the aux blobs ONCE, not per drain
         app = built.executor.getApp()
@@ -346,7 +346,7 @@ class PipelineServer:
         # one row-aligned feed per input edge, zipped per launch (the
         # fan-in join path; single-input pipelines are the 1-edge case)
         feed = _JoinFeed(plan, group_iter())
-        queues = [StreamQueue(feed.feed(e), device=plan.queue_target,
+        queues = [StreamQueue(feed.feed(e), device=plan.place,
                               depth=self.depth)
                   for e in range(la.n_inputs)]
         responses: List[ServeResponse] = []

@@ -7,7 +7,7 @@ from hypothesis import given, strategies as st
 
 from repro.core import (ALIGN, ArenaLayout, pack_device, pack_host,
                         pack_tree_host, plan_layout, trace, unpack_device,
-                        unpack_host, unpack_tree_host)
+                        unpack_host, unpack_tree_host, write_host)
 
 DTYPES = ["float32", "int8", "int32", "bfloat16", "complex64", "bool", "uint8",
           "float16", "int16", "uint16"]
@@ -185,6 +185,49 @@ def test_word_sized_entries_keep_numpy_bytes(rng):
     on_device = jax.jit(lambda d: pack_device(d, layout))(
         {"f": jnp.asarray(f), "c": jnp.asarray(c), "i": jnp.asarray(i)})
     np.testing.assert_array_equal(_np(on_device).view(np.uint8), want)
+
+
+def _reference_words(arrs, layout):
+    """The arena's words by the format's definition, item by item: numpy's
+    bytes, a complex entry's real then imaginary plane, a sub-word entry's
+    item ``i`` in lane ``i // q`` of word ``i % q``, zeros elsewhere."""
+    out = np.zeros(layout.total_words, np.uint32)
+    raw = out.view(np.uint8)
+    for e in layout.entries:
+        a = _np(arrs[e.name]).reshape(-1)
+        if a.dtype.kind == "c":
+            a = np.concatenate([a.real, a.imag])
+        if a.dtype.itemsize >= 4:
+            raw[e.offset:e.offset + a.nbytes] = a.view(np.uint8)
+            continue
+        bits = 8 * a.dtype.itemsize
+        q = -(-a.size // (32 // bits))
+        for i, v in enumerate(a.view(f"uint{bits}").tolist()):
+            out[e.offset // 4 + i % q] |= np.uint32(v << (bits * (i // q)))
+    return out
+
+
+@pytest.mark.parametrize("shape", [(1,), (3, 5), (37,), (1029,)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", ["complex64", "float32", "float64",
+                                   "bfloat16", "int8", "bool"])
+def test_row_writer_writes_pack_host_words_over_a_used_row(rng, dtype,
+                                                           shape):
+    """``write_host`` into a row whose every bit is set gives exactly
+    ``pack_host``'s words, and the format's: no bit of what the row held
+    survives, in an entry, in a sub-word entry's last word, or in the
+    padding after it.  Sizes are neither multiples of 128 bytes nor of
+    the lanes a word holds."""
+    arrs = {"x": _mk(rng, shape, dtype), "after": _mk(rng, (5,), "int8"),
+            "last": _mk(rng, (3,), "float32")}
+    blob, layout = pack_host(arrs)
+    row = np.full(layout.total_words, 0xFFFFFFFF, np.uint32)
+    write_host(row, arrs, layout)
+    want = _reference_words(arrs, layout)
+    np.testing.assert_array_equal(blob, want)
+    np.testing.assert_array_equal(row, want)
+    with pytest.raises(ValueError, match="does not match layout"):
+        write_host(row[:-1], arrs, layout)
 
 
 def test_host_codec_counts_subword_bytes(rng):

@@ -777,6 +777,40 @@ def test_recon_2d_outputs_resident_on_every_device(rng):
 
 
 @needs_8_devices
+def test_sharded_stream_stages_each_batch_in_a_reused_buffer(rng):
+    """The sharded stream's batches (10 scans at batch 8 over data=8, the
+    tail padded) are stacked in reused staging buffers too: images equal
+    their own launch() bit for bit, buffers are reused from the second
+    call on, and no more than depth + 2 are made for the one shape."""
+    from repro.core import trace
+    from repro.processes import SimpleMRIRecon
+
+    def _c(shape):
+        return (rng.standard_normal(shape)
+                + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    items = [KData({"kdata": _c((4, 2, 16, 16)),
+                    "sensitivity_maps": _c((2, 16, 16))}) for _ in range(10)]
+    app = CLapp().init()
+    pipe = Pipeline(app) | SimpleMRIRecon(app, mode="fused_pallas")
+    want = [pipe.run(d).get_ndarray(0).host.copy() for d in items]
+
+    def counts():
+        return trace.STAGING_ALLOCS.value(), trace.STAGING_REUSES.value()
+    a0, r0 = counts()
+    for call in range(3):
+        _, reused = counts()
+        got = pipe.run(items, mode="stream", batch=8, sharded=True)
+        for i, (g, w) in enumerate(zip(got, want)):
+            np.testing.assert_array_equal(g.get_ndarray(0).host, w,
+                                          err_msg=f"call {call} item {i}")
+        if call:
+            assert counts()[1] > reused
+    allocs, reuses = counts()
+    assert allocs - a0 <= 2 + 2
+    assert (allocs - a0) + (reuses - r0) == 3 * 2     # one a batch
+
+
+@needs_8_devices
 def test_decode_2d_bit_identical():
     """DecodeStep on a (2, 4) mesh: the B=4 decode batch shard_maps one
     slot per model-group device (position via exact integer pmax) and the

@@ -1,13 +1,14 @@
 """Streaming executor: double-buffer correctness (streamed == sequential
 launch(), bitwise), batch-axis compile-cache hits, donation across streamed
-in-place chains, in-flight transfer tracking, and the loader->queue feed."""
+in-place chains, in-flight transfer tracking, the loader->queue feed, and
+the host staging buffers batches are stacked in."""
 import jax
 import numpy as np
 import pytest
 
 from repro.core import (BatchedProcess, CLapp, Coherence, Data,
                         DonatedBufferError, Process, ProcessChain,
-                        StreamQueue, XData, compile_cache_stats,
+                        StreamQueue, XData, compile_cache_stats, trace,
                         unpack_device)
 from repro.data.pipeline import ArenaFeed, StreamConfig, TokenStream
 
@@ -221,3 +222,79 @@ def test_arena_feed_streams_loader_batches(app):
     # data_at mirrors the same batch as a registrable Data
     d = feed.data_at(1)
     assert set(d.names) == {"tokens", "labels"}
+
+
+# ---------------------------------------------------------------------------
+# host staging buffers: one per stacked batch, reused across calls
+# ---------------------------------------------------------------------------
+
+def _staging_counts():
+    return (trace.STAGING_ALLOCS.value(), trace.STAGING_REUSES.value())
+
+
+def test_staging_pool_hands_a_buffer_out_again_once_placed_and_landed():
+    from repro.core.app import StagingPool
+    pool, shape = StagingPool(), (2, 32)
+    a0, r0 = _staging_counts()
+    first = pool.acquire(shape, cap=3)
+    second = pool.acquire(shape, cap=3)        # the first is being written
+    assert first.base is not second.base
+    pool.placed(first, jax.device_put(np.asarray(first)))
+    third = pool.acquire(shape, cap=3)          # landed: the first again
+    assert third.base is first.base
+    del second                                  # dropped unplaced: free
+    fourth = pool.acquire(shape, cap=3)
+    assert _staging_counts() == (a0 + 2, r0 + 2)
+    # past the cap, with every buffer still being written, a buffer is
+    # made but not kept
+    fifth = pool.acquire(shape, cap=2)
+    assert fifth.base is None and fourth.base is not None
+    assert _staging_counts() == (a0 + 3, r0 + 2)
+
+
+def test_staging_pool_fences_a_donated_placement_by_the_launch_output():
+    from repro.core.app import StagingPool
+    pool, shape = StagingPool(), (1, 16)
+    buf = pool.acquire(shape, cap=1)
+    placed = jax.device_put(np.asarray(buf))
+    pool.placed(buf, placed)
+    out = jax.jit(lambda x: x + 1, donate_argnums=0)(placed)
+    pool.consumed([placed], [out])
+    (slot,) = pool._pool[shape]
+    assert slot.fences == [out]
+    jax.block_until_ready(out)
+    assert pool.acquire(shape, cap=1).base is buf.base
+
+
+def _recon_items(rng, n):
+    from repro.core import KData
+    def c(shape):
+        return (rng.standard_normal(shape)
+                + 1j * rng.standard_normal(shape)).astype(np.complex64)
+    return [KData({"kdata": c((4, 2, 16, 16)),
+                   "sensitivity_maps": c((2, 16, 16))}) for _ in range(n)]
+
+
+def test_stream_stages_each_batch_in_a_reused_buffer(rng):
+    """Calls of 10 distinct scans at batch 4 (a padded ragged tail, more
+    batches over the calls than the pool holds): every image equals its
+    own launch() bit for bit, buffers are reused from the second call on,
+    and no more than depth + 2 are ever made for the one batch shape."""
+    from repro.core import Pipeline
+    from repro.processes import SimpleMRIRecon
+    app = CLapp().init()
+    pipe = Pipeline(app) | SimpleMRIRecon(app, mode="fused_pallas")
+    items = _recon_items(rng, 10)
+    want = [pipe.run(d).get_ndarray(0).host.copy() for d in items]
+    a0, r0 = _staging_counts()
+    for call in range(3):
+        _, reused = _staging_counts()
+        got = pipe.run(items, mode="stream", batch=4)
+        for i, (g, w) in enumerate(zip(got, want)):
+            np.testing.assert_array_equal(g.get_ndarray(0).host, w,
+                                          err_msg=f"call {call} item {i}")
+        if call:
+            assert _staging_counts()[1] > reused
+    allocs, reuses = _staging_counts()
+    assert allocs - a0 <= 2 + 2
+    assert (allocs - a0) + (reuses - r0) == 3 * 3     # one a batch

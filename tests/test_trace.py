@@ -197,6 +197,11 @@ def test_stream_run_counts_its_bytes_and_its_children_cover_it():
         assert c.counts["stream.stack"] == 2
         assert c.counts["stream.place"] == 2
         assert c.counts["stream.launch"] == 2
+        # one staging buffer a batch, the first of each call left over
+        # from the call before
+        assert c.deltas["repro_staging_reuses_total"] \
+            + c.deltas["repro_staging_allocs_total"] == 2
+        assert c.deltas["repro_staging_reuses_total"] >= 1
     # the phases account for the call: the root's own time is what no
     # phase covers (best of three calls, so a descheduled moment of a busy
     # test machine does not decide it)
@@ -228,7 +233,8 @@ def test_metrics_registry_renders_the_program_counters():
                  "repro_gc_pause_seconds_total",
                  "repro_compile_cache_hits_total",
                  "repro_compile_cache_misses_total",
-                 "repro_arena_subword_bytes_total"):
+                 "repro_arena_subword_bytes_total",
+                 "repro_staging_reuses_total", "repro_staging_allocs_total"):
         assert f"\n{name} " in "\n" + text, name
     from repro.serve import control
     assert control.Metrics is trace.Metrics
