@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows.
 
-    PYTHONPATH=src python -m benchmarks.run [table1 fig2 overhead roofline lm lm_decode stream mesh serve fanin pallas ckpt]
+    PYTHONPATH=src python -m benchmarks.run [table1 fig2 overhead roofline lm lm_decode stream serve fanin pallas ckpt]
 """
 from __future__ import annotations
 
@@ -13,8 +13,8 @@ def main() -> None:
     from repro.core import enable_compile_cache
     enable_compile_cache()
     which = set(sys.argv[1:]) or {"table1", "fig2", "overhead", "roofline",
-                                  "lm", "lm_decode", "stream", "mesh",
-                                  "serve", "fanin", "pallas", "ckpt"}
+                                  "lm", "lm_decode", "stream", "serve",
+                                  "fanin", "pallas", "ckpt"}
     print("name,us_per_call,derived")
     rows = []
     if "table1" in which:
@@ -38,9 +38,6 @@ def main() -> None:
     if "stream" in which:
         from benchmarks.stream_throughput import rows as stream_rows
         rows += stream_rows()
-    if "mesh" in which:
-        from benchmarks.mesh_scaling import rows as mesh_rows
-        rows += mesh_rows()
     if "serve" in which:
         from benchmarks.serve_latency import rows as serve_rows
         rows += serve_rows()

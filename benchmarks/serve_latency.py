@@ -26,8 +26,8 @@ Scenario 4 — **profile-informed routing** (PR 8): a burst of requests
 through a 2-replica pool with a 4:1 speed skew under **eager dispatch**
 (``dispatch_ahead=None`` — the router commits each request immediately,
 as a front-end before remote replicas must), routed ``"round-robin"``
-vs ``"profile"`` (smooth weighted round-robin over measured items/sec —
-the :class:`~repro.launch.mesh.DeviceProfileRegistry` signal).
+vs ``"profile"`` (smooth weighted round-robin over each replica's
+measured items/sec, a :class:`~repro.serve.control.DeviceProfile` EMA).
 Round-robin sends half the burst to the slow replica; the profile policy
 sends work where the capacity is and wins on makespan and p99;
 ``speedup`` in the JSON is round-robin makespan / profile makespan.

@@ -20,12 +20,6 @@ housekeeping promise.  Force a multi-device host CPU with, e.g.::
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python examples/mri_recon.py --stream 16 --batch 8 --sharded
 
-``--proportional`` (with ``--sharded``) switches the batch carve to
-``split="proportional"``: sub-batches sized by the measured per-device
-items/sec in ``app.device_profiles`` (the first batch runs balanced and
-doubles as the warmup measurement); the example prints the rates the run
-recorded and the split vector the next batch would get.
-
 ``--pipeline`` additionally demonstrates the declarative operator-graph
 API (docs/pipeline.md): the same reconstruction wired as ``Pipeline(app) |
 FFT | ComplexElementProd | XImageSum`` and routed through all three
@@ -43,8 +37,7 @@ in one launch.  The joined outputs are asserted bit-identical to the
 ``--pipeline`` graph in every mode.
 
 Run:  PYTHONPATH=src python examples/mri_recon.py [--fused] [--pallas]
-          [--stream N] [--batch K] [--sharded] [--proportional]
-          [--pipeline] [--join]
+          [--stream N] [--batch K] [--sharded] [--pipeline] [--join]
 """
 import sys
 import time
@@ -77,7 +70,7 @@ def _argval(flag: str, default: int) -> int:
 
 
 def stream_slice_stack(app, proc, cfg, n_slices: int, batch: int,
-                       sharded: bool = False, split: str = "equal") -> None:
+                       sharded: bool = False) -> None:
     """Reconstruct a stack of independent slice acquisitions via the
     streaming executor and verify bit-identity with sequential launch()."""
     slices = []
@@ -88,12 +81,10 @@ def stream_slice_stack(app, proc, cfg, n_slices: int, batch: int,
 
     import jax
     t0 = time.perf_counter()
-    outs = proc.stream(slices, batch=batch, sharded=sharded, split=split)
+    outs = proc.stream(slices, batch=batch, sharded=sharded)
     jax.block_until_ready([o.device_blob for o in outs])
     t_stream = time.perf_counter() - t0
     tag = "sharded stream" if sharded else "stream"
-    if split != "equal":
-        tag += f" split={split}"
     print(f"[{tag}] {n_slices} slices, batch={batch}: "
           f"{t_stream * 1e3:.1f} ms total, "
           f"{t_stream / n_slices * 1e3:.2f} ms/slice")
@@ -104,14 +95,6 @@ def stream_slice_stack(app, proc, cfg, n_slices: int, batch: int,
         print(f"[sharded stream] outputs resident on {len(used)} device(s) "
               f"of {len(app.devices)} selected "
               f"(mesh {dict(app.mesh.shape)})")
-    if split == "proportional":
-        # the warmup batches populated the registry; show what it measured
-        rates = app.device_profiles.rates(app.devices)
-        print("[proportional] measured device rates (items/s): "
-              + ", ".join(f"{r:.0f}" for r in rates)
-              + "; next split of a full batch: "
-              + str(app.device_profiles.split(batch, app.devices)
-                    or "balanced (cold/small)"))
 
     # spot-check one slice against the sequential oracle, bitwise via the
     # framework and numerically via numpy
@@ -122,20 +105,11 @@ def stream_slice_stack(app, proc, cfg, n_slices: int, batch: int,
     proc.launch()
     seq = np.asarray(app.getData(proc.out_handle).device_views()["xdata"])
     got = np.asarray(outs[-1].device_view("xdata"))
-    if split == "proportional":
-        # uneven sub-batch sizes: XLA's FFT picks per-batch-size algorithms,
-        # so the proportional carve matches at rtol 1e-6 instead of bitwise
-        # (the same caveat the ragged-tail executable carries)
-        np.testing.assert_allclose(got, seq, rtol=1e-6, atol=1e-6)
-        check_msg = "matches sequential launch() at rtol 1e-6"
-    else:
-        assert np.array_equal(got, seq), \
-            "streamed result must be bit-identical"
-        check_msg = "bit-identical to sequential launch()"
+    assert np.array_equal(got, seq), "streamed result must be bit-identical"
     want = oracle_recon(np.asarray(slices[-1].kdata.host),
                         np.asarray(slices[-1].smaps.host))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-    print(f"[stream] {check_msg}, oracle verified")
+    print("[stream] bit-identical to sequential launch(), oracle verified")
 
 
 def pipeline_demo(app, cfg, reference: np.ndarray, exact: bool = True) -> None:
@@ -318,9 +292,7 @@ def main() -> None:
                   exact=(mode == "staged" and not use_pallas))
 
     if n_stream:
-        split = "proportional" if "--proportional" in sys.argv else "equal"
-        stream_slice_stack(app, proc, cfg, n_stream, batch, sharded=sharded,
-                           split=split)
+        stream_slice_stack(app, proc, cfg, n_stream, batch, sharded=sharded)
 
 
 if __name__ == "__main__":

@@ -47,7 +47,7 @@ from .process import (
 )
 from .graph import GraphError, Node, Pipeline
 from .registry import KernelCompileError, KernelEntry, KernelRegistry, kernel
-from .stream import BatchedProcess, SplitBatch, StreamQueue, stream_launch
+from .stream import BatchedProcess, StreamQueue, stream_launch
 from .sync import Coherence, SyncSource
 
 __all__ = [
@@ -57,7 +57,7 @@ __all__ = [
     "KData", "KernelCompileError", "KernelEntry", "KernelRegistry",
     "NDArray", "Node", "NoMatchingDeviceError", "Pipeline", "PlatformTraits",
     "Port", "PortError", "Process", "ProcessChain", "ProfileParameters",
-    "PureLaunchable", "SplitBatch", "StreamQueue", "SyncSource", "XData",
+    "PureLaunchable", "StreamQueue", "SyncSource", "XData",
     "aot_compile",
     "batched_spec", "compile_cache_dir", "compile_cache_stats",
     "device_view", "enable_compile_cache", "kernel",

@@ -2,14 +2,13 @@
 
 Owns: device discovery & selection by traits, the data registry
 (handle -> Data, device-resident arena blobs), the kernel registry, the
-``("data", "model")`` device mesh built over the selected devices, the
-per-device throughput profiles (:attr:`CLapp.device_profiles`) that drive
-throughput-proportional batch splitting, and the host staging buffers
-streamed batches are written into (:attr:`CLapp.staging`).  This is the single place where
-"housekeeping" lives, exactly as in the paper: ``init()`` selects devices
-in one call, and everything downstream — transfers (``host2device`` places
-via ``NamedSharding``), launches, sharded streaming, proportional splits —
-is device-count-agnostic.
+``("data", "model")`` device mesh built over the selected devices, and the
+host staging buffers streamed batches are written into
+(:attr:`CLapp.staging`).  This is the single place where "housekeeping"
+lives, exactly as in the paper: ``init()`` selects devices in one call,
+and everything downstream — transfers (``host2device`` places via
+``NamedSharding``), launches, sharded streaming — is
+device-count-agnostic.
 
 Operators are wired to Data declaratively: ``Process.bind(...)`` maps
 typed ports to named edges and :class:`~repro.core.graph.Pipeline`
@@ -17,14 +16,6 @@ composes, validates, and runs the graph in all three execution modes (see
 :mod:`repro.core.graph` and ``docs/pipeline.md``).  Handles registered
 with :meth:`CLapp.addData` remain the currency between operators and the
 arena — the Pipeline plumbs them for you.
-
-Throughput profiles: :attr:`device_profiles` is a
-:class:`repro.launch.mesh.DeviceProfileRegistry` recording measured
-items/sec per selected device.  The streaming executor's
-``split="proportional"`` policy records into it on every launch (warmup
-batches run balanced while the profiles are cold) and reads it back to
-carve each stacked batch proportionally to what the devices actually
-deliver; see :mod:`repro.core.stream` and ``docs/architecture.md``.
 """
 from __future__ import annotations
 
@@ -231,12 +222,6 @@ class CLapp:
         self._data: Dict[DataHandle, Data] = {}
         self._next_handle: DataHandle = 0
         self.kernels = KernelRegistry()
-        # measured per-device throughput (items/sec), fed by the streaming
-        # executor's proportional-split launches and read back to carve the
-        # next batch; survives re-init (profiles are keyed by device id, so
-        # deselected devices simply stop being consulted)
-        from repro.launch.mesh import DeviceProfileRegistry  # lazy: keep core light
-        self.device_profiles = DeviceProfileRegistry()
         self._initialized = False
         # handle -> coherence state to settle into once the dispatched
         # host->device transfer lands (see host2device(wait=False))
@@ -313,11 +298,9 @@ class CLapp:
         """Partition the selected devices into ``n`` independent replica
         apps — the backend pool of the serving control plane
         (:class:`repro.serve.control.FrontDoor`): each returned app owns a
-        contiguous, disjoint device subset with its own mesh, data
-        registry, and :class:`~repro.launch.mesh.DeviceProfileRegistry`,
-        so replicas profile (and fail) in isolation.  Requires at least
-        one device per replica; extra devices go to the earlier replicas
-        (the same largest-first convention as the balanced batch split).
+        contiguous, disjoint device subset with its own mesh and data
+        registry, so replicas fail in isolation.  Requires at least one
+        device per replica; extra devices go to the earlier replicas.
         """
         devices = self.devices            # raises if init() never ran
         if n < 1:
@@ -326,7 +309,7 @@ class CLapp:
             raise ValueError(
                 f"cannot split {len(devices)} device(s) into {n} replicas "
                 "(each replica needs at least one device)")
-        from repro.launch.mesh import DeviceProfileRegistry, make_data_mesh
+        from repro.launch.mesh import make_data_mesh
         base, extra = divmod(len(devices), n)
         apps, start = [], 0
         for i in range(n):
@@ -335,8 +318,6 @@ class CLapp:
             app._devices = list(devices[start:stop])
             app._mesh = make_data_mesh(app._devices)
             app._initialized = True
-            app.device_profiles = DeviceProfileRegistry(
-                ema=self.device_profiles.ema)
             apps.append(app)
             start = stop
         return apps

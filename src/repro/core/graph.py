@@ -777,9 +777,7 @@ class Pipeline:
         return tuple(by_edge[e] for e in built.input_order)
 
     def run(self, inputs: Any = None, *, mode: str = "launch",
-            batch: int = 1, sharded: bool = False, depth: int = 2,
-            sync: bool = True, tail_waste_threshold: float = 0.5,
-            split: str = "equal", lanes: bool = False,
+            batch: int = 1, sharded: bool = False, sync: bool = True,
             profile: Optional[ProfileParameters] = None) -> Any:
         """Route the validated graph through one of three execution modes.
 
@@ -799,17 +797,13 @@ class Pipeline:
         edge is batched independently and the per-edge batches are zipped
         row-aligned into one joined launch.
 
-        ``batch``/``sharded``/``depth``/``tail_waste_threshold``/``split``
-        apply to the stream and serve modes (see :meth:`Process.stream`;
-        ``split="proportional"`` carves each stacked batch over the mesh
-        devices proportionally to their measured throughput, falling back
-        to the equal split while the ``app.device_profiles`` registry is
-        cold).  With
-        ``sync=True`` (default) results are copied back to host arrays;
-        otherwise they stay device-fresh.  All three modes execute the SAME
-        compiled per-item computation — outputs are bit-identical across
-        modes and to the legacy imperative protocol, and a streamed join is
-        bit-identical to the same port bound as a static aux broadcast.
+        ``batch``/``sharded`` apply to the stream and serve modes (see
+        :meth:`Process.stream`).  With ``sync=True`` (default) results are
+        copied back to host arrays; otherwise they stay device-fresh.  All
+        three modes execute the SAME compiled per-item computation —
+        outputs are bit-identical across modes and to the legacy imperative
+        protocol, and a streamed join is bit-identical to the same port
+        bound as a static aux broadcast.
         """
         with trace.span("pipeline.run", mode=mode):
             if mode == "launch":
@@ -862,16 +856,13 @@ class Pipeline:
                     items = [self._item_tuple(built, d, what=f"inputs[{i}]")
                              for i, d in enumerate(datasets)]
                 return built.executor.stream(
-                    items, batch=batch, depth=depth, sync=sync,
-                    sharded=sharded, tail_waste_threshold=tail_waste_threshold,
-                    split=split, lanes=lanes, profile=profile)
+                    items, batch=batch, sync=sync, sharded=sharded,
+                    profile=profile)
             if mode == "serve":
                 requests = list(inputs or ())
                 if not requests:
                     return []
-                server = self.serve(batch=batch, sharded=sharded, depth=depth,
-                                    tail_waste_threshold=tail_waste_threshold,
-                                    split=split, lanes=lanes)
+                server = self.serve(batch=batch, sharded=sharded)
                 rids = [server.submit(d) for d in requests]
                 by_rid = {r.rid: r for r in server.drain()}
                 outs = []
@@ -886,24 +877,17 @@ class Pipeline:
             raise ValueError(f"unknown mode {mode!r}: expected "
                              "'launch' | 'stream' | 'serve'")
 
-    def serve(self, *, batch: int = 8, sharded: bool = False, depth: int = 2,
-              tail_waste_threshold: float = 0.5, split: str = "equal",
-              lanes: bool = False,
+    def serve(self, *, batch: int = 8, sharded: bool = False,
               flush_timeout: Optional[float] = None):
         """A standing request/response loop over this pipeline (admission
         queue -> dynamic batcher -> batched (sharded) joined launches); see
         :class:`repro.serve.pipeline.PipelineServer`.  ``flush_timeout``
         (seconds) enables the background drain thread: a partial batch is
         flushed once its oldest request has waited that long instead of
-        waiting for a full batch.  ``split="proportional"`` carves each
-        served batch over the mesh devices by measured throughput (see
-        :meth:`Process.stream`)."""
+        waiting for a full batch."""
         from repro.serve.pipeline import PipelineServer  # lazy: serve layer
 
         return PipelineServer(self, batch=batch, sharded=sharded,
-                              depth=depth,
-                              tail_waste_threshold=tail_waste_threshold,
-                              split=split, lanes=lanes,
                               flush_timeout=flush_timeout)
 
     @staticmethod

@@ -110,8 +110,8 @@ class ProfileParameters:
     :meth:`record_phase` — the streaming executor and ``aot_compile`` use
     the conventional names ``"transfer"`` (host→device uploads),
     ``"compile"`` (trace+lower+compile on a cache miss) and ``"compute"``
-    (executable run to completion), so benchmarks can show where a scaling
-    curve's time actually goes (``benchmarks/mesh_scaling.py``).
+    (executable run to completion), so benchmarks can show where a
+    launch's time actually goes.
     """
 
     enable: bool = False
@@ -250,11 +250,10 @@ _COMPILE_CACHE: Dict[Any, Any] = {}
 # This is the sharding-propagation hook behind the logical-axis annotation
 # layer: `repro.launch.mesh.shard_by_logical` resolves it at trace time, so
 # ONE annotated apply() body lowers model-sharded under the app's 2D mesh,
-# and unsharded (a total no-op) inside pinned per-device/per-group
-# executables whose mesh has a trivial `model` axis.  A plain module global
-# (not a contextvar): aot_compile holds no locks and the compile cache is
-# only mutated from the thread that traces, which is the thread that reads
-# this.
+# and unsharded (a total no-op) under a mesh of one device.  A plain module
+# global (not a contextvar): aot_compile holds no locks and the compile
+# cache is only mutated from the thread that traces, which is the thread
+# that reads this.
 _CURRENT_COMPILE_MESH: Any = None
 
 
@@ -834,9 +833,7 @@ class Process:
 
     # -- streaming (beyond paper; see repro.core.stream) -----------------------
     def stream(self, datasets: Sequence[Any], batch: int = 1, *,
-               depth: int = 2, sync: bool = False, sharded: bool = False,
-               tail_waste_threshold: float = 0.5, split: str = "equal",
-               lanes: bool = False,
+               sync: bool = False, sharded: bool = False,
                profile: ProfileParameters | None = None) -> List[Any]:
         """Run many independent input Data sets through this process.
 
@@ -861,28 +858,16 @@ class Process:
         bit-identical and each item's output stays on the device that
         computed it.  Requires ``batch`` divisible by the device count.
 
-        ``split`` picks the batch-carving policy under ``sharded=True``:
-        ``"equal"`` (default) gives every device the same number of rows
-        via one mesh-sharded launch; ``"proportional"`` carves each batch
-        into per-device sub-batches sized by the measured items/sec in
-        ``app.device_profiles`` — self-calibrating (every launch refines
-        the rates), falling back to an equal/balanced carve while profiles
-        are cold or the batch is too small to matter, and lifting the
-        batch-divisibility requirement.  Outputs are bit-identical either
-        way; see the :mod:`repro.core.stream` module docstring.
-
         Ragged tail: when the final batch has fewer than ``batch`` items
-        and the padding waste fraction exceeds ``tail_waste_threshold``, a
-        second, smaller executable is compiled for the tail instead of
-        padding by repetition (set the threshold ``>= 1.0`` to always pad,
-        the pre-tail behaviour).
+        and the padding waste fraction exceeds
+        :data:`repro.core.stream.TAIL_WASTE` (0.5), a second, smaller
+        executable is compiled for the tail instead of padding by
+        repetition (see :func:`repro.core.stream.launch_rows`).
         """
         from .stream import stream_launch  # local import: avoid cycle
 
-        return stream_launch(self, datasets, batch=batch, depth=depth,
-                             sync=sync, sharded=sharded,
-                             tail_waste_threshold=tail_waste_threshold,
-                             split=split, lanes=lanes, profile=profile)
+        return stream_launch(self, datasets, batch=batch, sync=sync,
+                             sharded=sharded, profile=profile)
 
 
 class ProcessChain(Process):

@@ -24,11 +24,9 @@ as much framework housekeeping as device selection was:
   pipeline instance — see :meth:`repro.core.app.CLapp.split`) by a
   pluggable policy: ``"round-robin"``, ``"least-outstanding"``, or
   ``"profile"`` — smooth weighted round-robin with weights taken from
-  each replica's **measured items/sec** (the PR-5
-  :class:`~repro.launch.mesh.DeviceProfileRegistry` signal), refined
-  after every completed batch, so the split across replicas
-  self-calibrates exactly like the proportional batch split does across
-  devices.
+  each replica's **measured items/sec** (a :class:`DeviceProfile` EMA),
+  refined after every completed batch, so the split across replicas
+  self-calibrates.
 * **Observability** — a :class:`~repro.core.trace.Metrics` registry
   (counters / gauges / histograms with label sets and a Prometheus-
   exposition :meth:`Metrics.render`; pass ``metrics=trace.METRICS`` to
@@ -63,8 +61,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.process import ProfileParameters
 from repro.core.trace import Counter, Gauge, Histogram, Metrics  # noqa: F401
-from repro.launch.mesh import DeviceProfile
 
 __all__ = [
     "AdmissionRejected", "CallableReplica", "FrontDoor", "Metrics",
@@ -149,6 +147,54 @@ class _Ticket:
 # Replicas
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass
+class DeviceProfile:
+    """Measured throughput of one replica's devices: items/sec, refined
+    per completed batch.
+
+    ``record(items, seconds)`` folds one observation into an exponential
+    moving average (``ema`` weight on the newest sample), so the estimate
+    tracks drifting speed (thermal throttling, contention) without a
+    warmup restart.  The raw per-batch wall times are kept in a
+    :class:`~repro.core.process.ProfileParameters` (``seconds``) so the
+    usual mean/p50/p99 statistics are available for introspection.
+    """
+
+    ema: float = 0.3
+    items: int = 0                  # total items processed
+    _rate: float = float("nan")     # EMA items/sec
+
+    def __post_init__(self):
+        self.seconds = ProfileParameters(enable=True)
+
+    def record(self, items: int, seconds: float) -> None:
+        """Fold one measured batch (``items`` rows in ``seconds``) in."""
+        if items <= 0 or seconds <= 0:
+            return
+        self.seconds.record(seconds)
+        self.items += int(items)
+        sample = items / seconds
+        if self.cold:
+            self._rate = sample
+        else:
+            self._rate = self.ema * sample + (1.0 - self.ema) * self._rate
+
+    @property
+    def rate(self) -> float:
+        """Current items/sec estimate; ``nan`` when nothing was recorded."""
+        return self._rate
+
+    @property
+    def cold(self) -> bool:
+        return self._rate != self._rate      # nan check
+
+    def set_rate(self, rate: float) -> None:
+        """Seed the estimate directly (benchmarks, tests, emulated pools)."""
+        if rate < 0:
+            raise ValueError(f"rate must be >= 0 items/sec, got {rate}")
+        self._rate = float(rate)
+
+
 class Replica:
     """One serving backend behind the FrontDoor.
 
@@ -156,7 +202,7 @@ class Replica:
     payloads, return the list of results in the same order.  The base
     class owns the control-plane bookkeeping: an in-flight counter, a
     health flag, a latency profile, and a measured items/sec rate (a
-    :class:`~repro.launch.mesh.DeviceProfile` EMA fed by the FrontDoor
+    :class:`DeviceProfile` EMA fed by the FrontDoor
     after every completed batch — the signal behind the ``"profile"``
     routing policy)."""
 
@@ -171,8 +217,8 @@ class Replica:
         self.in_flight = 0              # dispatched to replica, not completed
         self.served = 0
         self.last_error: Optional[BaseException] = None
-        # replica-level throughput EMA; device_id=-1 marks "whole replica"
-        self.profile = DeviceProfile(device_id=-1)
+        # replica-level throughput EMA
+        self.profile = DeviceProfile()
 
     # -- backend contract ---------------------------------------------------
     def process(self, payloads: Sequence[Any]) -> List[Any]:
@@ -217,13 +263,7 @@ class PipelineReplica(Replica):
     ``{edge: Data}`` mapping for fan-in graphs); results are the served
     output Data, in request order.  ``max_batch`` follows the server's
     dynamic-batch size, so one FrontDoor dispatch fills at most one
-    batched launch.
-
-    When the replica's ``CLapp`` has warm per-device throughput profiles
-    (``split="proportional"`` streaming feeds them), :attr:`rate` prefers
-    their sum — the measured capacity of the replica's whole device
-    subset — over the FrontDoor-side EMA, so the ``"profile"`` routing
-    policy and the proportional batch split read the same signal."""
+    batched launch."""
 
     def __init__(self, name: str, server, *, probe_request: Any = None):
         super().__init__(name, max_batch=server.batch,
@@ -242,13 +282,6 @@ class PipelineReplica(Replica):
     @property
     def app(self):
         return self.server.pipeline.app
-
-    @property
-    def rate(self) -> float:
-        total = self.app.device_profiles.total_rate(self.app.devices)
-        if total == total:          # registry warm: measured device capacity
-            return total
-        return self.profile.rate
 
     def warm_start(self, directory: str, handle, *,
                    step: Optional[int] = None) -> int:
@@ -321,9 +354,8 @@ class Router:
       proportional to each replica's measured items/sec (:attr:`Replica.
       rate`); a cold replica weighs in at the mean warm rate (or 1.0
       when every replica is cold — degenerating to plain round-robin),
-      so routing self-calibrates exactly like PR 5's proportional batch
-      split: the first dispatches measure, every later one is carved by
-      what the replicas actually delivered.
+      so routing self-calibrates: the first dispatches measure, every
+      later one is carved by what the replicas actually delivered.
     """
 
     POLICIES = ("round-robin", "least-outstanding", "profile")

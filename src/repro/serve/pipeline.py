@@ -24,14 +24,7 @@ executor in a request/response loop —
   :class:`repro.core.stream.StreamQueue` s (the admission buffer per the
   ROADMAP), zipped before each launch: batch *i+1* is in flight to the
   device — sharded across the mesh's ``data`` axis when ``sharded=True``
-  — while batch *i* computes.  With ``split="proportional"`` each served
-  batch is instead carved into per-device sub-batches sized by the
-  measured throughput in ``app.device_profiles`` (equal fallback while
-  profiles are cold); see :mod:`repro.core.stream`.  ``lanes=True`` keeps
-  the equal carve but routes it through the same per-device machinery
-  (one pinned sub-batch + executable per mesh device), so served batch
-  sizes need not divide the device count and each device's upload is
-  dispatched independently.
+  — while batch *i* computes.
 * **Flush timeout** — with ``flush_timeout`` (seconds) set, a background
   drain thread serves continuously: full batches launch immediately, and
   a PARTIAL batch is flushed once its oldest request has waited
@@ -60,10 +53,11 @@ import numpy as np
 
 from repro.core import trace
 from repro.core.app import CLapp
+from repro.core.arena import split_batched_blob
 from repro.core.data import Data
 from repro.core.graph import Pipeline
 from repro.core.process import PortError
-from repro.core.stream import (StreamQueue, _BatchPlan, _JoinFeed,
+from repro.core.stream import (DEPTH, StreamQueue, _BatchPlan, _JoinFeed,
                                _edge_blobs)
 from repro.core.sync import Coherence
 
@@ -135,8 +129,6 @@ class PipelineServer:
     """
 
     def __init__(self, pipeline, *, batch: int = 8, sharded: bool = False,
-                 depth: int = 2, tail_waste_threshold: float = 0.5,
-                 split: str = "equal", lanes: bool = False,
                  flush_timeout: Optional[float] = None):
         if batch < 1:
             raise ValueError(f"batch must be >= 1, got {batch}")
@@ -146,10 +138,6 @@ class PipelineServer:
         self.pipeline = pipeline
         self.batch = batch
         self.sharded = sharded
-        self.depth = depth
-        self.tail_waste_threshold = tail_waste_threshold
-        self.split = split
-        self.lanes = lanes
         self.flush_timeout = flush_timeout
         self._pending: Deque[_Request] = deque()
         self._next_rid = 0
@@ -174,10 +162,8 @@ class PipelineServer:
             return
         built = self.pipeline.build(request)
         self._built = built
-        self._plan = _BatchPlan(
-            built.executor, self.batch, sharded=self.sharded,
-            tail_waste_threshold=self.tail_waste_threshold,
-            split=self.split, lanes=self.lanes, depth=self.depth).init()
+        self._plan = _BatchPlan(built.executor, self.batch,
+                                sharded=self.sharded).init()
         # aux wiring is fixed for the server's lifetime: prepare (and, when
         # sharded, mesh-replicate) the aux blobs ONCE, not per drain
         app = built.executor.getApp()
@@ -202,12 +188,7 @@ class PipelineServer:
         batch plus every partial-flush row count the ragged-tail policy
         can pick.  Keeps first-seen group sizes (e.g. timing-dependent
         partial flushes under ``flush_timeout``) from paying XLA compile
-        time inside a served window.  Under ``split="proportional"`` the
-        covered vectors are the balanced fallback plus the vector the
-        registry holds NOW — as measurements refine, a shifted vector can
-        still compile one new (device, rows) executable lazily (cached
-        forever after); call ``warmup()`` again after a calibration run
-        for full coverage."""
+        time inside a served window."""
         if self._plan is None:
             raise RuntimeError("server not built yet (submit a request)")
         for r in range(1, self.batch + 1):
@@ -275,7 +256,7 @@ class PipelineServer:
                        out: jax.Array, t_done: float) -> List[ServeResponse]:
         la = self._plan.launchable
         with trace.span("stream.split"):
-            per_item = self._plan.split_output(out)[:len(group)]
+            per_item = split_batched_blob(out)[:len(group)]
             self.launches += 1
             responses = []
             for req, blob in zip(group, per_item):
@@ -318,11 +299,7 @@ class PipelineServer:
 
         # compile the expected tail executable(s) BEFORE the launch loop so
         # a partial flush never stalls serving (nor charges XLA compile
-        # time to the requests' recorded latencies).  Under
-        # split="proportional" this covers the balanced fallback and the
-        # CURRENT measured vector; a vector that shifts as the registry
-        # refines can still pay one lazy compile per new (device, rows)
-        # pair — see _BatchPlan.precompile.
+        # time to the requests' recorded latencies)
         tail = len(self._pending) % self.batch
         if tail:
             plan.precompile(tail)
@@ -346,8 +323,7 @@ class PipelineServer:
         # one row-aligned feed per input edge, zipped per launch (the
         # fan-in join path; single-input pipelines are the 1-edge case)
         feed = _JoinFeed(plan, group_iter())
-        queues = [StreamQueue(feed.feed(e), device=plan.place,
-                              depth=self.depth)
+        queues = [StreamQueue(feed.feed(e), device=plan.place, depth=DEPTH)
                   for e in range(la.n_inputs)]
         responses: List[ServeResponse] = []
         for dev_blobs in zip(*queues):  # next flush transfers while this runs
@@ -358,7 +334,6 @@ class PipelineServer:
                 t_done = time.perf_counter()
                 responses.extend(self._responses_for(group, out, t_done))
         self.served += len(responses)
-        plan.join_timers()      # results are ready; settle the rate timers
         return responses
 
     # ------------------------------------------- background drain (timeout)

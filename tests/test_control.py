@@ -19,7 +19,7 @@ import pytest
 from repro.core import CLapp, Pipeline, Process, XData
 from repro.serve import (AdmissionRejected, CallableReplica, FrontDoor,
                          Metrics, PipelineReplica, PriorityClass, Router)
-from repro.serve.control import Counter, Gauge, Histogram
+from repro.serve.control import Counter, DeviceProfile, Gauge, Histogram
 
 
 @pytest.fixture
@@ -490,9 +490,37 @@ def test_frontdoor_metrics_accounting():
         fd.close()
 
 
+def test_device_profile_records_ema():
+    p = DeviceProfile(ema=0.5)
+    assert p.cold and p.rate != p.rate          # nan
+    p.record(10, 1.0)                           # first sample sets directly
+    assert p.rate == pytest.approx(10.0)
+    p.record(20, 1.0)                           # 0.5*20 + 0.5*10
+    assert p.rate == pytest.approx(15.0)
+    assert p.items == 30
+    assert len(p.seconds.samples) == 2          # raw wall times kept
+    assert p.seconds.mean() == pytest.approx(1.0)
+
+
+def test_device_profile_ignores_degenerate_samples():
+    p = DeviceProfile()
+    p.record(0, 1.0)
+    p.record(4, 0.0)
+    p.record(4, -1.0)
+    assert p.cold
+
+
+def test_device_profile_set_rate():
+    p = DeviceProfile()
+    p.set_rate(3.0)
+    assert p.rate == 3.0 and not p.cold
+    with pytest.raises(ValueError):
+        p.set_rate(-1.0)
+
+
 def test_replica_rate_self_calibrates():
     """Without seeding, completed batches feed the replica EMA — the
-    profile signal warms itself exactly like PR 5's proportional split."""
+    profile signal warms itself."""
     r = CallableReplica("r", lambda p: p)
     assert r.rate != r.rate                   # cold: nan
     fd = FrontDoor([r], capacity=8)
@@ -575,7 +603,6 @@ def test_clapp_split_partitions_devices(app):
     assert [p.device for p in parts] == list(app.devices)
     for p in parts:
         assert p.mesh is not None
-        assert p.device_profiles is not app.device_profiles
     with pytest.raises(ValueError, match="at least one device"):
         app.split(n + 1)
     with pytest.raises(ValueError, match="n >= 1"):

@@ -318,7 +318,8 @@ def test_composite_streams_mappings_by_its_own_port_names(app):
 # ragged tails on joined edges
 # ---------------------------------------------------------------------------
 
-def test_joined_ragged_tail_compiles_one_shared_executable(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_joined_ragged_tail_compiles_one_shared_executable(app, rng, sharded):
     """9 items at batch=8 on a two-edge join: waste 7/8 > 0.5 -> ONE tail
     executable spanning both edges (not one per edge), and per-item math
     still holds."""
@@ -331,7 +332,7 @@ def test_joined_ragged_tail_compiles_one_shared_executable(app, rng):
     items = [{"x": l, "r": r} for l, r in zip(lhs, rhs)]
     pipe.build(items[0])                 # single-shot compile outside count
     h0, m0 = compile_cache_stats()
-    outs = pipe.run(items, mode="stream", batch=8)
+    outs = pipe.run(items, mode="stream", batch=8, sharded=sharded)
     h1, m1 = compile_cache_stats()
     assert m1 - m0 == 2, "main batched program + ONE shared tail program"
     assert len(outs) == 9
@@ -342,11 +343,12 @@ def test_joined_ragged_tail_compiles_one_shared_executable(app, rng):
             rtol=1e-6)
     # same tail again: everything from the cache
     h2, m2 = compile_cache_stats()
-    pipe.run(items, mode="stream", batch=8)
+    pipe.run(items, mode="stream", batch=8, sharded=sharded)
     assert compile_cache_stats()[1] == m2, "repeat stream compiles nothing"
 
 
-def test_joined_small_tail_pads_rows_aligned(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_joined_small_tail_pads_rows_aligned(app, rng, sharded):
     """10 items at batch=4: the padded tail must stay row-aligned across
     edges (item i of edge A multiplied with item i of edge B, never a
     padded row of one edge against a real row of the other)."""
@@ -357,7 +359,7 @@ def test_joined_small_tail_pads_rows_aligned(app, rng):
     lhs = [_img(rng, shape) for _ in range(10)]
     rhs = [_img(rng, shape) for _ in range(10)]
     items = [{"x": l, "r": r} for l, r in zip(lhs, rhs)]
-    outs = pipe.run(items, mode="stream", batch=4)
+    outs = pipe.run(items, mode="stream", batch=4, sharded=sharded)
     for l, r, o in zip(lhs, rhs, outs):
         np.testing.assert_allclose(
             o.get_ndarray(0).host,
@@ -418,12 +420,13 @@ def test_server_multi_tensor_requests(app, rng):
         server.submit({"x": _img(rng, (2, 2)), "r": _img(rng, (2, 2))})
 
 
-def test_server_flush_timeout_background_drain(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_server_flush_timeout_background_drain(app, rng, sharded):
     """A partial batch is flushed by the background thread after
     flush_timeout instead of waiting for a full batch; results match and
     latency reflects the timeout wait."""
     pipe = Pipeline(app) | Scale(app).bind(params=-3.0)
-    server = pipe.serve(batch=8, flush_timeout=0.05)
+    server = pipe.serve(batch=8, sharded=sharded, flush_timeout=0.05)
     try:
         # warm up the tail executables outside the timed window
         server.submit(_img(rng))

@@ -2,10 +2,10 @@
 All kernels run in interpret mode on CPU (same blocking/grid semantics).
 
 The ``sharded_pallas`` section validates every kernel INSIDE the streaming
-executor — vmapped :class:`BatchedProcess`, ``sharded=True``,
-``split="proportional"``, ``lanes=True`` — on 8 devices;
-``test_rerun_forced_eight_devices_pallas`` re-runs just that section in a
-forced-8-host-device subprocess so it executes in a plain tier-1 pass.
+executor — vmapped :class:`BatchedProcess`, ``sharded=True`` — on 8
+devices; ``test_rerun_forced_eight_devices_pallas`` re-runs just that
+section in a forced-8-host-device subprocess so it executes in a plain
+tier-1 pass.
 """
 import os
 import subprocess
@@ -451,7 +451,7 @@ def test_rerun_forced_eight_devices_pallas():
     assert "passed" in r.stdout
 
 
-def _kernel_stream_case(app, rng, proc_cls, mk_item, ref_fn, n=16, **stream_kw):
+def _kernel_stream_case(app, rng, proc_cls, mk_item, ref_fn, n=16):
     """Stream ``n`` independent items through a kernel-wrapper Process and
     check every output against the pure-jnp oracle."""
     from repro.core import Data
@@ -462,7 +462,7 @@ def _kernel_stream_case(app, rng, proc_cls, mk_item, ref_fn, n=16, **stream_kw):
     p.in_handle = app.addData(Data(zero))
     p.out_handle = app.addData(Data({"y": np.zeros_like(want0)}))
     p.init()
-    got = p.stream(datasets, batch=8, sharded=True, sync=True, **stream_kw)
+    got = p.stream(datasets, batch=8, sharded=True, sync=True)
     assert len(got) == len(datasets)
     for i, (d, o) in enumerate(zip(datasets, got)):
         arrs = {nd.name: jnp.asarray(nd.host) for nd in d}
@@ -580,18 +580,6 @@ def test_sharded_pallas_stream_parity(rng, case):
     app = CLapp().init()
     idx, mk, rf = _KERNEL_CASES[case]
     _kernel_stream_case(app, rng, _make_kernel_procs()[idx], mk, rf)
-
-
-@needs_8_devices
-@pytest.mark.parametrize("case", ["coil_combine", "rmsnorm"])
-@pytest.mark.parametrize("kw", [{"split": "proportional"}, {"lanes": True}])
-def test_sharded_pallas_proportional_and_lanes(rng, case, kw):
-    """Pallas kernels under the per-device carve paths: proportional split
-    and per-device upload lanes."""
-    from repro.core import CLapp
-    app = CLapp().init()
-    idx, mk, rf = _KERNEL_CASES[case]
-    _kernel_stream_case(app, rng, _make_kernel_procs()[idx], mk, rf, **kw)
 
 
 @needs_8_devices

@@ -385,266 +385,6 @@ def test_sharded_joined_stream_bit_identical_and_spread(rng):
 
 
 @needs_8_devices
-def test_proportional_stream_bit_identical_and_spread(rng):
-    """split='proportional' over 8 devices: bit-identical to the equal
-    split, every device used, and the warmup run leaves a warm registry."""
-    app = CLapp().init()
-    datasets = _mk_datasets(rng, 32)
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.in_handle = h_in; p.out_handle = h_out
-    p.set_launch_parameters(-1.5)
-    p.init()
-    eq = p.stream(datasets, batch=16, sharded=True, sync=True)
-    assert not app.device_profiles.warm(app.devices)   # equal path: no rates
-    pr = p.stream(datasets, batch=16, sharded=True, split="proportional",
-                  sync=True)
-    out_devices = set()
-    for i, (a, b) in enumerate(zip(eq, pr)):
-        np.testing.assert_array_equal(a.get_ndarray(0).host,
-                                      b.get_ndarray(0).host,
-                                      err_msg=f"dataset {i}")
-        out_devices |= set(b.device_blob.devices())
-    assert out_devices == set(app.devices), \
-        "cold-profile fallback must still spread work over every device"
-    assert app.device_profiles.warm(app.devices), \
-        "every device's launches must have recorded items/sec"
-
-
-@needs_8_devices
-def test_proportional_skewed_allocation(rng):
-    """A seeded skewed registry steers rows: the slow device receives
-    (many) fewer items than the balanced share, a zero-rate device none —
-    outputs still bit-identical to the equal split."""
-    app = CLapp().init()
-    slow, fast = app.devices[0], app.devices[1:]
-    app.device_profiles.set_rate(slow, 1.0)
-    for d in fast:
-        app.device_profiles.set_rate(d, 7.0)
-    vec = app.device_profiles.split(50, app.devices)
-    assert vec == (1, 7, 7, 7, 7, 7, 7, 7)
-
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.in_handle = h_in; p.out_handle = h_out
-    p.set_launch_parameters(2.5)
-    p.init()
-    datasets = _mk_datasets(rng, 16)
-    eq = p.stream(datasets, batch=16, sharded=True, sync=True)
-
-    # zero-rate device: gets nothing at all
-    app.device_profiles.set_rate(slow, 0.0)
-    pr = p.stream(datasets, batch=16, sharded=True, split="proportional",
-                  sync=True)
-    used = set()
-    for a, b in zip(eq, pr):
-        np.testing.assert_array_equal(a.get_ndarray(0).host,
-                                      b.get_ndarray(0).host)
-        used |= set(b.device_blob.devices())
-    assert slow not in used, "a zero-rate device must receive zero rows"
-    assert used == set(fast)
-
-
-@needs_8_devices
-def test_proportional_joined_stream_shares_split_vector(rng):
-    """A fan-in join under split='proportional': every edge is carved by
-    ONE shared split vector, so row alignment holds and results match the
-    equal split bit for bit — in stream AND serve mode, skewed registry
-    included."""
-    app = CLapp().init()
-    app.device_profiles.set_rate(app.devices[0], 1.0)
-    for d in app.devices[1:]:
-        app.device_profiles.set_rate(d, 3.0)
-    a = Scale(app).bind(infile="x", outfile="lhs", params=2.0)
-    j = MulTwo(app).bind(infile="lhs", outfile="prod", rhs="r")
-    pipe = Pipeline.from_graph(app, [a, j], output="prod")
-    lhs = _mk_datasets(rng, 16)
-    rhs = _mk_datasets(rng, 16)
-    items = [{"x": l, "r": r} for l, r in zip(lhs, rhs)]
-    want = [pipe.run(it).get_ndarray(0).host.copy() for it in items]
-
-    got = pipe.run(items, mode="stream", batch=8, sharded=True,
-                   split="proportional")
-    for i, o in enumerate(got):
-        np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
-                                      err_msg=f"item {i}")
-    served = pipe.run(items, mode="serve", batch=8, sharded=True,
-                      split="proportional")
-    for i, o in enumerate(served):
-        np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
-                                      err_msg=f"served item {i}")
-
-
-@needs_8_devices
-def test_zero_rate_device_excluded_from_balanced_fallback(rng):
-    """An explicitly zero-rated device gets no rows even when the split
-    falls back to balanced (small batch / cold peers) — the 'broken
-    accelerator stays in the pool' case must survive the fallback."""
-    app = CLapp().init()
-    broken = app.devices[0]
-    app.device_profiles.set_rate(broken, 0.0)   # peers stay cold
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.in_handle = h_in; p.out_handle = h_out
-    p.set_launch_parameters(3.0)
-    p.init()
-    datasets = _mk_datasets(rng, 8)
-    # batch=8 over 8 devices -> rows < 2*n -> registry.split returns None
-    got = p.stream(datasets, batch=8, sharded=True, split="proportional",
-                   sync=True)
-    used = set()
-    for d, o in zip(datasets, got):
-        np.testing.assert_array_equal(o.get_ndarray(0).host,
-                                      d.get_ndarray(0).host * 3.0)
-        used |= set(o.device_blob.devices())
-    assert broken not in used
-    assert used == set(app.devices[1:])
-
-
-@needs_8_devices
-def test_proportional_uneven_batch_allowed(rng):
-    """Proportional carving lifts the equal split's batch-divisibility
-    constraint: batch=6 over 8 devices streams fine (and stays
-    bit-identical to an unsharded run)."""
-    app = CLapp().init()
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.in_handle = h_in; p.out_handle = h_out
-    p.set_launch_parameters(0.5)
-    p.init()
-    datasets = _mk_datasets(rng, 12)
-    with pytest.raises(ValueError, match="divisible"):
-        p.stream(datasets, batch=6, sharded=True)
-    want = p.stream(datasets, batch=6, sync=True)
-    got = p.stream(datasets, batch=6, sharded=True, split="proportional",
-                   sync=True)
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w.get_ndarray(0).host,
-                                      g.get_ndarray(0).host)
-
-
-# ---------------------------------------------------------------------------
-# per-device upload lanes (ISSUE 6: residency + lanes)
-# ---------------------------------------------------------------------------
-
-def test_lanes_require_sharded(rng):
-    app = CLapp().init()
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    p = Scale(app)
-    p.in_handle, p.out_handle = app.addData(d_in), app.addData(d_out)
-    p.set_launch_parameters(1.0)
-    with pytest.raises(ValueError, match="sharded"):
-        p.stream(_mk_datasets(rng, 4), batch=2, lanes=True)
-
-
-@needs_8_devices
-def test_lanes_stream_bit_identical_and_spread(rng):
-    """lanes=True: every mesh device gets its own pinned upload lane; the
-    carved sub-batches land bit-identical to sequential launches and the
-    per-item outputs cover all 8 devices."""
-    app = CLapp().init()
-    datasets = _mk_datasets(rng, 16)
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.set_in_handle(h_in); p.set_out_handle(h_out)
-    p.set_launch_parameters(-1.5)
-    p.init()
-    want = _sequential(app, p, h_in, h_out, d_in, d_out, datasets)
-
-    got = p.stream(datasets, batch=8, sharded=True, lanes=True, sync=True)
-    assert len(got) == len(datasets)
-    out_devices = set()
-    for i, o in enumerate(got):
-        np.testing.assert_array_equal(
-            o.get_ndarray(0).host, want[i], err_msg=f"dataset {i}")
-        out_devices |= set(o.device_blob.devices())
-    assert out_devices == set(app.devices), \
-        "lane streaming must use every mesh device"
-
-
-@needs_8_devices
-def test_lanes_lift_batch_divisibility(rng):
-    """The plain equal sharded split rejects batch % n_devices != 0; lanes
-    carve a balanced (possibly uneven) vector instead, so the same call
-    works with lanes=True — and stays bit-identical to unsharded."""
-    app = CLapp().init()
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    h_in, h_out = app.addData(d_in), app.addData(d_out)
-    p = Scale(app)
-    p.in_handle = h_in; p.out_handle = h_out
-    p.set_launch_parameters(2.5)
-    p.init()
-    datasets = _mk_datasets(rng, 6)
-    with pytest.raises(ValueError, match="divisible"):
-        p.stream(datasets, batch=3, sharded=True)
-    want = p.stream(datasets, batch=3, sync=True)
-    got = p.stream(datasets, batch=3, sharded=True, lanes=True, sync=True)
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w.get_ndarray(0).host,
-                                      g.get_ndarray(0).host)
-
-
-@needs_8_devices
-def test_lanes_transfer_phase_one_record_per_lane(rng):
-    """Phase accounting: with lanes every (batch, device) pair is one
-    pinned host2device transfer — 16 items at batch=8 over 8 lanes makes
-    2 * 8 transfer records, plus one compute record per device launch."""
-    from repro.core import ProfileParameters
-    app = CLapp().init()
-    datasets = _mk_datasets(rng, 16)
-    d_in = XData({"img": np.zeros((8, 8), np.float32)})
-    d_out = XData(d_in, copy_values=False)
-    p = Scale(app)
-    p.in_handle, p.out_handle = app.addData(d_in), app.addData(d_out)
-    p.set_launch_parameters(3.0)
-    p.init()
-    prof = ProfileParameters(enable=True)
-    p.stream(datasets, batch=8, sharded=True, lanes=True, sync=True,
-             profile=prof)
-    n_batches, n_lanes = 2, 8
-    assert len(prof.phases.get("transfer", ())) == n_batches * n_lanes
-    assert len(prof.phases.get("compute", ())) == n_batches * n_lanes
-    assert prof.phase_total("transfer") > 0
-
-
-@needs_8_devices
-def test_lanes_joined_stream_row_aligned(rng):
-    """Fan-in join under lanes: both edges are carved by the SAME balanced
-    vector and fed through per-device lanes, so row alignment holds and
-    stream AND serve match per-item launches bit for bit."""
-    app = CLapp().init()
-    a = Scale(app).bind(infile="x", outfile="lhs", params=2.0)
-    j = MulTwo(app).bind(infile="lhs", outfile="prod", rhs="r")
-    pipe = Pipeline.from_graph(app, [a, j], output="prod")
-    lhs = _mk_datasets(rng, 12)
-    rhs = _mk_datasets(rng, 12)
-    items = [{"x": l, "r": r} for l, r in zip(lhs, rhs)]
-    want = [pipe.run(it).get_ndarray(0).host.copy() for it in items]
-
-    got = pipe.run(items, mode="stream", batch=8, sharded=True, lanes=True)
-    assert len(got) == 12
-    for i, o in enumerate(got):
-        np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
-                                      err_msg=f"item {i}")
-    served = pipe.run(items, mode="serve", batch=8, sharded=True, lanes=True)
-    for i, o in enumerate(served):
-        np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
-                                      err_msg=f"served item {i}")
-
-
-@needs_8_devices
 def test_single_device_traits_on_multi_device_host(rng):
     """DeviceTraits(count=1) on an 8-device host: the mesh is trivial and
     sharded=True degrades to the single-device path — the algorithm call
@@ -714,7 +454,7 @@ def test_model_axis_mesh_construction():
 def test_recon_2d_bit_identical_three_modes(rng):
     """The shard_map'd fused MRI recon on a (data=2, model=4) mesh is
     BIT-identical to the same program on a trivial mesh — in launch,
-    sharded stream (equal + proportional splits, lanes) and serve.  The
+    sharded stream and serve.  The
     frames axis (F=8) splits 2-per-device over each model group; shard_map
     partitioning must not change a single ulp vs the unpartitioned jit."""
     from repro.core import KData
@@ -738,20 +478,12 @@ def test_recon_2d_bit_identical_three_modes(rng):
 
     got_launch = [fused.run(d).get_ndarray(0).host.copy() for d in inputs]
     got_stream = fused.run(inputs, mode="stream", batch=2, sharded=True)
-    got_prop = fused.run(inputs, mode="stream", batch=2, sharded=True,
-                         split="proportional")
-    got_lanes = fused.run(inputs, mode="stream", batch=4, sharded=True,
-                          lanes=True)
     got_serve = fused.run(inputs, mode="serve", batch=2, sharded=True)
     for i in range(len(inputs)):
         np.testing.assert_array_equal(got_launch[i], want[i],
                                       err_msg=f"launch[{i}]")
         np.testing.assert_array_equal(got_stream[i].get_ndarray(0).host,
                                       want[i], err_msg=f"stream[{i}]")
-        np.testing.assert_array_equal(got_prop[i].get_ndarray(0).host,
-                                      want[i], err_msg=f"proportional[{i}]")
-        np.testing.assert_array_equal(got_lanes[i].get_ndarray(0).host,
-                                      want[i], err_msg=f"lanes[{i}]")
         np.testing.assert_array_equal(got_serve[i].get_ndarray(0).host,
                                       want[i], err_msg=f"serve[{i}]")
 

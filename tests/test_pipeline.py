@@ -11,6 +11,7 @@ import jax
 from repro.core import (CLapp, Data, GraphError, KData, Node, Pipeline, Port,
                         PortError, Process, ProfileParameters, XData,
                         compile_cache_stats)
+from repro.core.stream import TAIL_WASTE, launch_rows
 from repro.processes import (FFT, ComplexElementProd, SimpleMRIRecon,
                              XImageSum)
 from repro.processes.coil_combine import CombineParams
@@ -364,7 +365,8 @@ def _wired_scale(app, shape):
     return p
 
 
-def test_ragged_tail_compiles_second_executable(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_ragged_tail_compiles_second_executable(app, rng, sharded):
     """9 items at batch=8: waste 7/8 > 0.5 -> the tail runs through a
     second executable compiled for 1 row (one extra cache miss), and the
     results still match the per-item math."""
@@ -373,7 +375,7 @@ def test_ragged_tail_compiles_second_executable(app, rng):
     datasets = [XData({"img": rng.standard_normal(shape).astype(np.float32)})
                 for _ in range(9)]
     h0, m0 = compile_cache_stats()
-    outs = p.stream(datasets, batch=8, sync=True)
+    outs = p.stream(datasets, batch=8, sync=True, sharded=sharded)
     h1, m1 = compile_cache_stats()
     assert m1 - m0 == 2, "main batched program + tail program"
     assert len(outs) == 9
@@ -382,12 +384,13 @@ def test_ragged_tail_compiles_second_executable(app, rng):
                                    d.get_ndarray(0).host * 3.0, rtol=1e-6)
     # same tail size again: both executables come from the cache
     h2, m2 = compile_cache_stats()
-    p.stream(datasets, batch=8, sync=True)
+    p.stream(datasets, batch=8, sync=True, sharded=sharded)
     h3, m3 = compile_cache_stats()
     assert m3 == m2, "repeat stream compiles nothing new"
 
 
-def test_small_waste_still_pads(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_small_waste_still_pads(app, rng, sharded):
     """10 items at batch=4: waste 2/4 <= 0.5 -> the tail is padded by
     repetition (no second executable, exactly one compile)."""
     shape = (5, 13)
@@ -395,7 +398,7 @@ def test_small_waste_still_pads(app, rng):
     datasets = [XData({"img": rng.standard_normal(shape).astype(np.float32)})
                 for _ in range(10)]
     h0, m0 = compile_cache_stats()
-    outs = p.stream(datasets, batch=4, sync=True)
+    outs = p.stream(datasets, batch=4, sync=True, sharded=sharded)
     h1, m1 = compile_cache_stats()
     assert m1 - m0 == 1, "padding path must not compile a tail program"
     for d, o in zip(datasets, outs):
@@ -403,15 +406,43 @@ def test_small_waste_still_pads(app, rng):
                                    d.get_ndarray(0).host * 3.0, rtol=1e-6)
 
 
-def test_tail_threshold_one_disables_tail_compile(app, rng):
-    shape = (2, 29)
-    p = _wired_scale(app, shape)
-    datasets = [XData({"img": rng.standard_normal(shape).astype(np.float32)})
-                for _ in range(9)]
-    h0, m0 = compile_cache_stats()
-    p.stream(datasets, batch=8, sync=True, tail_waste_threshold=1.0)
-    h1, m1 = compile_cache_stats()
-    assert m1 - m0 == 1, "threshold >= 1.0 always pads (pre-tail behaviour)"
+@pytest.mark.parametrize("rows,batch,data_axis,want", [
+    (8, 8, 1, 8),       # a full batch
+    (9, 8, 1, 8),       # more rows than the batch: a full one
+    (0, 8, 1, 8),       # no rows: nothing to shrink to
+    (4, 8, 1, 8),       # waste 0.5, at the limit: pad
+    (6, 8, 1, 8),       # waste 0.25: pad
+    (3, 8, 1, 3),       # waste 0.625 unsharded: a tail executable
+    (2, 8, 2, 2),       # waste 0.75, a multiple of the data axis: a tail
+    (3, 8, 2, 8),       # waste 0.625, not a multiple of it: pad
+])
+def test_launch_rows_decision_table(rows, batch, data_axis, want):
+    """The ragged-tail rule: pad while the waste is at most TAIL_WASTE,
+    or when a sharded tail is not a multiple of the data axis."""
+    assert TAIL_WASTE == 0.5
+    assert launch_rows(rows, batch, data_axis) == want
+
+
+@pytest.mark.parametrize("entry", ["Process.stream", "Pipeline.run",
+                                   "Pipeline.serve", "PipelineServer"])
+def test_removed_stream_options_raise_type_error(app, rng, entry):
+    """The batch carve and queue depth are fixed: each former option is
+    refused by name, not silently ignored."""
+    from repro.serve.pipeline import PipelineServer
+    datasets = [XData({"img": rng.standard_normal((2, 3)).astype(np.float32)})
+                for _ in range(2)]
+    pipe = Pipeline(app) | Scale(app).bind(params=2.0)
+    call = {
+        "Process.stream": lambda **kw: _wired_scale(app, (2, 3)).stream(
+            datasets, batch=2, **kw),
+        "Pipeline.run": lambda **kw: pipe.run(datasets, mode="stream",
+                                              batch=2, **kw),
+        "Pipeline.serve": lambda **kw: pipe.serve(batch=2, **kw),
+        "PipelineServer": lambda **kw: PipelineServer(pipe, batch=2, **kw),
+    }[entry]
+    for name, value in (("depth", 2), ("split", "equal"), ("lanes", False)):
+        with pytest.raises(TypeError, match=name):
+            call(**{name: value})
 
 
 # ---------------------------------------------------------------------------

@@ -120,24 +120,28 @@ def test_launch_bit_identical_to_host_roundtrip(app, rng):
         np.testing.assert_array_equal(got, want[i], err_msg=f"launch[{i}]")
 
 
-def test_stream_bit_identical_with_ragged_tail(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_stream_bit_identical_with_ragged_tail(app, rng, sharded):
     """7 items at batch=3: a ragged tail rides through the residency
     plan's fused streaming path and still matches the host round trip."""
     datasets = [_img(rng) for _ in range(7)]
     want = _roundtrip_reference(datasets)
     pipe = _chain(app)
-    outs = pipe.run(datasets, mode="stream", batch=3, sync=True)
+    outs = pipe.run(datasets, mode="stream", batch=3, sync=True,
+                    sharded=sharded)
     assert len(outs) == 7
     for i, o in enumerate(outs):
         np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
                                       err_msg=f"stream[{i}]")
 
 
-def test_serve_bit_identical_with_ragged_tail(app, rng):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_serve_bit_identical_with_ragged_tail(app, rng, sharded):
     datasets = [_img(rng) for _ in range(5)]
     want = _roundtrip_reference(datasets)
     pipe = _chain(app)
-    outs = pipe.run(datasets, mode="serve", batch=2, sync=True)
+    outs = pipe.run(datasets, mode="serve", batch=2, sync=True,
+                    sharded=sharded)
     for i, o in enumerate(outs):
         np.testing.assert_array_equal(o.get_ndarray(0).host, want[i],
                                       err_msg=f"serve[{i}]")

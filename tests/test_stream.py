@@ -8,8 +8,8 @@ import pytest
 
 from repro.core import (BatchedProcess, CLapp, Coherence, Data,
                         DonatedBufferError, Process, ProcessChain,
-                        StreamQueue, XData, compile_cache_stats, trace,
-                        unpack_device)
+                        ProfileParameters, StreamQueue, XData,
+                        compile_cache_stats, trace, unpack_device)
 from repro.data.pipeline import ArenaFeed, StreamConfig, TokenStream
 
 
@@ -59,9 +59,10 @@ def _sequential(app, chain, h_in, h_out, d_in, d_out, datasets):
     return out
 
 
+@pytest.mark.parametrize("sharded", [False, True])
 @pytest.mark.parametrize("mode", ["staged", "fused"])
 @pytest.mark.parametrize("batch,n", [(1, 3), (4, 8), (4, 10)])  # incl. ragged
-def test_stream_matches_sequential_launch(app, rng, mode, batch, n):
+def test_stream_matches_sequential_launch(app, rng, mode, batch, n, sharded):
     datasets = _mk_datasets(rng, n)
     d_in = XData({"img": np.zeros((8, 8), np.float32)})
     d_mid = XData(d_in, copy_values=False)
@@ -70,7 +71,7 @@ def test_stream_matches_sequential_launch(app, rng, mode, batch, n):
     chain = _chain(app, h_in, h_mid, h_out, mode=mode)
     chain.init()
     want = _sequential(app, chain, h_in, h_out, d_in, d_out, datasets)
-    got = chain.stream(datasets, batch=batch, sync=True)
+    got = chain.stream(datasets, batch=batch, sync=True, sharded=sharded)
     assert len(got) == n
     for i in range(n):
         np.testing.assert_array_equal(got[i].get_ndarray(0).host, want[i],
@@ -94,6 +95,21 @@ def test_stream_with_aux_broadcast(app, rng):
     for d, o in zip(datasets, got):
         np.testing.assert_array_equal(
             o.get_ndarray(0).host, d.get_ndarray(0).host + bias)
+
+
+def test_stream_profile_records_a_compute_phase_per_launch(app, rng):
+    """With a profile, each batched launch has left one "compute" sample
+    by the time stream() returns."""
+    d_in = XData({"img": np.zeros((8, 8), np.float32)})
+    d_out = XData(d_in, copy_values=False)
+    p = Scale(app)
+    p.set_in_handle(app.addData(d_in)); p.set_out_handle(app.addData(d_out))
+    p.set_launch_parameters(2.0)
+    prof = ProfileParameters(enable=True)
+    p.stream(_mk_datasets(rng, 7), batch=2, profile=prof)
+    assert len(prof.phases["compute"]) == 4      # 3 full batches + a tail
+    assert all(s > 0 for s in prof.phases["compute"])
+    assert len(prof.samples) == 1                # the whole call
 
 
 def test_stream_batch_axis_compile_cache_hits(app, rng):
