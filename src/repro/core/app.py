@@ -386,7 +386,8 @@ class CLapp:
         data = self._data.pop(handle, None)
         self._in_flight.pop(handle, None)
         if data is not None:
-            data.device_blob = None  # drop device reference
+            data.device_blob = None  # drop device references
+            data.device_entries = None
 
     def host2device(self, handle: DataHandle, *, wait: bool = True,
                     sharding: Optional[jax.sharding.Sharding] = None) -> None:
@@ -402,20 +403,34 @@ class CLapp:
         ``sharding`` overrides the placement (e.g. ``app.data_sharding()``
         to replicate an aux blob over every selected device for sharded
         streaming); the default is :attr:`default_sharding` — the primary
-        device, matching pre-mesh behaviour exactly."""
+        device, matching pre-mesh behaviour exactly.
+
+        A static Data (:attr:`~repro.core.data.Data.static`) is placed as
+        its own arrays, one ``device_put`` of its entries in layout order,
+        and holds no blob: the one copy on the device is the one programs
+        read."""
         data = self.getData(handle)
         if data.layout is None:
             data.plan()
-        if all(a.host is not None for a in data):
-            blob = data.pack_host()
-            coherence = Coherence.IN_SYNC
+        target = sharding if sharding is not None else self.default_sharding
+        hosted = all(a.host is not None for a in data)
+        coherence = Coherence.IN_SYNC if hosted else Coherence.DEVICE_FRESH
+        if data.static:
+            host = {a.name: a.host for a in data}
+            arrays = tuple(
+                np.zeros(e.shape, e.np_dtype) if host[e.name] is None
+                else host[e.name] for e in data.layout.entries)
+            data.device_entries = tuple(jax.device_put(arrays, target))
+            nbytes = sum(a.nbytes for a in arrays)
         else:
-            spec = blob_spec(data.layout)
-            blob = np.zeros(spec.shape, spec.dtype)
-            coherence = Coherence.DEVICE_FRESH
-        data.device_blob = jax.device_put(
-            blob, sharding if sharding is not None else self.default_sharding)
-        trace.H2D_BYTES.inc(blob.nbytes)
+            if hosted:
+                blob = data.pack_host()
+            else:
+                spec = blob_spec(data.layout)
+                blob = np.zeros(spec.shape, spec.dtype)
+            data.device_blob = jax.device_put(blob, target)
+            nbytes = blob.nbytes
+        trace.H2D_BYTES.inc(nbytes)
         data.donated_by = None  # explicit re-upload resurrects a donated Data
         if wait:
             self._in_flight.pop(handle, None)
@@ -432,8 +447,8 @@ class CLapp:
             [h for h in handles if h in self._in_flight]
         for h in todo:
             data = self.getData(h)
-            if data.device_blob is not None:
-                jax.block_until_ready(data.device_blob)
+            if data.on_device:
+                jax.block_until_ready(data.device_args())
             data.coherence = self._in_flight.pop(h)
 
     @property
@@ -451,6 +466,10 @@ class CLapp:
     # internal: processes replace a Data's device blob after computing
     def _set_device_blob(self, handle: DataHandle, blob: jax.Array) -> None:
         data = self.getData(handle)
+        if data.static:
+            raise ValueError(
+                f"data handle {handle} is static (read-only): it cannot "
+                "be a program's output")
         data.device_blob = blob
         data.donated_by = None  # fresh result resurrects a donated edge
         # internal pipeline edges are planned to live on the device only;

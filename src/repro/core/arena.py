@@ -41,6 +41,7 @@ produce the same bytes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Any, Dict, Iterable, List, Mapping, Sequence, Tuple
 
@@ -96,6 +97,11 @@ class ArenaLayout:
     @property
     def names(self) -> List[str]:
         return [e.name for e in self.entries]
+
+    @functools.cached_property
+    def payload_bytes(self) -> int:
+        """Bytes of the entries themselves, without alignment padding."""
+        return sum(e.nbytes for e in self.entries)
 
     def entry(self, name: str) -> ArenaEntry:
         for e in self.entries:
@@ -386,6 +392,13 @@ def pack_device(arrays: Mapping[str, jax.Array], layout: ArenaLayout) -> jax.Arr
 def blob_spec(layout: ArenaLayout) -> jax.ShapeDtypeStruct:
     """AOT spec of one arena blob: ``(total_words,)`` words."""
     return jax.ShapeDtypeStruct((layout.total_words,), WORD)
+
+
+def entry_specs(layout: ArenaLayout) -> Tuple[jax.ShapeDtypeStruct, ...]:
+    """AOT specs of an arena's entries held as their own arrays (a static
+    Data): one per entry, in layout order."""
+    return tuple(jax.ShapeDtypeStruct(e.shape, e.np_dtype)
+                 for e in layout.entries)
 
 
 def batched_spec(layout: ArenaLayout, batch: int) -> jax.ShapeDtypeStruct:

@@ -121,6 +121,14 @@ class Data:
         # it, so every step's result is stamped Coherence.DEVICE_RESIDENT
         # and run(sync=False) never round-trips it through the host.
         self.persistent: bool = False
+        # static contract (model weights): read-only for the Data's life,
+        # bound only to ``Port(aux=True)`` ports and never a program's
+        # output.  Such a Data is held on the device as its own arrays,
+        # one per arena entry in the entry's dtype and shape
+        # (``device_entries``), and programs take those arrays as they are:
+        # the arena carries it only as a layout, never as a blob to decode.
+        self.static: bool = False
+        self.device_entries: Optional[Tuple[jax.Array, ...]] = None
         # set by Process.launch when a downstream stage donated this blob
         # to XLA; reads must fail loudly (with graph context when known)
         self.donated_by: Optional[str] = None
@@ -236,6 +244,11 @@ class Data:
             f"exists; re-upload with host2device before reusing this Data")
 
     # -- device views ----------------------------------------------------------
+    @property
+    def on_device(self) -> bool:
+        """Whether the Data holds a device copy (blob or static entries)."""
+        return self.device_blob is not None or self.device_entries is not None
+
     def _device_blob(self) -> jax.Array:
         if self.device_blob is None or self.layout is None:
             if self.donated_by is not None:
@@ -243,16 +256,29 @@ class Data:
             raise ValueError("Data not registered on a device (use CLapp.addData)")
         return self.device_blob
 
+    def device_args(self) -> Any:
+        """What a program takes for this Data: a static Data's entry arrays
+        (a tuple in layout order), any other Data's arena blob."""
+        if self.static:
+            if self.device_entries is None:
+                raise ValueError(
+                    "Data not registered on a device (use CLapp.addData)")
+            return self.device_entries
+        return self._device_blob()
+
     def device_views(self) -> Dict[str, jax.Array]:
+        if self.static:
+            return dict(zip(self.layout.names, self.device_args()))
         return unpack_device(self._device_blob(), self.layout)
 
     def device_view(self, name_or_idx) -> jax.Array:
         """One entry, unpacked alone: called eagerly, unpacking every entry
         would allocate and decode the whole blob to read one of them."""
-        blob = self._device_blob()
         name = (self._arrays[name_or_idx].name
                 if isinstance(name_or_idx, int) else name_or_idx)
-        return entry_view(blob, self.layout.entry(name))
+        if self.static:
+            return self.device_views()[name]
+        return entry_view(self._device_blob(), self.layout.entry(name))
 
     # -- host sync --------------------------------------------------------------
     def sync_to_host(self) -> None:

@@ -64,9 +64,11 @@ padding would be wasteful.  A multi-input process streams *tuples* (or
 row-aligned batch queue, zipped into one joined launch.
 
 The lowered form, :class:`PureLaunchable`, is genuinely multi-input:
-``fn(*in_blobs, *aux_blobs) -> blob_out`` with ordered ``in_names`` /
+``fn(*in_blobs, *aux_args) -> blob_out`` with ordered ``in_names`` /
 ``in_layouts`` / ``in_handles`` instead of a privileged primary input —
-the primary ``"in"`` port is simply position 0.  Secondary input views are
+the primary ``"in"`` port is simply position 0.  An aux arg is an arena
+blob the program unpacks, or, for a static Data (the LM weights), the
+tuple of its entry arrays, taken as they are.  Secondary input views are
 delivered to :meth:`Process.apply` through the same ``aux`` argument slot
 the static-broadcast path uses, which is what makes a streamed join
 bit-identical to the legacy aux binding by construction.
@@ -93,7 +95,8 @@ import numpy as np
 
 from . import trace
 from .app import CLapp, DataHandle, INVALID_HANDLE
-from .arena import ArenaLayout, blob_spec, pack_device, unpack_device
+from .arena import (ArenaLayout, blob_spec, entry_specs, pack_device,
+                    unpack_device)
 from .sync import Coherence
 
 
@@ -360,9 +363,18 @@ def aot_compile(fn: Callable, specs: Sequence[Any], *, tag: str,
     return compiled
 
 
-def _conform_blobs(compiled, blobs):
-    """device_put any blob whose placement doesn't match what ``compiled``
-    expects.
+def _input_shardings(compiled) -> Optional[List[Any]]:
+    """The shardings ``compiled`` expects of its arguments, one per array
+    leaf (None where the executable does not say)."""
+    try:
+        return jax.tree_util.tree_leaves(compiled.input_shardings[0])
+    except Exception:
+        return None
+
+
+def _conform_blobs(expected, blobs):
+    """device_put any blob (or static aux entry) whose placement doesn't
+    match ``expected`` (:func:`_input_shardings` of the program).
 
     A program whose apply body is ``shard_map``-partitioned over the mesh's
     ``model`` axis (see :func:`repro.launch.mesh.shard_by_logical`) lowers
@@ -372,16 +384,14 @@ def _conform_blobs(compiled, blobs):
     1D fast path untouched and moves data at most once per blob: the
     conformed output blob already matches on the next stage's launch.
     Returns ``(blobs, moved_any)``."""
-    try:
-        expected = compiled.input_shardings[0]
-    except Exception:
-        return blobs, False
-    if len(expected) != len(blobs):
+    leaves, tree = jax.tree_util.tree_flatten(list(blobs))
+    if expected is None or len(expected) != len(leaves):
         return blobs, False
     out, moved = [], False
-    for b, s in zip(blobs, expected):
+    for b, s in zip(leaves, expected):
         try:
-            ok = b.sharding.is_equivalent_to(s, b.ndim)
+            have = b.sharding
+            ok = have == s or have.is_equivalent_to(s, b.ndim)
         except Exception:
             ok = True
         if ok:
@@ -389,21 +399,32 @@ def _conform_blobs(compiled, blobs):
         else:
             out.append(jax.device_put(b, s))
             moved = True
-    return out, moved
+    return jax.tree_util.tree_unflatten(tree, out), moved
+
+
+def count_aux(aux_datas: Sequence[Any]) -> None:
+    """Count the aux one launch is handed, by form: a static Data's entries
+    as native bytes, any other Data's arena blob as packed bytes."""
+    for d in aux_datas:
+        if d.static:
+            trace.AUX_NATIVE_BYTES.inc(d.layout.payload_bytes)
+        else:
+            trace.AUX_PACKED_BYTES.inc(d.layout.total_bytes)
 
 
 def _layout_fingerprint(app, la: "PureLaunchable") -> Any:
     """Hashable fingerprint of every arena layout a compiled program bakes
-    in (inputs, output, aux).  Folded into the compile-cache static key:
-    the blob *specs* only carry total byte sizes, and two different
-    layouts can round up to the same arena size — without this they would
-    collide on one executable that unpacks the wrong shapes."""
+    in (inputs, output, aux), each aux with its form (static entries or a
+    packed blob).  Folded into the compile-cache static key: the blob
+    *specs* only carry total byte sizes, and two different layouts can
+    round up to the same arena size — without this they would collide on
+    one executable that unpacks the wrong shapes."""
     aux_layouts = []
     for h in la.aux_handles:
         d = app.getData(h)
         if d.layout is None:
             d.plan()
-        aux_layouts.append(d.layout)
+        aux_layouts.append((d.layout, d.static))
     return (la.in_layouts, la.out_layout, tuple(aux_layouts))
 
 
@@ -418,7 +439,8 @@ class DonatedBufferError(RuntimeError):
 class PureLaunchable:
     """A Process lowered to its pure, launchable form.
 
-    ``fn(*in_blobs, *aux_blobs) -> blob_out`` plus everything needed to
+    ``fn(*in_blobs, *aux_args) -> blob_out`` (an aux arg is a blob, or a
+    static Data's tuple of entry arrays) plus everything needed to
     compile and feed it: the ordered streaming inputs (names, arena
     layouts, Data handles — position 0 is the primary ``"in"`` port), the
     aux Data handles in positional order, the compile-cache tag/static
@@ -491,6 +513,7 @@ class Process:
         self._compiled_in_names: Tuple[str, ...] = ()
         self._compiled_donate_name: Optional[str] = None
         self._compiled_donate_reason: Optional[str] = None  # 'in_place'|'port'
+        self._compiled_shardings: Optional[List[Any]] = None
         self._initialized = False
         self._legacy_warned = False
 
@@ -613,18 +636,28 @@ class Process:
     def _layouts(self) -> Tuple[Tuple[ArenaLayout, ...], ArenaLayout,
                                 Dict[str, ArenaLayout]]:
         app = self.getApp()
+        owner = type(self).__name__
         in_layouts = []
         for name in self.input_names:
             d = app.getData(self.in_handles.get(name, INVALID_HANDLE))
+            if d.static:
+                raise PortError(f"{owner}: input {name!r} is a static Data; "
+                                "static Data binds only to aux ports")
             if d.layout is None:
                 d.plan()
             in_layouts.append(d.layout)
         dout = app.getData(self.out_handle)
+        if dout.static:
+            raise PortError(f"{owner}: the output is a static Data, which "
+                            "is read-only")
         if dout.layout is None:
             dout.plan()
         aux_layouts = {}
         for name, h in self.aux_handles.items():
             d = app.getData(h)
+            if d.static and not self.ports.get(name, Port()).aux:
+                raise PortError(f"{owner}: port {name!r} is not an aux "
+                                "port; static Data binds only to aux ports")
             if d.layout is None:
                 d.plan()
             aux_layouts[name] = d.layout
@@ -640,8 +673,12 @@ class Process:
 
     def pure_fn(self) -> Tuple[Callable, Tuple[ArenaLayout, ...], ArenaLayout,
                                List[str]]:
-        """(fn(*in_blobs, *aux_blobs) -> blob_out, in_layouts, out_layout,
+        """(fn(*in_blobs, *aux_args) -> blob_out, in_layouts, out_layout,
         aux names) — the fusable unit used by both init() and ProcessChain.
+
+        An aux arg is its Data's arena blob, unpacked in the program, or,
+        for a static Data, the tuple of its entry arrays, taken as they
+        are.
 
         The primary input's views become :meth:`apply`'s ``views`` argument;
         every SECONDARY streaming input is delivered through the ``aux``
@@ -653,9 +690,12 @@ class Process:
         aux_names = sorted(aux_layouts)
         params = self.launch_params
         n_in = len(in_names)
+        app = self.getApp()
+        static = {n for n in aux_names
+                  if app.getData(self.aux_handles[n]).static}
 
         def fn(*blobs):
-            in_blobs, aux_blobs = blobs[:n_in], blobs[n_in:]
+            in_blobs, aux_args = blobs[:n_in], blobs[n_in:]
             views = unpack_device(in_blobs[0], in_layouts[0])
             aux = {
                 name: unpack_device(blob, lay)
@@ -663,8 +703,10 @@ class Process:
                                            in_layouts[1:])
             }
             aux.update({
-                name: unpack_device(blob, aux_layouts[name])
-                for name, blob in zip(aux_names, aux_blobs)
+                name: (dict(zip(aux_layouts[name].names, arg))
+                       if name in static
+                       else unpack_device(arg, aux_layouts[name]))
+                for name, arg in zip(aux_names, aux_args)
             })
             outs = self.apply(views, aux, params)
             missing = set(out_layout.names) - set(outs)
@@ -718,14 +760,17 @@ class Process:
         return tuple(self.aux_handles[n] for n in sorted(self.aux_handles))
 
     # -- init / launch ----------------------------------------------------------
-    def _aux_specs(self, la: PureLaunchable) -> List[jax.ShapeDtypeStruct]:
+    def _aux_specs(self, la: PureLaunchable) -> List[Any]:
+        """AOT specs of the aux args: a blob spec, or a static Data's
+        tuple of entry specs."""
         app = self.getApp()
         specs = []
         for h in la.aux_handles:
             d = app.getData(h)
             if d.layout is None:
                 d.plan()
-            specs.append(blob_spec(d.layout))
+            specs.append(entry_specs(d.layout) if d.static
+                         else blob_spec(d.layout))
         return specs
 
     def init(self) -> None:
@@ -746,6 +791,7 @@ class Process:
             mesh=app.mesh,
         )
         self._compiled_in_names = la.in_names
+        self._compiled_shardings = _input_shardings(self._compiled)
         self._compiled_donate_name = (
             la.in_names[la.donate_idx] if la.donate_idx is not None else None)
         self._compiled_donate_reason = (
@@ -804,15 +850,18 @@ class Process:
                     uploaded = True
                 in_blobs.append(d.device_blob)
                 in_datas.append(d)
-            aux_blobs = []
+            aux_args = []
+            aux_datas = []
             for h in self._current_aux_handles():
                 d = app.getData(h)
-                if d.device_blob is None:
+                if not d.on_device:
                     app.host2device(h)
                     uploaded = True
-                aux_blobs.append(d.device_blob)
-            blobs, moved = _conform_blobs(self._compiled,
-                                          in_blobs + aux_blobs)
+                aux_args.append(d.device_args())
+                aux_datas.append(d)
+            count_aux(aux_datas)
+            blobs, moved = _conform_blobs(self._compiled_shardings,
+                                          in_blobs + aux_args)
             if (uploaded or moved) and profile is not None \
                     and profile.enable:
                 profile.record_phase("transfer", time.perf_counter() - t_up)
@@ -937,7 +986,7 @@ class ProcessChain(Process):
 
         def fused(*blobs):
             # leading blobs are the chain inputs; the rest is the
-            # concatenation of each stage's aux blobs, in order
+            # concatenation of each stage's aux args, in order
             env: Dict[DataHandle, Any] = dict(zip(chain_inputs, blobs[:n_in]))
             all_aux = blobs[n_in:]
             i = 0
@@ -1010,6 +1059,7 @@ class ProcessChain(Process):
             mesh=self.getApp().mesh,
         )
         self._compiled_in_names = la.in_names
+        self._compiled_shardings = _input_shardings(self._compiled)
         self._compiled_donate_name = (
             la.in_names[la.donate_idx] if la.donate_idx is not None else None)
         # a fused chain only donates when its output handle IS a chain input

@@ -114,7 +114,7 @@ from . import trace
 from .arena import batched_spec, blob_spec, split_batched_blob
 from .data import Data
 from .process import (PureLaunchable, ProfileParameters, aot_compile,
-                      _layout_fingerprint)
+                      count_aux, _layout_fingerprint)
 from .sync import Coherence
 
 #: transfers a :class:`StreamQueue` of the executor dispatches ahead
@@ -496,6 +496,8 @@ class _BatchPlan:
         """One batched launch for one group, through the executable of
         its row count."""
         with trace.span("stream.launch"):
+            app = self.process.getApp()
+            count_aux([app.getData(h) for h in self.launchable.aux_handles])
             out = self.executable(int(dev_blobs[0].shape[0]))(
                 tuple(dev_blobs), aux_blobs)
             # a donated input can no longer be waited for: the staging
@@ -503,31 +505,33 @@ class _BatchPlan:
             self.staging.consumed(list(dev_blobs), [out])
         return out
 
-    def prepare_aux(self) -> List[jax.Array]:
-        """Device aux blobs in positional order, replicated over the mesh
-        when sharded.  Shared by stream_launch and the serving loop."""
+    def prepare_aux(self) -> List[Any]:
+        """Device aux args in positional order (a blob, or a static Data's
+        entry arrays), replicated over the mesh when sharded.  Shared by
+        stream_launch and the serving loop."""
         app = self.process.getApp()
         replicated = app.data_sharding() if self.sharded else None
-        aux_blobs: List[jax.Array] = []
+        aux_args: List[Any] = []
         for h in self.launchable.aux_handles:
             d = app.getData(h)
-            if d.device_blob is None:
+            if not d.on_device:
                 # dispatch-only upload: the aux transfer rides alongside the
                 # first input batch's transfer; the launch consuming the
                 # blob is the implicit sync point (CLapp tracks the handle
                 # in flight)
                 app.host2device(h, wait=False)
-            blob = d.device_blob
-            if replicated is not None and not blob.sharding.is_equivalent_to(
-                    replicated, blob.ndim):
+            arg = d.device_args()
+            if replicated is not None and not all(
+                    a.sharding.is_equivalent_to(replicated, a.ndim)
+                    for a in jax.tree_util.tree_leaves(arg)):
                 # the sharded program broadcasts aux across the whole mesh.
                 # The replicated copy is CALL-LOCAL: the Data keeps its
                 # stored blob at the default placement, so later unsharded
                 # launch()/stream() calls (compiled for single-device
                 # inputs) still match.
-                blob = jax.device_put(blob, replicated)
-            aux_blobs.append(blob)
-        return aux_blobs
+                arg = jax.device_put(arg, replicated)
+            aux_args.append(arg)
+        return aux_args
 
 
 def _time_compute(profile: ProfileParameters, t0: float,

@@ -286,10 +286,17 @@ STAGING_REUSES = METRICS.counter(
 STAGING_ALLOCS = METRICS.counter(
     "repro_staging_allocs_total",
     "host staging buffers of stacked batches allocated")
+AUX_NATIVE_BYTES = METRICS.counter(
+    "repro_aux_native_bytes_total",
+    "bytes of aux handed to a launch as their own arrays (static Data)")
+AUX_PACKED_BYTES = METRICS.counter(
+    "repro_aux_packed_bytes_total",
+    "bytes of aux handed to a launch as arena words the program decodes")
 _PROCESS_COUNTERS = (H2D_BYTES, D2H_BYTES, COMPILES, COMPILE_SECONDS,
                      GC_PAUSE_SECONDS, CACHE_HITS, CACHE_MISSES, SUBWORD_BYTES,
                      MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_ROWS,
-                     STAGING_REUSES, STAGING_ALLOCS)
+                     STAGING_REUSES, STAGING_ALLOCS, AUX_NATIVE_BYTES,
+                     AUX_PACKED_BYTES)
 for _c in _PROCESS_COUNTERS:
     _c.inc(0.0)         # a series from the start: rendered at 0, and read
                         # unlocked by _counter_values

@@ -9,6 +9,9 @@ the model's serve entry points as typed-port :class:`Process` es, so decode
 runs through the SAME graph/residency/donation machinery as every other
 workload:
 
+* **weights as one static Data** — every params leaf held on the device
+  as its own array (:func:`weights_data`), handed to each prefill and
+  decode program as it is: no program decodes the weights.
 * **decode state as one persistent arena Data** — ``token`` (B,1) i32,
   ``positions`` (B,) i32, ``active`` (B,) i32, plus every flattened cache
   leaf.  The Data is marked :attr:`~repro.core.data.Data.persistent`:
@@ -90,11 +93,15 @@ def _abstract_cache(model, batch: int, max_len: int,
 
 
 def weights_data(params: Any, prefix: str = "w") -> Tuple[Data, TreeCodec]:
-    """Flatten a params tree into one arena-backed Data (the static
-    ``weights`` aux of every decode process) plus its codec."""
+    """Flatten a params tree into one Data (the ``weights`` aux of every
+    decode process) plus its codec.  The Data is marked
+    :attr:`~repro.core.data.Data.static`: it is uploaded as its own arrays,
+    one per leaf, and every program takes those arrays as they are."""
     codec = TreeCodec(params, prefix=prefix)
     named = codec.flatten(params)
-    return Data({n: np.asarray(v) for n, v in named.items()}), codec
+    data = Data({n: np.asarray(v) for n, v in named.items()})
+    data.static = True
+    return data, codec
 
 
 def decode_state_data(model, batch: int, max_len: int,

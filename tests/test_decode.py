@@ -11,7 +11,9 @@ never mirrored back to the host.  These tests pin down:
   re-registered on the output handle every launch);
 * bit-identity of :class:`~repro.processes.lm.DecodeSession` against an
   inline ``jax.jit`` prefill+decode loop (the model serve contract driven
-  directly);
+  directly), for a dense, a recurrent and an MLA/MoE model;
+* the weights as a static Data: each leaf its own program argument, and
+  one copy of them on the device;
 * bit-identity of :class:`~repro.serve.LMServer` (continuous batching via
   per-slot cache splices) against a verbatim inline copy of the legacy
   ``ServeEngine`` slot loop — transformer, rwkv6 and whisper;
@@ -20,6 +22,8 @@ never mirrored back to the host.  These tests pin down:
 * the ``SamplingConfig`` default: a fresh instance per engine (the old
   mutable dataclass default was shared process-wide).
 """
+import gc
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -51,6 +55,9 @@ def _tiny_model(family: str):
     elif family == "ssm":
         cfg = ArchConfig(name="tiny-rwkv", family="ssm", rwkv_head_dim=8,
                          **TINY)
+    elif family == "mla_moe":
+        from repro.configs import get_smoke
+        cfg = get_smoke("deepseek-v2-lite-16b").scaled(n_layers=2)
     elif family == "encdec":
         cfg = ArchConfig(name="tiny-whisper", family="encdec",
                          enc_layers=2, dec_layers=2, use_rope=False,
@@ -114,7 +121,7 @@ def test_state_device_resident_across_steps():
 # DecodeSession == direct jit loop (the model serve contract)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["dense", "ssm"])
+@pytest.mark.parametrize("family", ["dense", "ssm", "mla_moe"])
 def test_decode_session_matches_jit_loop(family):
     cfg, model, params = _tiny_model(family)
     B, P, steps = 2, 4, 5
@@ -144,6 +151,53 @@ def test_decode_session_matches_jit_loop(family):
         got.append(sess.tokens())
     for i, (w, g) in enumerate(zip(want, got)):
         np.testing.assert_array_equal(g, w, err_msg=f"step {i}")
+
+
+@pytest.mark.parametrize("family", ["dense", "mla_moe"])
+def test_lm_programs_take_each_weight_as_its_own_argument(family):
+    """The compiled prefill and decode step take every params leaf as an
+    argument of its own, in its shape and dtype and in codec order, and no
+    ``uint32`` argument the size of the weights' arena."""
+    cfg, model, params = _tiny_model(family)
+    app = CLapp().init()
+    sess = DecodeSession(app, model, params, batch=2, max_len=16)
+    sess.prefill(np.zeros((2, 3), np.int32))
+    sess.step()
+    weights = app.getData(sess.weights_h)
+    want = [(leaf.shape, leaf.dtype)
+            for leaf in jax.tree_util.tree_leaves(params)]
+    for pipe in (sess.prefill_pipe, sess.decode_pipe):
+        args = jax.tree_util.tree_leaves(
+            pipe._built.executor._compiled.args_info)
+        assert [(a.shape, a.dtype) for a in args[1:]] == want
+        assert not any(a.dtype == np.uint32
+                       and a.shape[0] >= weights.layout.total_words
+                       for a in args)
+
+
+def test_weights_are_one_copy_on_the_device():
+    """Uploading the weights leaves one copy of their bytes on the device:
+    the entry arrays, and no arena blob beside them."""
+    cfg, model, params = _tiny_model("dense")
+    host = jax.tree.map(np.asarray, params)
+    del params
+    gc.collect()                   # earlier tests' arrays go first
+    live = lambda: sum(a.nbytes for a in jax.live_arrays())
+    before = live()
+    server = LMServer(model, host, batch=2, max_len=16,
+                      sampling=SamplingConfig(max_new_tokens=2))
+    weights = server.app.getData(server._weights_h)
+    wbytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(host))
+    assert weights.device_blob is None
+    assert sum(e.nbytes for e in weights.device_entries) == wbytes
+    slots = sum(server.app.getData(h).layout.total_words * 4
+                for h in server._slot_h)
+    assert live() - before == wbytes + slots
+    server.submit([1, 2, 3])
+    assert len(server.run()[0]) == 2
+    assert not any(a.dtype == np.uint32
+                   and a.size >= weights.layout.total_words
+                   for a in jax.live_arrays())
 
 
 def test_whisper_fanin_prefill_matches_and_enc_is_device_resident():

@@ -7,9 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (CLapp, Data, DeviceTraits, PlatformTraits, Process,
-                        ProcessChain, ProfileParameters, SyncSource, XData,
-                        compile_cache_stats)
+from repro.core import (CLapp, Data, DeviceTraits, Pipeline, PlatformTraits,
+                        Port, PortError, Process, ProcessChain,
+                        ProfileParameters, SyncSource, XData,
+                        compile_cache_stats, trace)
 
 
 class AddConst(Process):
@@ -194,3 +195,104 @@ def test_heterogeneous_data_single_transfer(app, rng):
         np.testing.assert_array_equal(
             np.asarray(views[name]),
             np.asarray([a.host for a in d if a.name == name][0]))
+
+
+class AddWeight(Process):
+    ports = {"in": Port(), "out": Port(), "w": Port(aux=True)}
+
+    def apply(self, views, aux, params):
+        return {k: v + aux["w"]["img"] for k, v in views.items()}
+
+
+def _static(rng, shape=(4, 4)):
+    d = Data({"img": rng.standard_normal(shape).astype(np.float32),
+              "half": rng.standard_normal(shape).astype(jnp.bfloat16),
+              "flag": np.ones((3,), bool)})
+    d.static = True
+    return d
+
+
+def test_static_data_is_held_as_its_own_arrays(app, rng):
+    """A static Data goes to the device as one array per entry, in the
+    entry's dtype and shape, with no blob; its views are those arrays, read
+    without a program, and delData drops them."""
+    d = _static(rng)
+    before = trace.H2D_BYTES.total()
+    h = app.addData(d)
+    assert d.device_blob is None
+    assert [(e.shape, e.dtype) for e in d.device_entries] == \
+        [(a.shape, a.dtype) for a in d]
+    assert trace.H2D_BYTES.total() - before == sum(a.nbytes for a in d)
+    views = d.device_views()
+    assert all(views[a.name] is e for a, e in zip(d, d.device_entries))
+    assert d.device_view("half") is d.device_entries[1]
+    for a in d:
+        np.testing.assert_array_equal(np.asarray(views[a.name]), a.host)
+    app.delData(h)
+    assert not d.on_device
+
+
+@pytest.mark.parametrize("where", ["bind_out", "bind_in", "wired_out",
+                                   "wired_in", "input_port"])
+def test_binding_a_static_data_off_an_aux_port_raises(app, rng, where):
+    """A static Data is read-only and binds only to aux ports: as an
+    output, an in-place input or a non-aux port it is refused."""
+    w = _static(rng)
+    if where in ("bind_out", "bind_in"):
+        x = Data({"img": np.zeros((4, 4), np.float32)})
+        w = Data({"img": np.ones((4, 4), np.float32)})
+        w.static = True
+        node = AddConst(app).bind(infile=x if where == "bind_out" else w,
+                                  outfile=w)
+        with pytest.raises(PortError, match="static"):
+            (Pipeline(app) | node).build()
+        return
+    h_w = app.addData(w)
+    h_x = app.addData(XData({"img": np.zeros((4, 4), np.float32)}))
+    p = AddWeight(app) if where != "input_port" else AddConst(app)
+    p.in_handle = h_w if where == "wired_in" else h_x
+    p.out_handle = h_w if where in ("wired_out", "wired_in") else h_x
+    p.aux_handles["w"] = h_w if where == "input_port" else h_x
+    with pytest.raises(PortError, match="static"):
+        p.init()
+    with pytest.raises(ValueError, match="static"):
+        app._set_device_blob(h_w, jnp.zeros((4,), jnp.uint32))
+
+
+@pytest.mark.parametrize("graph", ["single", "staged", "fused"])
+def test_a_static_aux_reaches_the_program_as_its_arrays(app, rng, graph):
+    """Launched through a Pipeline (one node, a staged chain or a fused
+    one), a static aux is handed to the program as its entries (native
+    bytes), an ordinary one as its blob (packed bytes); the two give the
+    same bits from two executables."""
+    x = XData({"img": rng.standard_normal((4, 4)).astype(np.float32)})
+    outs, programs = {}, {}
+    for static in (False, True):
+        w = _static(np.random.default_rng(0))
+        w.static = static
+        pipe = Pipeline(app, fuse=graph == "fused") | AddWeight(app).bind(w=w)
+        if graph != "single":
+            pipe = pipe | Scale(app).bind(params=2.0)
+        pipe.build(x)
+        native, packed = (trace.AUX_NATIVE_BYTES.total(),
+                          trace.AUX_PACKED_BYTES.total())
+        outs[static] = pipe.run(x).get_ndarray(0).host.copy()
+        got = (trace.AUX_NATIVE_BYTES.total() - native,
+               trace.AUX_PACKED_BYTES.total() - packed)
+        if static:
+            assert got == (sum(a.nbytes for a in w), 0)
+        else:
+            assert got == (0, w.layout.total_words * 4)
+        exe = pipe._built.executor
+        programs[static] = (exe.stages[0] if graph == "staged"
+                            else exe)._compiled
+        args = jax.tree_util.tree_leaves(programs[static].args_info)[1:]
+        assert [(a.shape, a.dtype) for a in args] == (
+            [(a.shape, a.dtype) for a in w] if static
+            else [((w.layout.total_words,), np.uint32)])
+    assert programs[True] is not programs[False]
+    np.testing.assert_array_equal(outs[True], outs[False])
+    want = x.get_ndarray(0).host \
+        + _static(np.random.default_rng(0)).get_ndarray(0).host
+    np.testing.assert_array_equal(
+        outs[True], want if graph == "single" else want * 2.0)

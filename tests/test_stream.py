@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import (BatchedProcess, CLapp, Coherence, Data,
-                        DonatedBufferError, Process, ProcessChain,
+                        DonatedBufferError, Port, Process, ProcessChain,
                         ProfileParameters, StreamQueue, XData,
                         compile_cache_stats, trace, unpack_device)
 from repro.data.pipeline import ArenaFeed, StreamConfig, TokenStream
@@ -95,6 +95,47 @@ def test_stream_with_aux_broadcast(app, rng):
     for d, o in zip(datasets, got):
         np.testing.assert_array_equal(
             o.get_ndarray(0).host, d.get_ndarray(0).host + bias)
+
+
+class AddAuxPort(AddAux):
+    ports = {"in": Port(), "out": Port(), "bias": Port(aux=True)}
+
+
+@pytest.mark.parametrize("static", [False, True])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_stream_aux_keeps_its_form(app, rng, sharded, static):
+    """An ordinary aux Data reaches the batched program as one packed
+    ``uint32`` blob, as it always did; only a static Data comes as its own
+    arrays.  Either way the streamed results are launch()'s bits."""
+    bias = rng.standard_normal((8, 8)).astype(np.float32)
+    d_bias = XData({"img": bias})
+    d_bias.static = static
+    h_bias = app.addData(d_bias)
+    d_in = XData({"img": np.zeros((8, 8), np.float32)})
+    d_out = XData(d_in, copy_values=False)
+    h_in, h_out = app.addData(d_in), app.addData(d_out)
+    p = AddAuxPort(app)
+    p.set_in_handle(h_in); p.set_out_handle(h_out)
+    p.set_aux_handle("bias", h_bias)
+    p.init()
+    datasets = _mk_datasets(rng, 4)
+    want = _sequential(app, p, h_in, h_out, d_in, d_out, datasets)
+    native, packed = (trace.AUX_NATIVE_BYTES.total(),
+                      trace.AUX_PACKED_BYTES.total())
+    got = p.stream(datasets, batch=2, sync=True, sharded=sharded)
+    nbytes = bias.nbytes if static else d_bias.layout.total_words * 4
+    assert (trace.AUX_NATIVE_BYTES.total() - native,
+            trace.AUX_PACKED_BYTES.total() - packed) == \
+        ((2 * nbytes, 0) if static else (0, 2 * nbytes))
+    for d, o, w in zip(datasets, got, want):
+        np.testing.assert_array_equal(o.get_ndarray(0).host, w)
+        np.testing.assert_array_equal(o.get_ndarray(0).host,
+                                      d.get_ndarray(0).host + bias)
+    bp = BatchedProcess(p, 2, sharded=sharded).init()
+    (aux,) = jax.tree_util.tree_leaves(bp._compiled.args_info)[1:]
+    assert (aux.shape, aux.dtype) == (
+        ((8, 8), np.float32) if static
+        else ((d_bias.layout.total_words,), np.uint32))
 
 
 def test_stream_profile_records_a_compute_phase_per_launch(app, rng):

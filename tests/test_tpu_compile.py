@@ -228,3 +228,38 @@ def test_arena_codec_is_lane_dense_for_v5e(one_chip, layout_of, direction):
     assert not _PAIRS.findall(text), sorted(set(_PAIRS.findall(text)))
     assert compiled.memory_analysis().temp_size_in_bytes <= \
         2 * layout.total_bytes
+
+
+def test_danube_decode_step_takes_its_weights_as_they_are_for_v5e(one_chip):
+    """danube's decode step at published widths, 2 slots x 1024, compiled
+    for a described v5e: each weight is an argument of its own (no
+    ``uint32`` argument of the weights' size) and the program makes no
+    decoded copy of them, so its temporaries stay under a tenth of the
+    weights (decoding the whole blob inside the step took ~3.7 GB)."""
+    from repro.core import CLapp, Data, DeviceTraits
+    from repro.core.arena import blob_spec
+    from repro.models import build_model
+    from repro.processes import lm
+    model = build_model(DANUBE)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    leaves = jax.tree_util.tree_leaves(params)
+    codec = lm.TreeCodec(params, prefix="w")
+    weights = Data.from_specs(dict(zip(codec.names, leaves)))
+    weights.static = True
+    state, ccodec = lm.decode_state_data(model, 2, 1024)
+    app = CLapp().init(device_traits=DeviceTraits(count=1))
+    step = lm.DecodeStep(app, model, codec, ccodec, max_len=1024)
+    step.in_handle = step.out_handle = app.addData(state, to_device=False)
+    step.aux_handles["weights"] = app.addData(weights, to_device=False)
+    la = step.launchable()
+    specs = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        [blob_spec(la.in_layout)] + step._aux_specs(la))
+    compiled = jax.jit(la.fn, donate_argnums=0).lower(*specs).compile()
+    args = jax.tree_util.tree_leaves(specs)
+    assert [(a.shape, a.dtype) for a in args[1:]] == \
+        [(a.shape, a.dtype) for a in leaves]
+    wbytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert not any(a.dtype == jnp.uint32 and a.size * 4 >= wbytes
+                   for a in args)
+    assert compiled.memory_analysis().temp_size_in_bytes < wbytes / 10

@@ -202,11 +202,69 @@ def test_stream_run_counts_its_bytes_and_its_children_cover_it():
         assert c.deltas["repro_staging_reuses_total"] \
             + c.deltas["repro_staging_allocs_total"] == 2
         assert c.deltas["repro_staging_reuses_total"] >= 1
+        # no aux: nothing handed over in either form
+        assert c.deltas["repro_aux_native_bytes_total"] == 0
+        assert c.deltas["repro_aux_packed_bytes_total"] == 0
     # the phases account for the call: the root's own time is what no
     # phase covers (best of three calls, so a descheduled moment of a busy
     # test machine does not decide it)
     assert min(c.self_s["pipeline.run"] / c.duration_s for c in calls) \
         <= 0.10
+
+
+def test_aux_counters_take_the_weights_as_native_and_the_rest_as_packed():
+    """Each LM launch counts the weights under native bytes (every prefill
+    and decode step); only the splice's ``row`` and the ``slot`` of a
+    splice or release count as packed bytes."""
+    from repro.models import build_model
+    from repro.models.common import ArchConfig
+    from repro.serve import LMServer, SamplingConfig
+    cfg = ArchConfig(name="tiny", family="dense", n_layers=2, d_model=16,
+                     n_heads=2, n_kv_heads=2, d_ff=32, vocab=48, remat=False,
+                     dtype="float32", param_dtype="float32")
+    model = build_model(cfg)
+    params = model.init_params(jax.random.key(0))
+    server = LMServer(model, params, batch=2, max_len=16,
+                      sampling=SamplingConfig(max_new_tokens=4))
+    for n in (3, 5, 3):
+        server.submit(list(range(1, n + 1)))
+    since = _last_id()
+    server.run()
+    app = server.app
+    wbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(params))
+    slot = app.getData(server._slot_h[0]).layout.total_words * 4
+    row = app.getData(next(iter(server._prefill_pipes.values()))
+                      ._built.output_handle).layout.total_words * 4
+    calls = [c for c in trace.calls("lm.step") if c.span.id > since]
+    assert calls
+    for c in calls:
+        n = c.counts.get
+        assert c.deltas["repro_aux_native_bytes_total"] == wbytes * (
+            n("lm.prefill", 0) + n("lm.decode", 0))
+        assert c.deltas["repro_aux_packed_bytes_total"] == \
+            row * n("lm.splice", 0) \
+            + slot * (n("lm.splice", 0) + n("lm.release", 0))
+    assert any(c.counts.get("lm.release") for c in calls)
+
+
+def test_an_mri_stream_hands_no_aux():
+    """The MRI recon streams its k-space and maps as inputs and binds no
+    aux: neither aux counter moves."""
+    from repro.core import KData
+    from repro.processes import SimpleMRIRecon
+    app = CLapp().init()
+    pipe = Pipeline(app) | SimpleMRIRecon(app, mode="fused")
+    rng = np.random.default_rng(0)
+    items = [KData({"kdata": (rng.standard_normal((2, 2, 8, 8)) + 1j)
+                    .astype(np.complex64),
+                    "sensitivity_maps": np.ones((2, 8, 8), np.complex64)})
+             for _ in range(4)]
+    since = _last_id()
+    pipe.run(items, mode="stream", batch=2)
+    (c,) = [c for c in trace.calls("pipeline.run") if c.span.id > since]
+    assert c.counts["stream.launch"] == 2
+    assert c.deltas["repro_aux_native_bytes_total"] == 0
+    assert c.deltas["repro_aux_packed_bytes_total"] == 0
 
 
 def test_programs_carry_stable_names():
@@ -234,7 +292,9 @@ def test_metrics_registry_renders_the_program_counters():
                  "repro_compile_cache_hits_total",
                  "repro_compile_cache_misses_total",
                  "repro_arena_subword_bytes_total",
-                 "repro_staging_reuses_total", "repro_staging_allocs_total"):
+                 "repro_staging_reuses_total", "repro_staging_allocs_total",
+                 "repro_aux_native_bytes_total",
+                 "repro_aux_packed_bytes_total"):
         assert f"\n{name} " in "\n" + text, name
     from repro.serve import control
     assert control.Metrics is trace.Metrics
